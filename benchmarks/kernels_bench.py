@@ -9,12 +9,9 @@ Three families:
     (R, I) cells with megakernel / fused-XLA / staged-jax columns —
     the same `RouteBalance._decide_core` probe `benchmarks.hotpath`
     times, here centered on the kernel comparison (interleaved
-    min-of-N so ambient CPU drift doesn't bias one backend). On this
-    CPU container the megakernel runs interpret mode
-    (``REPRO_PALLAS_INTERPRET``), which executes as XLA — the
-    parity-or-better gate against fused-XLA
-    (`benchmarks.perf_guard._megakernel_guard`) is meaningful here,
-    and the TPU compiled path reuses the identical kernel body;
+    min-of-N so ambient CPU drift doesn't bias one backend). Off a
+    TPU the megakernel runs in the Pallas interpreter, which executes
+    as XLA; on a TPU the same kernel body compiles with Mosaic;
   * **multi-window batching**: K coalesced windows through one
     megakernel dispatch (`FusedHotPath.decide_cols_multi`) vs K
     separate dispatches — the launch/sync amortization rows.

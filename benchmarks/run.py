@@ -20,6 +20,9 @@ MODULES = ("predictors", "kernels_bench", "decision_core", "hotpath",
 
 
 def main() -> None:
+    from benchmarks import common  # noqa: F401  (puts src/ on sys.path)
+    from repro.launch.cache import place_compile_cache
+    place_compile_cache()
     only = sys.argv[1:] if len(sys.argv) > 1 else None
     failures = []
     for name in MODULES:

@@ -53,30 +53,47 @@ def bucket_pow2(n: int, lo: int = 8) -> int:
     return max(lo, 1 << max(int(n) - 1, 0).bit_length())
 
 
-def greedy_step(r, d, b, free, *, q_inst, c_hat, l_inst, tpot,
-                nominal_tpot, b0, max_batch, weights, allowed,
-                latency_mode, row_valid, affinity):
-    """One greedy-scan step: Eq. 1 score for request ``r`` over the
-    live dead-reckoned state, the pick, and the state update. THE one
+def first_index(hit):
+    """Column of the first True along the trailing axis, keepdims (the
+    tie order of argmax/argmin); all-False gives the axis length.
+    Written as compare + min so it lowers inside a Mosaic kernel."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, hit.shape, hit.ndim - 1)
+    return jnp.min(jnp.where(hit, iota, hit.shape[-1]), axis=-1,
+                   keepdims=True)
+
+
+def greedy_step(q_r, c_r, l_r, allowed_r, aff_r, valid_r, d, b, free, *,
+                tpot, nominal_tpot, b0, max_batch, weights,
+                latency_mode):
+    """One greedy-scan step: Eq. 1 score for one request over the live
+    dead-reckoned state, the pick, and the state update. THE one
     definition of the per-step arithmetic — `_greedy_scan`'s lax.scan
     (staged-jax and fused-XLA backends) and the Pallas megakernel's
     in-kernel fori_loop (`repro.kernels.decision_megakernel`) both
     trace this body, which is what makes their dead-reckoned carries
     bitwise identical by construction rather than by luck.
 
-    Returns (d, b, free, i (int32 pick), est (float32 latency))."""
+    The request's row of each (R, I) plane comes in already selected
+    (``q_r``/``c_r``/``l_r``/``allowed_r``/``aff_r``, ``aff_r`` None
+    when affinity is off) with the same shape as the state ``d``/``b``/
+    ``free`` — (I,) in the scan, (1, I) in the kernel; ``valid_r`` is
+    the request's row-valid flag. The pick and the scatter-updates are
+    spelled as compare/select over the candidate axis (no gather, no
+    scatter), which is bitwise the indexed form and lowers in Mosaic.
+
+    Returns (d, b, free, i (int32 pick), est (float32 latency)), i and
+    est with a kept trailing axis of size 1."""
     wq, wl, wc = weights
     wait = jnp.where(free > 0, 0.0, d / jnp.maximum(b, 1.0))
     tpot_eff = tpot * jnp.maximum(b / b0, 1.0)
     if latency_mode == "static_prior":
-        T = nominal_tpot * l_inst[r]
+        T = nominal_tpot * l_r
     else:
-        T = tpot_eff * (wait + l_inst[r])
-    if affinity is not None:
-        T = affinity_discount(T, affinity[r], jnp)
+        T = tpot_eff * (wait + l_r)
+    if aff_r is not None:
+        T = affinity_discount(T, aff_r, jnp)
     if latency_mode in ("off_reactive", "off_predictive"):
-        s = masked_score(q_inst[r], c_hat[r], T, (wq, 0.0, wc),
-                         allowed[r], jnp)
+        s = masked_score(q_r, c_r, T, (wq, 0.0, wc), allowed_r, jnp)
         # model score is instance-blind: tie-break within winner
         # model. The numpy loop subtracts 1e-9 * normalized tie in
         # float64; that term is below float32 eps for O(1) scores,
@@ -85,23 +102,20 @@ def greedy_step(r, d, b, free, *, q_inst, c_hat, l_inst, tpot,
         # epsilon-quantized from masked_score, so the tie groups
         # are identical across float32/float64 backends.
         tie = (d + b) if latency_mode == "off_reactive" else T
-        tn = tie / jnp.maximum(tie.max(), 1e-9)
-        i = jnp.argmin(jnp.where(s >= s.max(), tn, jnp.inf))
+        tn = tie / jnp.maximum(jnp.max(tie, axis=-1, keepdims=True), 1e-9)
+        v = jnp.where(s >= jnp.max(s, axis=-1, keepdims=True), tn, jnp.inf)
+        i = first_index(v == jnp.min(v, axis=-1, keepdims=True))
     else:
-        s = masked_score(q_inst[r], c_hat[r], T, (wq, wl, wc),
-                         allowed[r], jnp)
-        i = jnp.argmax(s)
-    est = T[i]
+        s = masked_score(q_r, c_r, T, (wq, wl, wc), allowed_r, jnp)
+        i = first_index(s == jnp.max(s, axis=-1, keepdims=True))
+    pick = jax.lax.broadcasted_iota(jnp.int32, d.shape, d.ndim - 1) == i
+    est = jnp.max(jnp.where(pick, T, -jnp.inf), axis=-1, keepdims=True)
     # dead reckoning: the chosen instance's pending work grows by L̂
-    v = row_valid[r]
-    d = d.at[i].add(jnp.where(v, l_inst[r, i], 0.0))
-    has_free = (free[i] > 0) & v
-    dec = jnp.where(has_free, 1.0, 0.0)
-    free = free.at[i].add(-dec)
-    b = b.at[i].set(jnp.where(has_free,
-                              jnp.minimum(b[i] + 1.0, max_batch[i]),
-                              b[i]))
-    return d, b, free, i.astype(jnp.int32), est
+    d = jnp.where(pick, d + jnp.where(valid_r, l_r, 0.0), d)
+    has_free = (free > 0) & valid_r
+    free = jnp.where(pick, free + -jnp.where(has_free, 1.0, 0.0), free)
+    b = jnp.where(pick & has_free, jnp.minimum(b + 1.0, max_batch), b)
+    return d, b, free, i, est
 
 
 def _greedy_scan(order, q_inst, c_hat, l_inst, tpot, nominal_tpot,
@@ -127,12 +141,12 @@ def _greedy_scan(order, q_inst, c_hat, l_inst, tpot, nominal_tpot,
     def step(state, r):
         d, b, free = state
         d, b, free, i, est = greedy_step(
-            r, d, b, free, q_inst=q_inst, c_hat=c_hat, l_inst=l_inst,
-            tpot=tpot, nominal_tpot=nominal_tpot, b0=b0,
-            max_batch=max_batch, weights=weights, allowed=allowed,
-            latency_mode=latency_mode, row_valid=row_valid,
-            affinity=affinity)
-        return (d, b, free), (i, est)
+            q_inst[r], c_hat[r], l_inst[r], allowed[r],
+            None if affinity is None else affinity[r], row_valid[r],
+            d, b, free, tpot=tpot, nominal_tpot=nominal_tpot, b0=b0,
+            max_batch=max_batch, weights=weights,
+            latency_mode=latency_mode)
+        return (d, b, free), (i[0], est[0])
 
     init = (d, b, free)
     (d, b, free), (picks, ests) = jax.lax.scan(step, init, order)
@@ -435,8 +449,6 @@ def sharded_greedy_scan(order, q_inst, c_hat, l_inst, tpot,
         return choice, est_T, (d2.reshape(I), b2.reshape(I),
                                f2.reshape(I))
 
-    from jax.experimental.shard_map import shard_map
-
     from ..launch.sharding import cell_specs
     pr, pi, pn = cell_specs()
     if row_valid is None:
@@ -465,7 +477,7 @@ def sharded_greedy_scan(order, q_inst, c_hat, l_inst, tpot,
     if has_aff:
         in_specs.append(pr)
         args.append(a3)
-    choice, est_T, (d2, b2, f2) = shard_map(
+    choice, est_T, (d2, b2, f2) = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(pn, pn, (pi, pi, pi)), check_rep=False)(*args)
+        out_specs=(pn, pn, (pi, pi, pi)), check_vma=False)(*args)
     return choice, est_T, (d2.reshape(I), b2.reshape(I), f2.reshape(I))

@@ -61,7 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.estimators.gbm import pack_ensemble, predict_packed_gathered
+from repro.estimators.gbm import pack_ensemble, predict_roster, \
+    roster_tables
 from repro.estimators.knn import topk_soft_lookup
 from repro.serving.affinity import SIG_WIDTH, SKETCH_SLOTS, hit_fraction
 
@@ -155,8 +156,8 @@ class FusedHotPath:
                          if getattr(cfg, "decision_backend", "fused")
                          == "megakernel" else "fused")
         if self._backend == "megakernel":
-            from repro.kernels.ops import INTERPRET
-            self._interpret = INTERPRET
+            from repro.kernels.ops import interpret_mode
+            self._interpret = interpret_mode()
         knn = bundle.knn
         self._E = bundle.encoder.dim
         self._k = knn.k
@@ -255,8 +256,10 @@ class FusedHotPath:
                 str([t for t, h in zip(tier_names, heads)
                      if h.model is None])
             stacked = pack_ensemble([h.model for h in heads])
-            self._gbm = {k: jnp.asarray(v) if isinstance(v, np.ndarray)
-                         else v for k, v in stacked.items()}
+            self._gbm = stacked
+            self._gbm_tables = dict(
+                roster_tables(stacked, tier_of_i), lr=stacked["lr"],
+                depth=stacked["depth"])
         # the telemetry mirror (d, b, free, ctx) is donated in and the
         # refreshed (pre-scan) mirror comes back out, so it chains
         # batch-to-batch on device; alive is read-only (re-uploaded on
@@ -369,10 +372,9 @@ class FusedHotPath:
         b_eff = jnp.maximum(b, 1.0)
         ctx_eff = jnp.maximum(ctx, 64.0)
         if self._use_gbm:
-            feats = jnp.stack([b_eff, d, ctx_eff, b_eff * ctx_eff],
-                              axis=1).astype(jnp.float32)
             tpot = jnp.maximum(
-                predict_packed_gathered(self._gbm, self._tier_of_i, feats),
+                predict_roster(self._gbm_tables,
+                               [b_eff, d, ctx_eff, b_eff * ctx_eff])[0],
                 1e-4)
         else:
             tpot = self._nominal
@@ -396,7 +398,7 @@ class FusedHotPath:
         # Python-level branch: w_aff == 0 compiles the term out and the
         # dummy psig/sig_plane inputs are dead.
         if self._w_aff > 0.0:
-            hit = hit_fraction(psig, len_in, sig_plane, jnp)
+            hit = hit_fraction(psig, len_in, sig_plane.T, jnp)
             hit = jnp.where(alive[None, :], hit, jnp.float32(0.0))
             aff = jnp.float32(self._w_aff) * hit
         else:

@@ -362,7 +362,7 @@ class RouteBalancePolicy(SchedulingPolicy):
                 req_sig = np.stack([prompt_signatures(r.prompt)
                                     for r in reqs])
             hit = hit_fraction(req_sig, len_in.astype(np.float32),
-                               tel.prefix_sig[alive_rows], np)
+                               tel.prefix_sig[alive_rows].T, np)
             aff = np.float32(cfg.affinity_weight) * hit
         if cfg.decision_backend == "jax":
             from . import decision_jax

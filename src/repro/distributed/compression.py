@@ -68,7 +68,6 @@ def compress_decompress(grads, error_state=None
 def shardmap_allreduce(x, mesh, axes=("data",)):
     """Explicit int8-payload all-reduce over the data axes: quantize
     locally, psum int32 accumulators, dequantize with the max scale."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(xl):
@@ -82,8 +81,8 @@ def shardmap_allreduce(x, mesh, axes=("data",)):
         return (s.astype(jnp.float32) * scale / n).astype(xl.dtype)
 
     spec = P(*([None] * x.ndim))
-    return shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(x)
 
 
 # ---------------------------------------------------------------------------

@@ -217,30 +217,51 @@ def pack_ensemble(models: List["GradientBoostedRegressor"]):
             "lr": packs[0]["lr"], "depth": packs[0]["depth"]}
 
 
-def predict_packed_gathered(stacked, member, X):
-    """Per-row member selection over a `pack_ensemble` stack (in-graph).
-
-    member: (n,) int — which booster scores each row; X: (n, f).
-    Returns (n,). Each row walks its own member's trees; used by the
-    fused hot path to run all per-tier TPOT heads in one dispatch.
-    The traversal gather is diagonal (row r vs row r's trees), unlike
-    `_packed_leaves`' cross product (every row vs every tree), but the
-    parity-critical accumulation shares `_accumulate`.
-    """
+def roster_tables(stacked, member):
+    """Per-instance tree tables for `predict_roster`: each instance's
+    member booster (`pack_ensemble` stack, ``member`` (I,) int) laid
+    out node-major and instance-minor — feature/threshold
+    (n_internal, T, I), leaf (n_leaves, T, I) — plus the (1, I) base.
+    Built once per dispatch, outside any kernel."""
     import jax.numpy as jnp
-    feat = jnp.asarray(stacked["feature"])[member]      # (n, T, n_int)
-    thr = jnp.asarray(stacked["threshold"])[member]
-    leaf = jnp.asarray(stacked["leaf"])[member]
-    X = jnp.asarray(X, jnp.float32)
-    T = feat.shape[1]
-    idx = jnp.zeros((X.shape[0], T), jnp.int32)
-    for _ in range(stacked["depth"]):
-        f = jnp.take_along_axis(feat, idx[:, :, None], axis=2)[..., 0]
-        t = jnp.take_along_axis(thr, idx[:, :, None], axis=2)[..., 0]
-        xv = jnp.take_along_axis(X, f, axis=1)          # (n, T)
+
+    def plane(a):                             # (G, T, n) -> (n, T, I)
+        return jnp.transpose(jnp.asarray(a)[member], (2, 1, 0))
+    return {"feature": plane(stacked["feature"]),
+            "threshold": plane(stacked["threshold"]),
+            "leaf": plane(stacked["leaf"]),
+            "base": jnp.asarray(stacked["base"], jnp.float32)[member][None]}
+
+
+def predict_roster(tables, feats):
+    """Per-instance member selection over a `pack_ensemble` stack: every
+    instance walks its own member's trees — all per-tier TPOT heads in
+    one pass, for the fused program and the megakernel alike. The walk
+    is compare-select over the (T, I) planes of `roster_tables`
+    (leading-axis indexing only, so it lowers inside a Mosaic kernel),
+    then the shared `_accumulate` order, so each instance's value is
+    bitwise its member's numpy prediction. ``tables`` may hold arrays
+    or kernel refs, plus ``lr`` and ``depth``; ``feats`` is the list of
+    (1, I) feature rows. Returns (1, I)."""
+    import jax.numpy as jnp
+    depth = tables["depth"]
+    feat, thr, leaf = (tables["feature"], tables["threshold"],
+                       tables["leaf"])
+    shape = feat.shape[1:]                                  # (T, I)
+    idx = jnp.zeros(shape, jnp.int32)
+    for level in range(depth):
+        f = jnp.zeros(shape, jnp.int32)
+        t = jnp.zeros(shape, jnp.float32)
+        for n in range(2 ** level - 1, 2 ** (level + 1) - 1):
+            at = idx == n
+            f = jnp.where(at, feat[n], f)
+            t = jnp.where(at, thr[n], t)
+        xv = jnp.zeros(shape, jnp.float32)
+        for c, row in enumerate(feats):
+            xv = jnp.where(f == c, row, xv)
         idx = 2 * idx + 1 + (xv > t).astype(jnp.int32)
-    leaf_idx = idx - (2 ** stacked["depth"] - 1)
-    vals = jnp.take_along_axis(leaf, leaf_idx[:, :, None],
-                               axis=2)[..., 0]          # (n, T)
-    base = jnp.asarray(stacked["base"])[member]
-    return _accumulate(base, stacked["lr"], vals.T, jnp)
+    idx = idx - (2 ** depth - 1)
+    vals = jnp.zeros(shape, jnp.float32)
+    for n in range(2 ** depth):
+        vals = jnp.where(idx == n, leaf[n], vals)
+    return _accumulate(tables["base"], tables["lr"], vals[None], jnp)

@@ -28,20 +28,38 @@ def distance_weights(d2, eps: float, xp=np):
     return w / w.sum(-1, keepdims=True)
 
 
+def label_mix(picks, w):
+    """The distance-weighted label mix: sum_j picks[j] * w[:, j] with
+    picks[j] the (B, M) labels of each row's j-th neighbour and w the
+    (B, k) `distance_weights`. Accumulated neighbour by neighbour in
+    float32 — one definition of the rounding order for the numpy, fused
+    and megakernel paths."""
+    out = picks[0] * w[:, 0:1]
+    for j in range(1, len(picks)):
+        out = out + picks[j] * w[:, j:j + 1]
+    return out
+
+
 def topk_soft_lookup(q, x, xsq, quality, length, k: int, eps: float):
     """The jnp KNN query body: squared distances via the
     ||q-x||² = ||q||² - 2 q·x + ||x||² expansion, `lax.top_k`, then the
     distance-weighted label mix. One definition traced by both the
     staged jax backend and the fused hot path (exact-parity tests
     compare their outputs bitwise). All args are jnp arrays; returns
-    (quality (B, M), length (B, M))."""
+    (quality (B, M), length (B, M)).
+
+    The cross term runs at HIGHEST precision: the TPU's default f32 dot
+    takes bf16 passes, and the neighbour set must agree with the
+    float32 numpy reference."""
     import jax
     import jax.numpy as jnp
-    d2 = xsq[None, :] - 2.0 * q @ x.T + jnp.sum(q * q, -1, keepdims=True)
+    d2 = (xsq[None, :]
+          - jnp.matmul(2.0 * q, x.T, precision=jax.lax.Precision.HIGHEST)
+          + jnp.sum(q * q, -1, keepdims=True))
     neg, idx = jax.lax.top_k(-d2, k)
     w = distance_weights(-neg, eps, jnp)
-    return ((quality[idx] * w[..., None]).sum(1),
-            (length[idx] * w[..., None]).sum(1))
+    return (label_mix([quality[idx[:, j]] for j in range(k)], w),
+            label_mix([length[idx[:, j]] for j in range(k)], w))
 
 
 class KNNEstimator:
@@ -97,9 +115,10 @@ class KNNEstimator:
         idx = np.take_along_axis(idx, order, axis=1)
         d2k = np.take_along_axis(d2k, order, axis=1)
         w = distance_weights(d2k, self.eps)
-        qual = (self._quality[idx] * w[..., None]).sum(1)
-        leng = (self._length[idx] * w[..., None]).sum(1)
-        return qual, leng
+        return (label_mix([self._quality[idx[:, j]]
+                           for j in range(self.k)], w),
+                label_mix([self._length[idx[:, j]]
+                           for j in range(self.k)], w))
 
     def _build_jax(self):
         import jax

@@ -5,8 +5,8 @@
 #   decision_megakernel — the whole fused routing decision (KNN top-k →
 #                         packed GBM → Eq. 2 admission → LPT greedy
 #                         scan) as one kernel, K windows per dispatch
-# ops.py = jit'd wrappers (REPRO_PALLAS_INTERPRET selects interpret vs
-# compiled TPU mode); ref.py = pure oracles.
+# ops.py = jit'd wrappers (Mosaic on a TPU, the interpreter elsewhere);
+# ref.py = pure oracles.
 from . import ops as knn_ops  # noqa: F401  (KNNEstimator pallas backend)
 # import the decision_megakernel SUBMODULE before binding the same-named
 # wrapper function: a later `import repro.kernels.decision_megakernel`

@@ -36,9 +36,11 @@ def _kernel(q_ref, qsq_ref, x_ref, xsq_ref, vals_ref, idx_ref, *,
     x = x_ref[...]                     # (T, E)
     xsq = xsq_ref[...]                 # (1, T)
     qsq = qsq_ref[...]                 # (B, 1)
-    # (B, T) squared distances on the MXU
+    # (B, T) squared distances on the MXU, at full f32 precision (the
+    # TPU's default f32 dot takes bf16 passes)
     d = qsq + xsq - 2.0 * jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     base = t * tile
     col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1) + base

@@ -114,7 +114,7 @@ def decision_ref(emb, row_valid, budgets, len_in, psig,
             allowed = np.broadcast_to(alive[None, :], c_hat.shape)
         if w_aff > 0.0:
             hit = hit_fraction(np.asarray(psig[wi]), lin,
-                               np.asarray(sig_plane), np)
+                               np.asarray(sig_plane).T, np)
             aff = f32(w_aff) * np.where(alive[None, :], hit, f32(0.0))
         else:
             aff = None

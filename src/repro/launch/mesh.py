@@ -35,7 +35,12 @@ def make_cell_mesh(n_cells: int):
     bitwise-identical single-program cell emulation, so a CPU box (one
     device by default; more via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) runs the
-    same logical decision without the collectives."""
+    same logical decision without the collectives.
+
+    The axis is Auto: only the scan's `shard_map` is sharded by hand,
+    and the hot path's telemetry scatters around it must not carry the
+    cell sharding in their types."""
     if n_cells <= 1 or jax.device_count() < n_cells:
         return None
-    return jax.make_mesh((n_cells,), ("cell",))
+    return jax.make_mesh((n_cells,), ("cell",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

@@ -77,6 +77,8 @@ def main():
                     help="digest wire codec")
     args = ap.parse_args()
 
+    from repro.launch.cache import place_compile_cache
+    place_compile_cache()
     from repro.core import (EngineConfig, EstimatorBundle, PRESETS,
                             ServingEngine, fit_policy, make_requests,
                             run_cell)

@@ -96,7 +96,6 @@ def moe_layer_sharded(x, p: Dict, *, top_k: int, capacity_factor: float,
     (shapes inside shard_map are per-shard).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.distributed.shardctx import batch_axes, current
     mesh, _ = current()
@@ -128,8 +127,8 @@ def moe_layer_sharded(x, p: Dict, *, top_k: int, capacity_factor: float,
     if glu:
         p_specs["gate"] = P(None, None, "model")
     x_spec = P(ba, *([None] * (x.ndim - 1)))
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(x_spec, p_specs),
-                   out_specs=(x_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(x_spec, p_specs),
+                       out_specs=(x_spec, P()),
+                       check_vma=False)
     return fn(x, p)

@@ -150,23 +150,37 @@ class PrefixSketch:
         return out
 
 
-def hit_fraction(req_sig, len_in, sig_plane, xp):
+def hit_fraction(req_sig, len_in, sig_plane_t, xp):
     """Matched-prefix fraction per (request, instance).
 
-    (R, SIG_WIDTH) int32 request signatures x (I, SKETCH_SLOTS) int32
-    sketch mirrors -> (R, I) float32 in [0, 1]: leading-run block
-    match, converted to tokens, capped at and normalized by the
-    request's input length. Pure integer compares plus one IEEE
-    float32 divide, written once over `xp` (numpy or jax.numpy) so
-    the staged and fused backends are bit-identical by construction.
-    The 0 sentinel (empty sketch slot / absent signature column)
-    never matches.
+    (R, SIG_WIDTH) int32 request signatures x the sketch mirrors,
+    transposed to (SKETCH_SLOTS, I) int32 -> (R, I) float32 in [0, 1]:
+    leading-run block match, converted to tokens, capped at and
+    normalized by the request's input length (R,) or (R, 1). Pure
+    integer compares plus one IEEE float32 divide, written once over
+    `xp` (numpy or jax.numpy) in 2-D compare-select form — which the
+    Mosaic kernel lowers too — so every backend is bit-identical by
+    construction. The 0 sentinel (empty sketch slot / absent signature
+    column) never matches.
     """
-    present = (req_sig[:, :, None, None]
-               == sig_plane[None, None, :, :]).any(-1)     # (R, D, I)
-    present = present & (req_sig != 0)[:, :, None]
-    run = xp.cumprod(present.astype(xp.int32), axis=1).sum(axis=1)
+    run = alive = None
+    for c in range(req_sig.shape[1]):
+        sig = req_sig[:, c:c + 1]                            # (R, 1)
+        present = sig == sig_plane_t[0:1, :]
+        for s in range(1, sig_plane_t.shape[0]):
+            present = present | (sig == sig_plane_t[s:s + 1, :])
+        present = present & (sig != 0)
+        alive = present if alive is None else alive & present
+        run = (alive.astype(xp.int32) if run is None
+               else run + alive.astype(xp.int32))
+    return _matched_fraction(run, len_in.reshape(-1, 1), xp)
+
+
+def _matched_fraction(run, len_in, xp):
+    """Leading-run block count (R, I) int -> matched fraction, with
+    len_in an (R, 1) column: tokens capped at and normalized by the
+    prompt length (one float32 multiply, min and divide)."""
     lenf = xp.maximum(len_in.astype(xp.float32), xp.float32(1.0))
     matched = xp.minimum(
-        run.astype(xp.float32) * xp.float32(PREFIX_BLOCK), lenf[:, None])
-    return matched / lenf[:, None]
+        run.astype(xp.float32) * xp.float32(PREFIX_BLOCK), lenf)
+    return matched / lenf

@@ -127,10 +127,10 @@ def test_hit_fraction_numpy_jax_bitwise():
         sk.insert(req_sig[i % 6, :rng.integers(1, SIG_WIDTH + 1)])
         sk.mirror(out=plane[i])
     lenf = lens.astype(np.float32)
-    h_np = hit_fraction(req_sig, lenf, plane, np)
+    h_np = hit_fraction(req_sig, lenf, plane.T, np)
     h_j = np.asarray(hit_fraction(jnp.asarray(req_sig),
                                   jnp.asarray(lenf),
-                                  jnp.asarray(plane), jnp))
+                                  jnp.asarray(plane).T, jnp))
     np.testing.assert_array_equal(h_np, h_j)          # bitwise
     assert h_np.dtype == np.float32
     assert (h_np >= 0).all() and (h_np <= 1).all()

@@ -164,13 +164,17 @@ def test_predict_packed_bitwise_matches_numpy():
 
 
 def test_pack_ensemble_gathered_matches_members():
-    from repro.estimators.gbm import pack_ensemble, predict_packed_gathered
+    from repro.estimators.gbm import (pack_ensemble, predict_roster,
+                                      roster_tables)
     models = [_toy_gbm(seed=s) for s in range(3)]
     stacked = pack_ensemble(models)
     rng = np.random.default_rng(2)
     Xq = rng.normal(size=(40, 4)).astype(np.float32)
     member = rng.integers(0, 3, 40)
-    got = np.asarray(predict_packed_gathered(stacked, member, Xq))
+    tables = dict(roster_tables(stacked, member), lr=stacked["lr"],
+                  depth=stacked["depth"])
+    got = np.asarray(predict_roster(tables, [Xq[None, :, f]
+                                             for f in range(4)]))[0]
     ref = np.select([member == j for j in range(3)],
                     [m.predict(Xq) for m in models])
     np.testing.assert_array_equal(got, ref.astype(np.float32))

@@ -13,8 +13,7 @@ Three layers, mirroring how the fused backend itself graduated:
     the prefix-affinity arm;
   * plumbing-level: multi-window coalescing equals K separate
     dispatches, compile variants stay pinned at the pow2 buckets
-    through roster churn, and the `REPRO_PALLAS_INTERPRET` env toggle
-    parses as documented.
+    through roster churn, and the kernel mode follows the platform.
 """
 import numpy as np
 import pytest
@@ -323,8 +322,9 @@ def test_decision_call_matches_numpy_oracle(use_gbm):
 
 
 def test_decision_call_topk_modes_bitwise_equal():
-    """topk_mode="running" (the Mosaic-lowerable TPU form) and
-    topk_mode="topk" (the interpret-mode fast path) must produce
+    """The index streamed in tiles (``knn_tile=16``: three grid steps,
+    the last one part padding — the compiled TPU form) and the whole
+    index as one tile (the interpreter's default) must produce
     bitwise-identical decisions end to end — survivor set, order, and
     every downstream float32 sum."""
     from repro.kernels.ops import decision_megakernel as mk_op
@@ -332,41 +332,75 @@ def test_decision_call_topk_modes_bitwise_equal():
     args, statics = _toy_world(seed=3)
     gfeat, gthr, gleaf, gbase = dummy_gbm()
     out = {}
-    for mode in ("topk", "running"):
-        out[mode] = mk_op(*args.values(), gfeat, gthr, gleaf, gbase,
+    for tile in (None, 16):
+        out[tile] = mk_op(*args.values(), gfeat, gthr, gleaf, gbase,
                           **statics, use_gbm=False, depth=1, lr=0.1,
-                          topk_mode=mode, knn_tile=16)
-    for a, b in zip(out["topk"], out["running"]):
+                          knn_tile=tile)
+    for a, b in zip(out[None], out[16]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_topk_running_matches_lax_topk_order():
-    """The in-kernel running-top-k must reproduce lax.top_k's exact
-    neighbor ORDER (stable sort by (distance, index)) — the label-mix
-    sums are order-sensitive in float32."""
+    """The kernel's streamed top-k (tile-by-tile merge, then survivor
+    ordering) must reproduce lax.top_k's exact neighbor set and ORDER —
+    stable by (distance, index) — and carry each survivor's labels: the
+    label-mix sums are order-sensitive in float32. Exact ties sit both
+    inside the top-k and on its boundary across tiles."""
+    import sys
+
     import jax
     import jax.numpy as jnp
-    from repro.kernels.decision_megakernel import _topk_running
+    import repro.kernels  # noqa: F401
+    mk = sys.modules["repro.kernels.decision_megakernel"]
     rng = np.random.default_rng(0)
-    d2 = rng.uniform(0, 10, (32, 600)).astype(np.float32)
-    d2[:, 100] = d2[:, 50]                   # force exact ties
+    R, N, k, tile = 32, 600, 10, 256
+    d2 = rng.uniform(0, 10, (R, N)).astype(np.float32)
+    d2[:, 100] = d2[:, 50]                   # ties anywhere
     d2[:, 401] = d2[:, 400]
-    vals, idx = _topk_running(jnp.asarray(d2), 10, tile=256)
-    neg, ridx = jax.lax.top_k(-jnp.asarray(d2), 10)
-    np.testing.assert_array_equal(np.asarray(vals), np.asarray(-neg))
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
+    # boundary ties: tile 0 fills the buffer with two 0.5s as its worst,
+    # tile 1's 0.3 must evict the higher index (30), tile 2's 0.5 must
+    # not enter
+    d2[:8] = np.maximum(d2[:8], 0.6)
+    d2[:8, :8] = 0.1
+    d2[:8, [20, 30, 520]] = 0.5
+    d2[:8, 300] = 0.3
+    labels = rng.uniform(0, 1, (2, N)).astype(np.float32)
+    vals = jnp.full((R, k), mk.NEG, jnp.float32)
+    idx = -1 - jax.lax.broadcasted_iota(jnp.int32, (R, k), 1)
+    labs = [jnp.zeros((R, k), jnp.float32)] * 2
+    pad = np.full((R, (-N) % tile), mk.NEG, np.float32)
+    dp = np.concatenate([d2, pad], 1)
+    lp = np.concatenate([labels, np.zeros((2, pad.shape[1]), np.float32)],
+                        1)
+    for t in range(0, dp.shape[1], tile):
+        vals, idx, labs = mk._merge_tile(
+            vals, idx, labs, jnp.asarray(dp[:, t:t + tile]),
+            [jnp.asarray(lp[c:c + 1, t:t + tile]) for c in range(2)], t, k)
+    d2k, picks = mk._ordered_survivors(vals, idx, labs, k)
+    neg, ridx = jax.lax.top_k(-jnp.asarray(d2), k)
+    np.testing.assert_array_equal(np.asarray(d2k), np.asarray(-neg))
+    ridx = np.asarray(ridx)
+    for j in range(k):
+        for c in range(2):
+            np.testing.assert_array_equal(
+                np.asarray(picks[j][c])[:, 0], labels[c][ridx[:, j]])
 
 
-# -- env toggle ---------------------------------------------------------------
+# -- execution mode -----------------------------------------------------------
 
-def test_env_interpret_toggle(monkeypatch):
-    from repro.kernels.ops import env_interpret
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert env_interpret() is True            # container default
-    assert env_interpret(default=False) is False
-    for off in ("0", "false", "OFF", ""):
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", off)
-        assert env_interpret() is False, off
-    for on in ("1", "true", "interpret"):
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", on)
-        assert env_interpret() is True, on
+def test_interpret_mode_follows_platform(monkeypatch, small_ctx):
+    """Kernels compile with Mosaic on a TPU and interpret elsewhere; the
+    mode is read from the platform when a runner is built."""
+    import jax
+
+    from repro.core.hotpath import FusedHotPath
+    from repro.kernels.ops import interpret_mode
+    assert interpret_mode() is True           # this CPU backend
+    sim = _loaded_sim(small_ctx)
+    cfg = RBConfig(decision_backend="megakernel")
+    for platform, interpret in (("tpu", False), ("cpu", True),
+                                ("gpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda p=platform: p)
+        assert interpret_mode() is interpret, platform
+        runner = FusedHotPath(small_ctx["bundle"], sim.instances, cfg)
+        assert runner._interpret is interpret, platform
