@@ -69,12 +69,19 @@ def device_phase(n_chips: int):
 
 
 def runner_counters(rb) -> dict:
+    """The fused runner's counters and its host split: seconds in each
+    `rb.*` span (staging, telemetry sync, dispatch, the wait for the
+    device, the copy) and the host arrays it handed the device."""
     fused = rb._fused
     st = fused.stats
     return {"compile_count": fused.compile_count(),
             "full_reseed": st["full_reseed"],
             "roster_reseed": st["roster_reseed"],
-            "delta_sync": st["delta_sync"], "carry": st["carry"]}
+            "delta_sync": st["delta_sync"], "carry": st["carry"],
+            **{k: f"{st[k]:.3f}" for k in ("stage_s", "telemetry_s",
+                                            "dispatch_s", "device_s",
+                                            "sync_s")},
+            "uploads": st["uploads"]}
 
 
 def served_cell(phase: str, m: dict, rb, n: int, seconds: float,

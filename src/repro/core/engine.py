@@ -48,6 +48,7 @@ from repro.serving.request import Request
 from repro.serving.tiers import Tier
 
 from .budget import max_tokens_clamp
+from .trace import span
 
 DEPLOYMENTS = ("windowed", "concurrent", "serial_published", "microbatch")
 # legacy PipelineConfig spelling, accepted as an alias
@@ -383,9 +384,10 @@ class ServingEngine:
             self._wait_start = self._wait_n = 0   # stream (or recover
             self._wait_cols = None                # from a mixed one)
         if batch:
-            t0 = time.perf_counter()
-            self._decide(batch, t, cols, rows)
-            dt_meas = time.perf_counter() - t0
+            with span("rb.window"):
+                t0 = time.perf_counter()
+                self._decide(batch, t, cols, rows)
+                dt_meas = time.perf_counter() - t0
             self._measured_compute = (0.8 * self._measured_compute
                                       + 0.2 * dt_meas)
             self.compute_log.append((len(batch), dt_meas))
@@ -407,7 +409,8 @@ class ServingEngine:
 
     def _decide(self, batch: List[Request], t: float, cols=None,
                 rows: Optional[np.ndarray] = None):
-        res = self._assign(BatchView(batch, cols, rows, t))
+        with span("rb.assign"):
+            res = self._assign(BatchView(batch, cols, rows, t))
         R = len(batch)
         I = int(self.sim.tel.alive.sum())
 
@@ -424,19 +427,20 @@ class ServingEngine:
         instances = res.instances
         clamp = self.policy.budget_clamp
         mgr = getattr(self.sim, "recovery", None)
-        for r_idx, req in enumerate(batch):
-            inst = instances[int(choice[r_idx])]
-            req.sched_compute = per_req_compute
-            req.sched_stats_fetch = stats
-            req.sched_batch_wait = max(t - req.arrival, 0.0)
-            mt = (max_tokens_clamp(req.budget, req.prompt.len_in,
-                                   inst.tier.price_in,
-                                   inst.tier.price_out)
-                  if clamp else None)
-            inst.submit(req, now, float(l_chosen[r_idx]), mt)
-            self.decisions += 1
-            if mgr is not None:
-                mgr.watch_dispatch(req, inst, now)
+        with span("rb.submit"):
+            for r_idx, req in enumerate(batch):
+                inst = instances[int(choice[r_idx])]
+                req.sched_compute = per_req_compute
+                req.sched_stats_fetch = stats
+                req.sched_batch_wait = max(t - req.arrival, 0.0)
+                mt = (max_tokens_clamp(req.budget, req.prompt.len_in,
+                                       inst.tier.price_in,
+                                       inst.tier.price_out)
+                      if clamp else None)
+                inst.submit(req, now, float(l_chosen[r_idx]), mt)
+                self.decisions += 1
+                if mgr is not None:
+                    mgr.watch_dispatch(req, inst, now)
         self.batches += 1
 
     # -- station deployments (§6.3 ladder) ------------------------------------

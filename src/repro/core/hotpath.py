@@ -54,7 +54,6 @@ randomized worlds (``tests/test_soak.py``).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -68,13 +67,40 @@ from repro.serving.affinity import SIG_WIDTH, SKETCH_SLOTS, hit_fraction
 
 from .budget import admission_math, cost_matrix
 from .decision_jax import _greedy_scan, bucket_pow2, sharded_greedy_scan
+from .trace import span
 
 
 def _new_stats() -> Dict:
-    return {"calls": 0, "host_s": 0.0, "stage_s": 0.0, "dispatch_s": 0.0,
-            "device_s": 0.0, "sync_s": 0.0, "full_reseed": 0,
+    """Host seconds per `rb.*` span of the runner (`core/trace.py`):
+    `stage_s` (rb.stage), `telemetry_s` (rb.telemetry), `host_s` (the
+    two together), `dispatch_s` (rb.dispatch), `device_s` (rb.wait) and
+    `sync_s` (rb.copy). Counters: `uploads`, the host arrays the runner
+    hands to the device (each numpy argument of the jitted step, and
+    each array a reseed uploads); `dirty_checks` and `dirty_rows_seen`,
+    the syncs that read `tel.dirty_rows` and the rows they found dirty,
+    before the mostly-dirty rule decides; then how each sync ended."""
+    return {"calls": 0, "host_s": 0.0, "stage_s": 0.0, "telemetry_s": 0.0,
+            "dispatch_s": 0.0, "device_s": 0.0, "sync_s": 0.0,
+            "uploads": 0, "dirty_checks": 0, "dirty_rows_seen": 0,
+            "full_reseed": 0,
             "roster_reseed": 0,        # full reseeds caused by roster churn
             "delta_sync": 0, "delta_rows": 0, "carry": 0}
+
+
+def _host_arrays(args) -> int:
+    """How many of a jitted call's arguments are host numpy arrays, each
+    transferred to the device before the launch."""
+    return sum(type(a) is np.ndarray for a in args)
+
+
+def _scatter_delta(d, b, free, ctx, didx, dd, db, dfree, dctx):
+    """The dirty rows into the telemetry mirror, under the `telemetry`
+    scope (pad lanes carry out-of-range indices and drop)."""
+    with jax.named_scope("telemetry"):
+        return (d.at[didx].set(dd, mode="drop"),
+                b.at[didx].set(db, mode="drop"),
+                free.at[didx].set(dfree, mode="drop"),
+                ctx.at[didx].set(dctx, mode="drop"))
 
 
 class LazyDecision:
@@ -97,14 +123,14 @@ class LazyDecision:
 
     def fetch(self) -> Tuple[np.ndarray, np.ndarray]:
         if self._out is None:
-            t0 = time.perf_counter()
-            jax.block_until_ready((self._choice, self._l))
-            t1 = time.perf_counter()
-            self._out = (np.asarray(self._choice[:self._R], np.int64),
-                         np.asarray(self._l[:self._R], np.float64))
-            t2 = time.perf_counter()
-            self._stats["device_s"] += t1 - t0
-            self._stats["sync_s"] += t2 - t1
+            st = self._stats
+            with span("rb.fetch"):
+                with span("rb.wait", st, "device_s"):
+                    jax.block_until_ready((self._choice, self._l))
+                with span("rb.copy", st, "sync_s"):
+                    self._out = (
+                        np.asarray(self._choice[:self._R], np.int64),
+                        np.asarray(self._l[:self._R], np.float64))
         return self._out
 
 
@@ -243,8 +269,10 @@ class FusedHotPath:
                 np.zeros((self._Itot, SKETCH_SLOTS), np.int32),
                 np.zeros((self._Itot, SKETCH_SLOTS), np.int32)]
             self._pflip = 0
-        self._dummy_psig = np.zeros((1, 1), np.int32)
-        self._dummy_plane = np.zeros((1, 1), np.int32)
+        # device-resident, so the dead affinity inputs are no host
+        # array of the call (`uploads` counts the host arrays)
+        self._dummy_psig = jnp.zeros((1, 1), jnp.int32)
+        self._dummy_plane = jnp.zeros((1, 1), jnp.int32)
         self._use_gbm = (cfg.latency_mode != "static_prior"
                          and cfg.learned_tpot)
         if self._use_gbm:
@@ -320,17 +348,18 @@ class FusedHotPath:
         else:
             gf, gt, gl, gb = dummy_gbm()
             depth, lr = 1, 0.1
-        return decision_call(
-            emb, row_valid, budgets, len_in, psig,
-            d, b, free, ctx, alive,
-            self._x, self._xsq, self._qual, self._leng,
-            self._m_of_i, self._tier_of_i, self._maxb, self._price_in,
-            self._price_out, self._nominal, sig_plane, gf, gt, gl, gb,
-            k=self._k, eps=self._eps, weights=self._weights,
-            latency_mode=self._mode, lpt=self._lpt,
-            budget_filter=self._budget_filter, w_aff=self._w_aff,
-            use_gbm=self._use_gbm, depth=depth, lr=lr,
-            interpret=self._interpret)
+        with jax.named_scope("megakernel"):
+            return decision_call(
+                emb, row_valid, budgets, len_in, psig,
+                d, b, free, ctx, alive,
+                self._x, self._xsq, self._qual, self._leng,
+                self._m_of_i, self._tier_of_i, self._maxb, self._price_in,
+                self._price_out, self._nominal, sig_plane, gf, gt, gl, gb,
+                k=self._k, eps=self._eps, weights=self._weights,
+                latency_mode=self._mode, lpt=self._lpt,
+                budget_filter=self._budget_filter, w_aff=self._w_aff,
+                use_gbm=self._use_gbm, depth=depth, lr=lr,
+                interpret=self._interpret)
 
     def _step_impl(self, emb, row_valid, budgets, len_in,
                    d, b, free, ctx, alive,
@@ -341,10 +370,8 @@ class FusedHotPath:
         # re-read — untouched rows' telemetry has not moved since they
         # were last synced — so this arm preserves the staged backends'
         # reseed-per-batch semantics exactly.
-        d = d.at[didx].set(dd, mode="drop")
-        b = b.at[didx].set(db, mode="drop")
-        free = free.at[didx].set(dfree, mode="drop")
-        ctx = ctx.at[didx].set(dctx, mode="drop")
+        d, b, free, ctx = _scatter_delta(d, b, free, ctx,
+                                         didx, dd, db, dfree, dctx)
 
         if self._backend == "megakernel":
             # stages 1–4 fused into one Pallas dispatch (K=1 window);
@@ -358,62 +385,71 @@ class FusedHotPath:
             return (choice, est_T, l_chosen, d, b, free, ctx,
                     d1, b1, f1)
 
+        # each stage under a named scope (op metadata only: the
+        # compiled program is the same), so a device trace splits the
+        # program by stage
+
         # 1. prompt-intrinsic estimation: KNN top-k over the ingest
         # embedding column, all models at once
-        qual, leng = topk_soft_lookup(emb, self._x, self._xsq,
-                                      self._qual, self._leng,
-                                      self._k, self._eps)    # (R, M)
-        q_inst = qual[:, self._m_of_i]                       # (R, I)
-        l_inst = leng[:, self._m_of_i]
-        # pad rows order strictly after every real request
-        pred_len_max = jnp.where(row_valid, leng.max(axis=1), -1e30)
+        with jax.named_scope("knn"):
+            qual, leng = topk_soft_lookup(emb, self._x, self._xsq,
+                                          self._qual, self._leng,
+                                          self._k, self._eps)  # (R, M)
+            q_inst = qual[:, self._m_of_i]                     # (R, I)
+            l_inst = leng[:, self._m_of_i]
+            # pad rows order strictly after every real request
+            pred_len_max = jnp.where(row_valid, leng.max(axis=1), -1e30)
 
         # 2. state-dependent TPOT: all per-tier heads in one packed gather
-        b_eff = jnp.maximum(b, 1.0)
-        ctx_eff = jnp.maximum(ctx, 64.0)
-        if self._use_gbm:
-            tpot = jnp.maximum(
-                predict_roster(self._gbm_tables,
-                               [b_eff, d, ctx_eff, b_eff * ctx_eff])[0],
-                1e-4)
-        else:
-            tpot = self._nominal
+        with jax.named_scope("tpot"):
+            b_eff = jnp.maximum(b, 1.0)
+            ctx_eff = jnp.maximum(ctx, 64.0)
+            if self._use_gbm:
+                tpot = jnp.maximum(
+                    predict_roster(self._gbm_tables,
+                                   [b_eff, d, ctx_eff, b_eff * ctx_eff])[0],
+                    1e-4)
+            else:
+                tpot = self._nominal
 
         # 3. Eq. 2 admission over the alive roster
-        budgets = budgets.astype(jnp.float32)
-        len_in = len_in.astype(jnp.float32)
-        if self._budget_filter:
-            allowed, c_hat = admission_math(
-                budgets, len_in, l_inst, self._price_in, self._price_out,
-                jnp, valid=alive)
-        else:
-            c_hat = cost_matrix(len_in, l_inst, self._price_in,
-                                self._price_out, jnp)
-            allowed = jnp.broadcast_to(alive[None, :], c_hat.shape)
+        with jax.named_scope("admission"):
+            budgets = budgets.astype(jnp.float32)
+            len_in = len_in.astype(jnp.float32)
+            if self._budget_filter:
+                allowed, c_hat = admission_math(
+                    budgets, len_in, l_inst, self._price_in,
+                    self._price_out, jnp, valid=alive)
+            else:
+                c_hat = cost_matrix(len_in, l_inst, self._price_in,
+                                    self._price_out, jnp)
+                allowed = jnp.broadcast_to(alive[None, :], c_hat.shape)
 
-        # 3b. prefix-affinity: matched-fraction hit against the mirrored
-        # per-instance sig planes, zeroed for dead/quarantined columns
-        # (alive is the same mask Eq. 2 admission uses, so a quarantined
-        # instance can neither be picked NOR attract affinity credit).
-        # Python-level branch: w_aff == 0 compiles the term out and the
-        # dummy psig/sig_plane inputs are dead.
-        if self._w_aff > 0.0:
-            hit = hit_fraction(psig, len_in, sig_plane.T, jnp)
-            hit = jnp.where(alive[None, :], hit, jnp.float32(0.0))
-            aff = jnp.float32(self._w_aff) * hit
-        else:
-            aff = None
+            # 3b. prefix-affinity: matched-fraction hit against the
+            # mirrored per-instance sig planes, zeroed for
+            # dead/quarantined columns (alive is the same mask Eq. 2
+            # admission uses, so a quarantined instance can neither be
+            # picked NOR attract affinity credit). Python-level branch:
+            # w_aff == 0 compiles the term out and the dummy
+            # psig/sig_plane inputs are dead.
+            if self._w_aff > 0.0:
+                hit = hit_fraction(psig, len_in, sig_plane.T, jnp)
+                hit = jnp.where(alive[None, :], hit, jnp.float32(0.0))
+                aff = jnp.float32(self._w_aff) * hit
+            else:
+                aff = None
 
         # 4. LPT order + dead-reckoned greedy scan (Eq. 1 per request)
-        if self._lpt:
-            order = jnp.argsort(-pred_len_max, stable=True)
-        else:
-            order = jnp.arange(q_inst.shape[0])
-        choice, est_T, (d1, b1, f1) = self._scan(
-            order, q_inst, c_hat, l_inst, tpot, d, b_eff, free,
-            allowed, row_valid, aff)
-        l_chosen = jnp.take_along_axis(l_inst, choice[:, None],
-                                       axis=1)[:, 0]
+        with jax.named_scope("scan"):
+            if self._lpt:
+                order = jnp.argsort(-pred_len_max, stable=True)
+            else:
+                order = jnp.arange(q_inst.shape[0])
+            choice, est_T, (d1, b1, f1) = self._scan(
+                order, q_inst, c_hat, l_inst, tpot, d, b_eff, free,
+                allowed, row_valid, aff)
+            l_chosen = jnp.take_along_axis(l_inst, choice[:, None],
+                                           axis=1)[:, 0]
         # the refreshed pre-scan mirror (d, b, free, ctx) is the carried
         # state; (d1, b1, f1) is the post-scan dead-reckoned view, kept
         # for diagnostics/invariant checks only — the next batch reseeds
@@ -448,10 +484,8 @@ class FusedHotPath:
         telemetry has not moved between them (the mirror reseeds from
         telemetry per dispatch, never across-batch dead-reckoning), so
         coalescing only amortizes launch/sync overhead."""
-        d = d.at[didx].set(dd, mode="drop")
-        b = b.at[didx].set(db, mode="drop")
-        free = free.at[didx].set(dfree, mode="drop")
-        ctx = ctx.at[didx].set(dctx, mode="drop")
+        d, b, free, ctx = _scatter_delta(d, b, free, ctx,
+                                         didx, dd, db, dfree, dctx)
         choice, est_T, l_chosen, d1, b1, f1 = self._mega_stages(
             emb, row_valid, budgets, len_in, d, b, free, ctx, alive,
             psig, sig_plane)
@@ -544,6 +578,8 @@ class FusedHotPath:
         if self._state is not None and tel is self._seen_tel:
             if tel.roster_version == self._seen_roster:
                 rows = tel.dirty_rows(self._seen_version)
+                st["dirty_checks"] += 1
+                st["dirty_rows_seen"] += len(rows)
                 if 2 * len(rows) > self._n_real:
                     rows = None              # mostly dirty: reseed outright
             else:
@@ -562,6 +598,7 @@ class FusedHotPath:
                 for x in (tel.pending, tel.batch, tel.free, tel.ctx))
             self._alive_dev = jnp.asarray(
                 self._pad_i(np.asarray(tel.alive), fill=False))
+            st["uploads"] += len(self._state) + 1
             st["full_reseed"] += 1
             return self._state + (self._alive_dev,) + self._empty_delta
         K = len(rows)
@@ -591,40 +628,48 @@ class FusedHotPath:
             "RequestColumns.ensure_embeddings must run before decide"
         st = self.stats
         st["calls"] += 1
-        t0 = time.perf_counter()
-        R = len(rows)
-        s = self._stage_buffers(bucket_pow2(R))
-        np.take(cols.prompt_row, rows, out=s["prow"][:R])
-        np.take(cols.emb, s["prow"][:R], axis=0, out=s["emb"][:R])
-        s["emb"][R:] = 0.0
-        s["budgets"][:R] = cols.budget[rows]
-        s["budgets"][R:] = np.nan
-        s["len_in"][:R] = cols.len_in[rows]
-        s["len_in"][R:] = 0.0
-        s["rv"][:R] = True
-        s["rv"][R:] = False
-        if self._w_aff > 0.0:
-            np.take(cols.prefix_sig, s["prow"][:R], axis=0,
-                    out=s["psig"][:R])
-            s["psig"][R:] = 0
-            self._pflip ^= 1
-            plane = self._pstage[self._pflip]
-            plane[:self._n_real] = tel.prefix_sig
-            psig = s["psig"]
-        else:
-            psig, plane = self._dummy_psig, self._dummy_plane
-        t1 = time.perf_counter()
-        state_args = self._sync_state(tel)
-        t2 = time.perf_counter()
-        out = self._step(s["emb"], s["rv"], s["budgets"], s["len_in"],
-                         *state_args, psig, plane)
-        self._state = out[3:7]               # refreshed pre-scan mirror
+        with span("rb.stage", st, "stage_s") as staged:
+            R = len(rows)
+            s = self._stage_buffers(bucket_pow2(R))
+            np.take(cols.prompt_row, rows, out=s["prow"][:R])
+            np.take(cols.emb, s["prow"][:R], axis=0, out=s["emb"][:R])
+            s["emb"][R:] = 0.0
+            s["budgets"][:R] = cols.budget[rows]
+            s["budgets"][R:] = np.nan
+            s["len_in"][:R] = cols.len_in[rows]
+            s["len_in"][R:] = 0.0
+            s["rv"][:R] = True
+            s["rv"][R:] = False
+            if self._w_aff > 0.0:
+                np.take(cols.prefix_sig, s["prow"][:R], axis=0,
+                        out=s["psig"][:R])
+                s["psig"][R:] = 0
+                self._pflip ^= 1
+                plane = self._pstage[self._pflip]
+                plane[:self._n_real] = tel.prefix_sig
+                psig = s["psig"]
+            else:
+                psig, plane = self._dummy_psig, self._dummy_plane
+        out = self._sync_and_dispatch(self._step, staged, s, psig, plane,
+                                      tel)
         self._post_state = out[7:10]         # post-scan (diagnostics)
-        t3 = time.perf_counter()
-        st["stage_s"] += t1 - t0
-        st["host_s"] += t2 - t0
-        st["dispatch_s"] += t3 - t2
         return LazyDecision(out[0], out[2], R, st)
+
+    def _sync_and_dispatch(self, step, staged: span, s, psig, plane, tel):
+        """Sync the telemetry mirror and launch `step` on the staged
+        buffers; returns its outputs. `staged` is the staging span, so
+        `host_s` gets staging plus sync."""
+        st = self.stats
+        with span("rb.telemetry", st, "telemetry_s") as synced:
+            state_args = self._sync_state(tel)
+        st["host_s"] += staged.seconds + synced.seconds
+        args = (s["emb"], s["rv"], s["budgets"], s["len_in"],
+                *state_args, psig, plane)
+        st["uploads"] += _host_arrays(args)
+        with span("rb.dispatch", st, "dispatch_s"):
+            out = step(*args)
+            self._state = out[3:7]           # refreshed pre-scan mirror
+        return out
 
     def _multi_buffers(self, Kb: int, Rb: int) -> Dict[str, np.ndarray]:
         """Double-buffered host staging for the (pow2 K, pow2 R)
@@ -638,7 +683,7 @@ class FusedHotPath:
                        "budgets": np.full((Kb, Rb), np.nan, np.float32),
                        "len_in": np.zeros((Kb, Rb), np.float32),
                        "rv": np.zeros((Kb, Rb), bool),
-                       "dummy_psig": np.zeros((Kb, 1, 1), np.int32)}
+                       "dummy_psig": jnp.zeros((Kb, 1, 1), jnp.int32)}
                 if self._w_aff > 0.0:
                     buf["psig"] = np.zeros((Kb, Rb, SIG_WIDTH), np.int32)
                 return buf
@@ -669,7 +714,21 @@ class FusedHotPath:
         K = len(batches)
         st["calls"] += K
         st["multi_dispatch"] = st.get("multi_dispatch", 0) + 1
-        t0 = time.perf_counter()
+        with span("rb.stage", st, "stage_s") as staged:
+            s, psig, plane = self._stage_multi(batches, tel)
+        out = self._sync_and_dispatch(self._step_multi, staged, s, psig,
+                                      plane, tel)
+        # diagnostics: the LAST real window's post-scan view (windows
+        # are independent; pad windows apply no updates)
+        self._post_state = tuple(o[K - 1] for o in out[7:10])
+        return [LazyDecision(out[0][ki], out[2][ki],
+                             len(batches[ki][1]), st)
+                for ki in range(K)]
+
+    def _stage_multi(self, batches, tel):
+        """Gather K windows into the (pow2 K, pow2 R) staging set;
+        returns (buffers, psig, sig plane)."""
+        K = len(batches)
         Kb = bucket_pow2(K, lo=1)
         Rb = bucket_pow2(max(len(rows) for _, rows in batches))
         s = self._multi_buffers(Kb, Rb)
@@ -705,22 +764,7 @@ class FusedHotPath:
             psig = s["psig"]
         else:
             psig, plane = s["dummy_psig"], self._dummy_plane
-        t1 = time.perf_counter()
-        state_args = self._sync_state(tel)
-        t2 = time.perf_counter()
-        out = self._step_multi(s["emb"], s["rv"], s["budgets"],
-                               s["len_in"], *state_args, psig, plane)
-        self._state = out[3:7]               # refreshed pre-scan mirror
-        # diagnostics: the LAST real window's post-scan view (windows
-        # are independent; pad windows apply no updates)
-        self._post_state = tuple(o[K - 1] for o in out[7:10])
-        t3 = time.perf_counter()
-        st["stage_s"] += t1 - t0
-        st["host_s"] += t2 - t0
-        st["dispatch_s"] += t3 - t2
-        return [LazyDecision(out[0][ki], out[2][ki],
-                             len(batches[ki][1]), st)
-                for ki in range(K)]
+        return s, psig, plane
 
     def decide(self, batch, tel) -> Tuple[np.ndarray, np.ndarray]:
         """Legacy AoS entry (direct callers, tests): derive the column

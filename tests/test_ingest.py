@@ -340,3 +340,132 @@ def test_dirty_row_tracking(small_ctx):
     assert 3 in tel.dirty_rows(v1)                 # revive rewrites row 3
     tel.mark_all_dirty()
     assert len(tel.dirty_rows(v1)) == len(tel.alive)
+
+
+# -- spans and counters of the runner -----------------------------------------
+
+# host arrays a window hands the device: the four staging buffers (emb,
+# row_valid, budgets, len_in) and the five delta lanes (idx, d, b, free,
+# ctx) are numpy arguments of the jitted step; the affinity dummies are
+# device-resident; a reseed uploads the four mirror planes and alive
+DELTA_UPLOADS = 4 + 5
+RESEED_UPLOADS = DELTA_UPLOADS + 4 + 1
+
+
+def test_host_seconds_are_stage_plus_telemetry(small_ctx):
+    """`host_s` keeps its meaning (staging and telemetry sync): the sum
+    of the two spans it is made of; the fetch's wait and copy land in
+    `device_s` and `sync_s`."""
+    sim = _loaded_sim(small_ctx)
+    fp = _runner(small_ctx, sim)
+    for R in (5, 9, 16):
+        fp.decide(_batch(small_ctx, R=R, seed=R), sim.tel)
+    st = fp.stats
+    assert st["calls"] == 3
+    for key in ("stage_s", "telemetry_s", "dispatch_s", "device_s",
+                "sync_s"):
+        assert st[key] > 0.0, key
+    assert st["host_s"] == pytest.approx(st["stage_s"] + st["telemetry_s"],
+                                         rel=1e-12)
+
+
+def test_uploads_count_the_host_arrays_of_each_window(small_ctx):
+    """A reseed window hands over 14 host arrays, a delta or carry
+    window 9; the counter agrees with the arguments actually given."""
+    sim = _loaded_sim(small_ctx)
+    tel = sim.tel
+    fp = _runner(small_ctx, sim)
+    handed = []
+    step = fp._step
+
+    def counting_step(*args):
+        handed.append(sum(isinstance(a, np.ndarray) for a in args))
+        return step(*args)
+
+    fp._step = counting_step
+    fp.decide(_batch(small_ctx, R=8, seed=1), tel)          # reseed
+    assert fp.stats["uploads"] == RESEED_UPLOADS
+    tel.write(4, pending=10.0, batch=2, free=1, ctx=300.0, queue=0, t=1.0)
+    fp.decide(_batch(small_ctx, R=8, seed=2), tel)          # delta
+    assert fp.stats["uploads"] == RESEED_UPLOADS + DELTA_UPLOADS
+    fp.decide(_batch(small_ctx, R=3, seed=3), tel)          # carry
+    assert fp.stats["uploads"] == RESEED_UPLOADS + 2 * DELTA_UPLOADS
+    assert (fp.stats["full_reseed"], fp.stats["delta_sync"],
+            fp.stats["carry"]) == (1, 1, 1)
+    assert handed == [DELTA_UPLOADS] * 3
+
+
+def test_dirty_rows_are_counted_before_the_mostly_dirty_rule(small_ctx):
+    """Each sync that reads `tel.dirty_rows` counts once, with every
+    dirty row it found, whether it then scatters or reseeds."""
+    sim = _loaded_sim(small_ctx)
+    tel = sim.tel
+    n = len(sim.instances)
+    fp = _runner(small_ctx, sim)
+    b = _batch(small_ctx, R=8, seed=1)
+    fp.decide(b, tel)                     # first sync: no read, reseed
+    assert (fp.stats["dirty_checks"], fp.stats["dirty_rows_seen"]) == (0, 0)
+    for slot in (1, 5, 6):
+        tel.write(slot, pending=5.0, batch=1, free=1, ctx=100.0, queue=0,
+                  t=1.0)
+    fp.decide(b, tel)                     # 3 dirty rows: delta
+    assert (fp.stats["dirty_checks"], fp.stats["dirty_rows_seen"]) == (1, 3)
+    tel.mark_all_dirty()
+    fp.decide(b, tel)                     # all dirty: reseed outright
+    assert (fp.stats["dirty_checks"],
+            fp.stats["dirty_rows_seen"]) == (2, 3 + n)
+    fp.decide(b, tel)                     # nothing written: carry
+    assert (fp.stats["dirty_checks"],
+            fp.stats["dirty_rows_seen"]) == (3, 3 + n)
+    assert (fp.stats["full_reseed"], fp.stats["delta_sync"],
+            fp.stats["carry"]) == (2, 1, 1)
+
+
+COUNTERS = ("full_reseed", "delta_sync", "delta_rows", "carry",
+            "roster_reseed", "uploads", "dirty_checks", "dirty_rows_seen")
+
+
+def _counter_sequence(ctx):
+    """Reseed, carry, delta, roster event, mostly dirty: the counters
+    after each decision."""
+    sim = _loaded_sim(ctx)
+    tel = sim.tel
+    fp = _runner(ctx, sim)
+    seen = []
+
+    def go(seed):
+        fp.decide(_batch(ctx, R=8, seed=seed), tel)
+        seen.append(tuple(fp.stats[k] for k in COUNTERS))
+
+    go(1)
+    go(2)
+    tel.write(3, pending=7.0, batch=1, free=1, ctx=50.0, queue=0, t=1.0)
+    go(3)
+    sim.instances[2].fail()
+    go(4)
+    tel.mark_all_dirty()
+    go(5)
+    return seen
+
+
+@pytest.mark.parametrize("profiled", [False, True])
+def test_spans_leave_the_counters_as_they_were(small_ctx, tmp_path,
+                                               profiled):
+    """With or without a profiler session the counters read the same,
+    decision by decision, as the sync rules dictate."""
+    import jax
+    if profiled:
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        seen = _counter_sequence(small_ctx)
+    finally:
+        if profiled:
+            jax.profiler.stop_trace()
+    n = len(_loaded_sim(small_ctx).instances)
+    R, D = RESEED_UPLOADS, DELTA_UPLOADS
+    # (full, delta, rows, carry, roster, uploads, checks, seen)
+    assert seen == [(1, 0, 0, 0, 0, R, 0, 0),
+                    (1, 0, 0, 1, 0, R + D, 1, 0),
+                    (1, 1, 1, 1, 0, R + 2 * D, 2, 1),
+                    (2, 1, 1, 1, 1, 2 * R + 2 * D, 2, 1),
+                    (3, 1, 1, 1, 1, 3 * R + 2 * D, 3, 1 + n)]

@@ -185,7 +185,13 @@ def test_multi_window_matches_separate_dispatches(small_ctx):
     views = [BatchView(c) for c in cuts]
     pol = _policy(small_ctx, sim, window_coalesce=4)
     multi = [r.fetch() for r in pol.assign_windows(views, sim)]
-    assert pol._fused.stats.get("multi_dispatch") == 1
+    st = pol._fused.stats
+    assert st.get("multi_dispatch") == 1 and st["calls"] == 4
+    # one staging pass, one sync, one launch for the four windows: the
+    # four staging sets and five delta lanes, plus the reseed's five
+    assert st["uploads"] == 4 + 5 + 5 and st["full_reseed"] == 1
+    assert st["host_s"] == pytest.approx(st["stage_s"] + st["telemetry_s"],
+                                         rel=1e-12)
     single = _policy(small_ctx, sim)
     sep = [single.assign(v, sim).fetch() for v in views]
     for (cm, lm), (cs, ls) in zip(multi, sep):
