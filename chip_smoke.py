@@ -71,7 +71,8 @@ def device_phase(n_chips: int):
 def runner_counters(rb) -> dict:
     """The fused runner's counters and its host split: seconds in each
     `rb.*` span (staging, telemetry sync, dispatch, the wait for the
-    device, the copy) and the host arrays it handed the device."""
+    device, the copy), the host arrays it handed the device and the
+    device-to-host transfers it started."""
     fused = rb._fused
     st = fused.stats
     return {"compile_count": fused.compile_count(),
@@ -81,7 +82,7 @@ def runner_counters(rb) -> dict:
             **{k: f"{st[k]:.3f}" for k in ("stage_s", "telemetry_s",
                                             "dispatch_s", "device_s",
                                             "sync_s")},
-            "uploads": st["uploads"]}
+            "uploads": st["uploads"], "d2h": st["d2h"]}
 
 
 def served_cell(phase: str, m: dict, rb, n: int, seconds: float,

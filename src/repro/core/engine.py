@@ -140,8 +140,9 @@ class AssignmentResult:
     possibly-deferred (choice, l_chosen) pair. `choice[r]` indexes
     `instances`; `l_chosen[r]` is the predicted output length at the
     chosen instance. The payload exposes `fetch()` — the fused
-    backend hands a `LazyDecision` (device arrays, transfer deferred
-    to the dispatch point), everything else a `Ready`."""
+    backend hands a `LazyDecision` (device arrays whose host transfer
+    starts at the launch, fetched at the dispatch point), everything
+    else a `Ready`."""
 
     __slots__ = ("instances", "_res")
 

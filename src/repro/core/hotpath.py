@@ -41,8 +41,11 @@ encoder forward over the padded token matrix, and a blocking
   * **async dispatch** — ``decide_cols`` returns a ``LazyDecision``
     whose host fetch is deferred to the scheduler's dispatch point, so
     residual accounting and next-batch staging overlap device
-    execution. The carried mirror chains batch-to-batch on device
-    through donated buffers without a host round trip.
+    execution. The host transfers of the padded choice and l_chosen
+    start at the launch and queue behind the program; the fetch slices
+    to R in numpy, so no program but the step runs per batch. The
+    carried mirror chains batch-to-batch on device through donated
+    buffers without a host round trip.
 
 Batch size R and roster size I are still bucketed to powers of two
 (`bucket_pow2`) for O(log R · log I) compile variants; roster pad
@@ -76,12 +79,14 @@ def _new_stats() -> Dict:
     two together), `dispatch_s` (rb.dispatch), `device_s` (rb.wait) and
     `sync_s` (rb.copy). Counters: `uploads`, the host arrays the runner
     hands to the device (each numpy argument of the jitted step, and
-    each array a reseed uploads); `dirty_checks` and `dirty_rows_seen`,
-    the syncs that read `tel.dirty_rows` and the rows they found dirty,
-    before the mostly-dirty rule decides; then how each sync ended."""
+    each array a reseed uploads); `d2h`, the device-to-host transfers
+    it starts (two per dispatch: the padded choice and l_chosen);
+    `dirty_checks` and `dirty_rows_seen`, the syncs that read
+    `tel.dirty_rows` and the rows they found dirty, before the
+    mostly-dirty rule decides; then how each sync ended."""
     return {"calls": 0, "host_s": 0.0, "stage_s": 0.0, "telemetry_s": 0.0,
             "dispatch_s": 0.0, "device_s": 0.0, "sync_s": 0.0,
-            "uploads": 0, "dirty_checks": 0, "dirty_rows_seen": 0,
+            "uploads": 0, "d2h": 0, "dirty_checks": 0, "dirty_rows_seen": 0,
             "full_reseed": 0,
             "roster_reseed": 0,        # full reseeds caused by roster churn
             "delta_sync": 0, "delta_rows": 0, "carry": 0}
@@ -104,20 +109,24 @@ def _scatter_delta(d, b, free, ctx, didx, dd, db, dfree, dctx):
 
 
 class LazyDecision:
-    """An in-flight fused decision: device arrays whose host transfer is
-    deferred until the caller actually needs the values (the dispatch
-    point). `fetch()` blocks on the device program, slices off the
-    shape-padding rows and returns numpy — idempotently, so diagnostics
-    may re-fetch. This is the fused policy's `AssignmentResult` payload
-    (`repro.core.engine`): the engine's windowed dispatch overlaps its
-    host bookkeeping with the device program and fetches last."""
+    """An in-flight fused decision: the step's padded device outputs,
+    whose host transfers the runner started at the launch, fetched only
+    when the caller needs the values (the dispatch point). `fetch()`
+    waits for the transfers, then slices off the shape-padding rows (and
+    picks window `k` of a multi-window dispatch) in numpy — no device
+    program — and returns fresh arrays, idempotently, so diagnostics may
+    re-fetch. The K decisions of one multi-window dispatch share its
+    arrays and so its one transfer. This is the fused policy's
+    `AssignmentResult` payload (`repro.core.engine`): the engine's
+    windowed dispatch overlaps its host bookkeeping with the device
+    program and fetches last."""
 
-    __slots__ = ("_choice", "_l", "_R", "_stats", "_out")
+    __slots__ = ("_choice", "_l", "_rows", "_stats", "_out")
 
-    def __init__(self, choice, l_chosen, R: int, stats: Dict):
+    def __init__(self, choice, l_chosen, rows, stats: Dict):
         self._choice = choice
         self._l = l_chosen
-        self._R = R
+        self._rows = rows        # numpy index: `np.s_[:R]` or `np.s_[k, :R]`
         self._stats = stats
         self._out: Optional[Tuple[np.ndarray, np.ndarray]] = None
 
@@ -126,11 +135,11 @@ class LazyDecision:
             st = self._stats
             with span("rb.fetch"):
                 with span("rb.wait", st, "device_s"):
-                    jax.block_until_ready((self._choice, self._l))
+                    choice, l_chosen = jax.device_get((self._choice,
+                                                       self._l))
                 with span("rb.copy", st, "sync_s"):
-                    self._out = (
-                        np.asarray(self._choice[:self._R], np.int64),
-                        np.asarray(self._l[:self._R], np.float64))
+                    self._out = (np.array(choice[self._rows], np.int64),
+                                 np.array(l_chosen[self._rows], np.float64))
         return self._out
 
 
@@ -653,7 +662,7 @@ class FusedHotPath:
         out = self._sync_and_dispatch(self._step, staged, s, psig, plane,
                                       tel)
         self._post_state = out[7:10]         # post-scan (diagnostics)
-        return LazyDecision(out[0], out[2], R, st)
+        return LazyDecision(out[0], out[2], np.s_[:R], st)
 
     def _sync_and_dispatch(self, step, staged: span, s, psig, plane, tel):
         """Sync the telemetry mirror and launch `step` on the staged
@@ -669,6 +678,11 @@ class FusedHotPath:
         with span("rb.dispatch", st, "dispatch_s"):
             out = step(*args)
             self._state = out[3:7]           # refreshed pre-scan mirror
+            # the fetch's transfers queue behind the program now, so
+            # they overlap each other and the host's work until the fetch
+            out[0].copy_to_host_async()
+            out[2].copy_to_host_async()
+            st["d2h"] += 2
         return out
 
     def _multi_buffers(self, Kb: int, Rb: int) -> Dict[str, np.ndarray]:
@@ -721,9 +735,8 @@ class FusedHotPath:
         # diagnostics: the LAST real window's post-scan view (windows
         # are independent; pad windows apply no updates)
         self._post_state = tuple(o[K - 1] for o in out[7:10])
-        return [LazyDecision(out[0][ki], out[2][ki],
-                             len(batches[ki][1]), st)
-                for ki in range(K)]
+        return [LazyDecision(out[0], out[2], np.s_[ki, :len(rows)], st)
+                for ki, (_, rows) in enumerate(batches)]
 
     def _stage_multi(self, batches, tel):
         """Gather K windows into the (pow2 K, pow2 R) staging set;

@@ -238,7 +238,7 @@ class RouteBalancePolicy(SchedulingPolicy):
     def assign(self, batch: BatchView, cluster: ClusterSim
                ) -> AssignmentResult:
         """Dispatch the per-batch decision; the fused backend's payload
-        is a LazyDecision (device arrays, deferred transfer); the
+        is a LazyDecision (device arrays, fetched later); the
         staged backends' is already numpy."""
         if self.cfg.decision_backend in ("fused", "megakernel"):
             instances, res = self._decide_fused(batch, cluster)

@@ -14,8 +14,9 @@ Span names describe the system, under ``rb.``:
 - decision runner (``core/hotpath.py``): ``rb.stage`` (gathers into the
   staging buffers), ``rb.telemetry`` (the dirty-row read, then the
   reseed uploads or the delta fill), ``rb.dispatch`` (the jitted step:
-  argument transfers and the launch), ``rb.fetch`` with ``rb.wait``
-  (``block_until_ready``) and ``rb.copy`` (slice and host copy) inside;
+  argument transfers, the launch and the start of the result's host
+  transfers), ``rb.fetch`` with ``rb.wait`` (until the transfers are
+  complete) and ``rb.copy`` (the numpy slice and cast) inside;
 - serving engine (``core/engine.py``): ``rb.window`` around one fired
   window, with ``rb.assign`` (the policy call) and ``rb.submit`` (the
   loop that submits the decided requests) inside.

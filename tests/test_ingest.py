@@ -1,7 +1,7 @@
 """Zero-allocation host path: SoA ingest, staging-buffer reuse,
 incremental telemetry deltas, and async double-buffered dispatch.
 
-Four contracts from the host-path rebuild (PR 4):
+Five contracts of the host path:
 
   * **ingest** — `RequestColumns` mirrors the AoS request fields
     exactly (dtypes included), the memoized per-prompt embedding column
@@ -19,7 +19,10 @@ Four contracts from the host-path rebuild (PR 4):
   * **async dispatch** — deferring the result fetch to the dispatch
     point changes nothing observable: full cluster runs through an
     explicit fail/straggle/recover `FailureEvent` schedule land on the
-    staged backends' exact trajectories.
+    staged backends' exact trajectories;
+  * **fetch** — the runner starts one host transfer of each padded
+    result at the launch and the fetch slices it in numpy: the same
+    arrays as a device slice, bit for bit, and no program of its own.
 """
 import numpy as np
 import pytest
@@ -469,3 +472,109 @@ def test_spans_leave_the_counters_as_they_were(small_ctx, tmp_path,
                     (1, 1, 1, 1, 0, R + 2 * D, 2, 1),
                     (2, 1, 1, 1, 1, 2 * R + 2 * D, 2, 1),
                     (3, 1, 1, 1, 1, 3 * R + 2 * D, 3, 1 + n)]
+
+
+# -- the fetch: one padded transfer per result, sliced on the host ------------
+
+def _decide_capturing(fp, cols, rows, tel):
+    """`decide_cols`, also returning the step's padded outputs."""
+    seen = {}
+    step = fp._step
+
+    def keep(*args):
+        seen["out"] = step(*args)
+        return seen["out"]
+
+    fp._step = keep
+    try:
+        lz = fp.decide_cols(cols, rows, tel)
+    finally:
+        fp._step = step
+    return lz, seen["out"]
+
+
+def _bits(x):
+    return np.ascontiguousarray(x).view(np.uint8)
+
+
+@pytest.mark.parametrize("R", [1, 5, 8, 13, 16])
+def test_fetch_is_the_device_slice_bit_for_bit(small_ctx, R):
+    """`fetch()` returns fresh, writable int64 choice and float64
+    l_chosen equal bit for bit to slicing the step's outputs on the
+    device; a second fetch returns the same objects; each window starts
+    two device-to-host transfers."""
+    sim = _loaded_sim(small_ctx)
+    fp = _runner(small_ctx, sim)
+    cols, rows = batch_columns(_batch(small_ctx, R=R, seed=R))
+    cols.ensure_embeddings(small_ctx["bundle"].encoder)
+    for window in (1, 2):
+        lz, out = _decide_capturing(fp, cols, rows, sim.tel)
+        assert fp.stats["d2h"] == 2 * window
+        choice, l_chosen = lz.fetch()
+        want_c = np.asarray(out[0][:R], np.int64)
+        want_l = np.asarray(out[2][:R], np.float64)
+        assert choice.dtype == np.int64 and choice.shape == (R,)
+        assert l_chosen.dtype == np.float64 and l_chosen.shape == (R,)
+        np.testing.assert_array_equal(_bits(choice), _bits(want_c))
+        np.testing.assert_array_equal(_bits(l_chosen), _bits(want_l))
+        for a in (choice, l_chosen):
+            assert a.flags.writeable and a.flags.owndata
+        again = lz.fetch()
+        assert again[0] is choice and again[1] is l_chosen
+        assert fp.stats["d2h"] == 2 * window
+
+
+def test_multi_window_fetches_share_one_transfer(small_ctx):
+    """K windows of one multi-window dispatch fetch what K separate
+    `decide_cols` fetch, from the one pair of padded arrays: two
+    transfers for the dispatch, not two per window."""
+    sim = _loaded_sim(small_ctx)
+    cfg = RBConfig(decision_backend="megakernel")
+    multi = FusedHotPath(small_ctx["bundle"], sim.instances, cfg)
+    single = FusedHotPath(small_ctx["bundle"], sim.instances, cfg)
+    enc = small_ctx["bundle"].encoder
+    reqs = _batch(small_ctx, R=15, seed=4)
+    batches = []
+    for cut in (reqs[:5], reqs[5:8], reqs[8:15]):
+        cols, rows = batch_columns(cut)
+        cols.ensure_embeddings(enc)
+        batches.append((cols, rows))
+    lazies = multi.decide_cols_multi(batches, sim.tel)
+    assert multi.stats["multi_dispatch"] == 1
+    assert multi.stats["calls"] == 3 and multi.stats["d2h"] == 2
+    got = [lz.fetch() for lz in lazies]
+    assert multi.stats["d2h"] == 2
+    for (cols, rows), (cm, lm) in zip(batches, got):
+        cs, ls = single.decide_cols(cols, rows, sim.tel).fetch()
+        assert cm.shape == (len(rows),) and cm.dtype == np.int64
+        assert lm.dtype == np.float64 and lm.flags.owndata
+        np.testing.assert_array_equal(_bits(cm), _bits(cs))
+        np.testing.assert_array_equal(_bits(lm), _bits(ls))
+    assert single.stats["d2h"] == 2 * len(batches)
+    multi.decide_cols_multi(batches, sim.tel)
+    assert multi.stats["d2h"] == 4
+
+
+@pytest.mark.parametrize("warm,new", [(5, 7), (9, 11), (17, 27)])
+def test_a_new_size_in_a_warm_bucket_compiles_nothing(small_ctx, warm,
+                                                      new):
+    """Once a pow2 bucket's step is compiled, deciding and fetching
+    another batch size in that bucket compiles no program: the fetch
+    slices on the host and launches nothing on the device."""
+    import jax.monitoring as monitoring
+    sim = _loaded_sim(small_ctx)
+    fp = _runner(small_ctx, sim)
+    fp.decide(_batch(small_ctx, R=warm, seed=warm), sim.tel)
+    compiled = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        choice, _ = fp.decide(_batch(small_ctx, R=new, seed=new), sim.tel)
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert choice.shape == (new,)
+    assert compiled == []
