@@ -11,7 +11,11 @@ A mix (`bench/traffic/<name>.json`) holds:
     horizon_s     simulated seconds of trace generated (the window
                   stops early, and says so, if it reaches the end)
 
-and whatever parameters its process reads (`cv` for `gamma`). A process
+and whatever parameters its process reads (`cv` for `gamma`): that is
+the one-shot stream, one test prompt per arrival. A mix may also hold a
+`sessions` block, a stream of multi-turn chat conversations whose turns
+share growing prefixes (`bench/sessions.py` gives its keys and law);
+the run merges the two streams by arrival time. A process
 module exposes `arrivals(mix, rng)`, the arrival times up to
 `horizon_s`, and may expose `schedule(sim, mix, rng)`, which pushes
 fleet events (failures, stragglers, recoveries) onto the simulator
@@ -54,6 +58,9 @@ class Mix:
                   fill_s=float(raw["fill_s"]),
                   horizon_s=float(raw["horizon_s"]), params=raw)
         process(mix.process)
+        if "sessions" in raw:
+            from .sessions import validate
+            validate(raw["sessions"], str(path))
         if not 0.0 < mix.fill_s < mix.horizon_s:
             raise ValueError(f"{path}: need 0 < fill_s < horizon_s")
         return mix

@@ -7,10 +7,13 @@ to one configuration, mix or metric lives in its own file.
 
 Set-up (timed as `setup_s`): the configuration's world, dataset and
 estimator bundle; the RouteBalance engine on the decision backend the
-configuration names (else the one the code selects); one decision at
-every batch size the trace can put in a window, so that every program
-the window runs (each pow2 batch bucket, and the fetch's slice of a
-result to its batch size) compiles or loads from the compile cache; any
+configuration names (else the one the code selects); the requests,
+drawn from the seed (`requests_for`): the mix's one-shot stream and,
+where the mix has a `sessions` block, its multi-turn chat stream
+(`bench/sessions.py`), merged by arrival time; one decision at every
+batch size the trace can put in a window, so that every program the
+window runs (the step of each pow2 batch bucket; the fetch slices its
+result on the host) compiles or loads from the compile cache; any
 fleet events the mix's process schedules; and `fill_s` simulated
 seconds of the trace replayed, unmeasured, so the window starts on a
 loaded fleet.
@@ -24,7 +27,8 @@ any program, is counted (`compiles_in_window`).
 The timed path is observed from outside: the policy's `assign` and the
 result's `fetch` are wrapped with the benchmark's own clock (decision
 latency), and the fused runner's `decide_cols`/`_step` are wrapped to
-keep each window's inputs and the program's outputs for the check.
+keep each window's inputs and the program's outputs for the check
+(with the affinity term on, also the instances' prefix sketches).
 The harness reads `_step`'s outputs by position: (choice, est_T,
 l_chosen, d, b, free, ctx, d1, b1, f1).
 """
@@ -43,6 +47,7 @@ import numpy as np
 
 from . import arrivals as traffic
 from . import check as checking
+from . import sessions
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
@@ -116,10 +121,11 @@ def fleet_tiers(config: dict):
     return out
 
 
-# the decision settings `bench/reference.py` implements
+# the decision settings `bench/reference.py` implements (besides any
+# affinity_weight in [0, 1])
 COVERED = {"latency_mode": ("full",), "lpt": (True,), "budget_filter": (True,),
-           "learned_tpot": (True,), "affinity_weight": (0.0,),
-           "shard_cells": (0, 1), "window_coalesce": (1,),
+           "learned_tpot": (True,), "shard_cells": (0, 1),
+           "window_coalesce": (1,),
            "decision_backend": ("fused", "megakernel")}
 
 
@@ -136,7 +142,48 @@ def decision_config(config: dict):
         if getattr(cfg, key) not in allowed:
             raise SystemExit(f"RBConfig.{key} = {getattr(cfg, key)!r}; the "
                              f"reference implements {allowed!r}")
+    if not 0.0 <= cfg.affinity_weight <= 1.0:
+        raise SystemExit(f"RBConfig.affinity_weight = "
+                         f"{cfg.affinity_weight!r}; the reference "
+                         f"implements [0.0, 1.0]")
     return cfg
+
+
+def requests_for(mix: traffic.Mix, ds, encoder,
+                 rng: np.random.Generator):
+    """(arrival times, requests) of a run, drawn from `rng`: the
+    one-shot stream (the seed deals the test prompts to its arrivals in
+    turn and draws a budget per request) and the chat stream's turns of
+    a `sessions` block (none without one) with a budget each, merged by
+    arrival time. Without sessions these are `make_requests`'s
+    requests for the one-shot stream, from the same draws."""
+    from repro.serving.request import Request, RequestColumns
+    t = traffic.arrivals(mix, rng)
+    order = rng.permutation(len(ds.test_idx))
+    dealt = dataclasses.replace(ds, test_idx=ds.test_idx[order])
+    budgets = traffic.budgets(mix, len(t), rng)
+    prompts, Q, L = dealt.split("test")
+    at, _, base, tokens = sessions.chat_turns(mix, prompts, rng)
+    budgets = np.concatenate([budgets, traffic.budgets(mix, len(at), rng)])
+    one_shot = [(prompts[i % len(prompts)], i % len(prompts))
+                for i in range(len(t))]
+    turns = [(dataclasses.replace(prompts[j], tokens=toks,
+                                  len_in=int(toks.size)), j)
+             for j, toks in zip(base, tokens)]
+    entries = one_shot + turns
+    t_all = np.concatenate([t, at])
+    merged = np.argsort(t_all, kind="stable")
+    reqs = []
+    for rid, e in enumerate(merged):
+        prompt, j = entries[e]
+        reqs.append(Request(
+            rid=rid, prompt=prompt, arrival=float(t_all[e]),
+            true_quality=Q[j], true_length=L[j],
+            budget=None if np.isnan(budgets[e]) else float(budgets[e])))
+    cols = RequestColumns.from_requests(reqs)
+    if encoder is not None:
+        cols.ensure_embeddings(encoder)
+    return t_all[merged], reqs
 
 
 @contextlib.contextmanager
@@ -167,11 +214,18 @@ def _span(name: str, on: bool):
         yield
 
 
+# host telemetry each window's check reads, as the program was handed it
+TELEMETRY = ("pending", "batch", "free", "ctx", "alive")
+
+
 class Probe:
     """Wraps the timed path from outside. While `on`: the benchmark's
     own host clock from the engine's call into the policy to the end of
     the fetch, per decision window; each window's inputs and the
-    program's outputs for the check; and, when `trace`, host spans."""
+    program's outputs for the check (where the configuration weighs
+    prefix affinity, also the instances' prefix sketch rows; the
+    window's token rows stay in its columns); and, when `trace`, host
+    spans."""
 
     def __init__(self, rb, runner, trace: bool,
                  fault: Optional[Callable] = None):
@@ -221,13 +275,14 @@ class Probe:
         self._wrap(policy, "assign", timed_assign)
 
         decide_cols = runner.decide_cols
+        kept = TELEMETRY + (("prefix_sig",)
+                            if rb.cfg.affinity_weight > 0.0 else ())
 
         def captured_decide_cols(cols, rows, tel):
             if probe.on:
                 probe._pending = {
                     "cols": cols, "rows": np.array(rows, np.int64),
-                    "tel": {k: np.array(getattr(tel, k)) for k in
-                            ("pending", "batch", "free", "ctx", "alive")}}
+                    "tel": {k: np.array(getattr(tel, k)) for k in kept}}
             return decide_cols(cols, rows, tel)
 
         self._wrap(runner, "decide_cols", captured_decide_cols)
@@ -345,14 +400,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         t_start: float, device: dict, fault: Optional[Callable] = None,
         info=print,
         setup: Optional[Setup] = None, mix: Optional[traffic.Mix] = None,
-        check: bool = True, control: bool = False):
+        check: bool = True, control: Optional[str] = None):
     """Set up, measure and check one run. Returns (result, record):
     the result object of the last output line, and what the metric
     readers read. `setup`/`mix` reuse a world and replace the cell's
-    mix (the knee sweep); `control` also returns the control's numbers
-    under result["control"]."""
+    mix (the knee sweep); `control`, a name of `check.CONTROLS`, also
+    returns that control's numbers under result["control"]."""
     import jax
-    from repro.core import BatchView, RouteBalance, make_requests
+    from repro.core import BatchView, RouteBalance
     from repro.serving.cluster import ClusterSim
 
     marks = {"start": time.perf_counter()}
@@ -364,22 +419,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     rb = RouteBalance(decision_config(config), bundle, tiers)
 
     tseed = traffic_seed(seed)
-    rng = np.random.default_rng(tseed)
     mix = mix or cell.mix
-    t = traffic.arrivals(mix, rng)
-    # the seed also deals the test prompts to the requests
-    order = rng.permutation(len(ds.test_idx))
-    dealt = dataclasses.replace(ds, test_idx=ds.test_idx[order])
-    reqs = make_requests(dealt, "test", t, budgets=traffic.budgets(mix, len(t),
-                                                                   rng),
-                         encoder=bundle.encoder)
+    t, reqs = requests_for(mix, ds, bundle.encoder,
+                           np.random.default_rng(tseed))
     cols = reqs[0].cols
     marks["requests"] = time.perf_counter()
 
     sim = ClusterSim(tiers, names)
     rb.attach(sim)
     # every batch size the trace can put in a window, once, before the
-    # fill: each pow2 bucket's step and each size's slice in the fetch
+    # fill, so that each pow2 bucket's step is compiled or loaded
     r_max = min(max(traffic.peak_window_count(t, WINDOW_MAX_S), 1),
                 len(reqs))
     for R in range(1, r_max + 1):
@@ -512,7 +561,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if control:
         result["control"] = checking.compare(
             fleet, probe.captured, np.random.default_rng(tseed + 1),
-            REF_REQUESTS, outputs=checking.control_outputs)
+            REF_REQUESTS, outputs=checking.CONTROLS[control])
         result["program"] = numbers
     result["checks"] = {k: {"value": c["value"], "limit": c["limit"]}
                         for k, c in checks.items()}

@@ -13,7 +13,8 @@ Three numbers are held to limits:
                 scoring, admission, the scan), else the larger relative
                 gap of the predicted output length (KNN neighbours,
                 weights, label mix) and of the predicted latency (GBM
-                TPOT, wait, dead reckoning) at the pick
+                TPOT, wait, dead reckoning, and the prefix-affinity
+                discount where the configuration weighs it) at the pick
     slot_miss   share of the compared windows whose dead-reckoned batch
                 or free slots after the scan differ from the
                 reference's anywhere: whole numbers, compared exactly
@@ -25,6 +26,13 @@ Three numbers are held to limits:
 
 The two state numbers also hold the telemetry the program synced to the
 device to the host's: both are taken against the host's telemetry.
+
+Where the configuration weighs prefix affinity, the reference hashes
+each compared request's prompt tokens itself and matches them against
+the instances' prefix sketches as the host held them at the decision;
+`hit_share`, the share of compared requests with a prefix hit at the
+program's pick, is printed beside the numbers (not held): it shows
+that the term did work.
 
 A percentile and not the widest gap for the requests: float32 rounding
 resolves a neighbour near-tie one way on the chip and the other in numpy
@@ -39,6 +47,7 @@ PERF.md gives the readings each was set from.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 from pathlib import Path
 from typing import Dict, List
@@ -81,7 +90,8 @@ def fleet_of(bundle, instances, config: dict) -> ref.Fleet:
         max_batch=np.array([i.tier.max_batch for i in instances], f32),
         price_in=np.array([i.tier.price_in for i in instances], f32),
         price_out=np.array([i.tier.price_out for i in instances], f32),
-        trees=trees, weights=tuple(config["decision"]["weights"]))
+        trees=trees, weights=tuple(config["decision"]["weights"]),
+        w_aff=float(config["decision"].get("affinity_weight", 0.0)))
 
 
 def load_limits(workload: str) -> Dict[str, float]:
@@ -111,16 +121,25 @@ def sample(captured: List[dict], rng: np.random.Generator,
     return [captured[j] for j in sorted(keep)]
 
 
-def window_inputs(c: dict, n_real: int) -> ref.Window:
+def window_inputs(c: dict, fleet: ref.Fleet) -> ref.Window:
+    """A captured window as the reference reads it over `fleet`'s
+    instances; the prompt tokens and prefix sketches only where the
+    fleet weighs prefix affinity."""
+    n_real = len(fleet.model_of)
     cols, rows, tel = c["cols"], c["rows"], c["tel"]
     prow = cols.prompt_row[rows]
-    return ref.Window(
+    w = ref.Window(
         emb=np.asarray(cols.emb[prow], np.float32),
         budget=np.asarray(cols.budget[rows], np.float64),
         len_in=np.asarray(cols.len_in[rows], np.float32),
         pending=tel["pending"][:n_real], batch=tel["batch"][:n_real],
         free=tel["free"][:n_real], ctx=tel["ctx"][:n_real],
         alive=tel["alive"][:n_real].astype(bool))
+    if fleet.w_aff > 0.0:
+        w.tokens = np.asarray(cols.tokens[prow])
+        w.tok_len = np.asarray(cols.tok_len[prow])
+        w.sketch = tel["prefix_sig"][:n_real]
+    return w
 
 
 def compare(fleet: ref.Fleet, captured: List[dict],
@@ -128,21 +147,20 @@ def compare(fleet: ref.Fleet, captured: List[dict],
             precision: str = "highest", outputs=None) -> Dict[str, float]:
     """The numbers over a seeded sample of the captured windows.
     `outputs`, when given, replaces the program's outputs per window
-    (the control puts the reference's own lower-precision answers
-    there)."""
+    (a control of `CONTROLS` puts the reference's own answers there)."""
     import jax
     picked = sample(captured, rng, cap)
     if not picked:
         return {"compared": 0}
     got = jax.device_get([c["out"] for c in picked])
     I = len(fleet.model_of)
-    wins = [window_inputs(c, I) for c in picked]
+    wins = [window_inputs(c, fleet) for c in picked]
     emb = np.concatenate([w.emb for w in wins])
     qual, leng = ref.knn_labels(emb, fleet, precision)
     if outputs is not None:
         got = outputs(fleet, wins)
     req_gaps, work_gaps = [], []
-    misses = flips = slot_misses = 0
+    misses = flips = slot_misses = hits = 0
     at = 0
     for w, out in zip(wins, got):
         choice, est, l_chosen, d1, b1, f1 = (np.asarray(o, np.float64)
@@ -157,6 +175,7 @@ def compare(fleet: ref.Fleet, captured: List[dict],
         g_lat = _rel(est[:R], dec.latency_at)
         misses += int(miss.sum())
         flips += int((g_len > FLIP).sum())
+        hits += int((dec.hit_at > 0).sum())
         req_gaps.append(np.where(miss, 1.0, np.maximum(g_len, g_lat)))
         live = w.alive
         before = w.pending.astype(np.float64)
@@ -166,11 +185,14 @@ def compare(fleet: ref.Fleet, captured: List[dict],
         slot_misses += int(np.any((b1[:I] != dec.batch)[live])
                            or np.any((f1[:I] != dec.free)[live]))
     gaps = np.concatenate(req_gaps)
-    return {"compared": int(len(gaps)), "windows": len(wins),
-            "decide_p99": pct(gaps, 99), "slot_miss": slot_misses / len(wins),
-            "pick_miss": misses / len(gaps), "len_flip": flips / len(gaps),
-            "decide_max": float(gaps.max()),
-            "work_gap": float(max(work_gaps))}
+    out = {"compared": int(len(gaps)), "windows": len(wins),
+           "decide_p99": pct(gaps, 99), "slot_miss": slot_misses / len(wins),
+           "pick_miss": misses / len(gaps), "len_flip": flips / len(gaps),
+           "decide_max": float(gaps.max()),
+           "work_gap": float(max(work_gaps))}
+    if fleet.w_aff > 0.0:
+        out["hit_share"] = hits / len(gaps)
+    return out
 
 
 def _rel(got: np.ndarray, want: np.ndarray) -> np.ndarray:
@@ -178,11 +200,10 @@ def _rel(got: np.ndarray, want: np.ndarray) -> np.ndarray:
     return np.abs(got - want) / np.maximum(np.abs(want), 1e-9)
 
 
-def control_outputs(fleet: ref.Fleet, wins: List[ref.Window],
-                    precision: str = "high"):
-    """The control in the program's place: the reference's own answers
-    per window with the KNN distance one precision step down, shaped as
-    the program's (choice, est, l_chosen, d1, b1, f1)."""
+def reference_outputs(fleet: ref.Fleet, wins: List[ref.Window],
+                      precision: str):
+    """The reference's own answers per window, shaped as the program's
+    (choice, est, l_chosen, d1, b1, f1)."""
     emb = np.concatenate([w.emb for w in wins])
     qual, leng = ref.knn_labels(emb, fleet, precision)
     out, at = [], 0
@@ -193,6 +214,17 @@ def control_outputs(fleet: ref.Fleet, wins: List[ref.Window],
         out.append((dec.pick, dec.latency_at, dec.length_at, dec.pending,
                     dec.batch, dec.free))
     return out
+
+
+# the controls, each the reference's own answers put in the program's
+# place (`bench/control.py --control <name>`): `knn_high` with the KNN
+# distance one precision step down (three-pass bfloat16); `affinity_off`
+# at the stated precision with the prefix-affinity term dropped
+CONTROLS = {
+    "knn_high": lambda fleet, wins: reference_outputs(fleet, wins, "high"),
+    "affinity_off": lambda fleet, wins: reference_outputs(
+        dataclasses.replace(fleet, w_aff=0.0), wins, "highest"),
+}
 
 
 def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> Dict:

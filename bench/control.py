@@ -6,12 +6,14 @@ on many seeds, in one process, at the cell's own size and load.
 
 For each seed, one run of the cell (one world for all) with a short
 window: the numbers of the program against the reference, and those of
-the control (the reference with its KNN distance one precision step
-down, put in the program's place) against the reference. One JSON line per
-seed on standard output. With `--fault <name>` the program runs with
-that fault of `bench/faults.py` planted in its outputs instead, and the
-line holds the numbers it reads. The benchmark's own runs do not run
-this.
+the control against the reference. The control, `--control <name>` of
+`bench/check.py`'s CONTROLS, is the reference's own answers put in the
+program's place: `knn_high` (the default) with its KNN distance one
+precision step down; `affinity_off` with the prefix-affinity term
+dropped. One JSON line per seed on standard output. With `--fault
+<name>` the program runs with that fault of `bench/faults.py` planted
+in its outputs instead, and the line holds the numbers it reads. The
+benchmark's own runs do not run this.
 """
 from __future__ import annotations
 
@@ -33,11 +35,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=6.0)
     ap.add_argument("--fault", default=None)
+    ap.add_argument("--control", default="knn_high")
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     from bench.cell import build, load_cell, run
+    from bench.check import CONTROLS
     from bench.faults import FAULTS
     from bench.run import device_or_exit, place_cache
+    if args.control not in CONTROLS:
+        ap.error(f"--control: one of {sorted(CONTROLS)}")
     place_cache()
     cell = load_cell(args.workload)
     device = device_or_exit(cell.chips)
@@ -45,12 +51,14 @@ def main(argv=None) -> int:
     fault = FAULTS[args.fault] if args.fault else None
     for seed in (int(s) for s in args.seeds.split(",")):
         res, _ = run(cell, seed, args.seconds, False, time.perf_counter(),
-                     device, setup=setup, control=fault is None,
+                     device, setup=setup,
+                     control=args.control if fault is None else None,
                      fault=fault, info=lambda s: None)
         line = {"workload": args.workload, "seed": seed,
                 "correct": res["correct"]}
         if fault is None:
-            line.update(program=res["program"], control=res["control"])
+            line.update(program=res["program"], control=res["control"],
+                        control_name=args.control)
         else:
             line.update(fault=args.fault, checks=res["checks"])
         print(json.dumps(line), flush=True)

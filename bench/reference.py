@@ -7,7 +7,8 @@ trees as lists of arrays, the roster's prices and slot counts, and the
 window's prompt embeddings, budgets and telemetry — never a table that
 the decision program packed for itself.
 
-One window, in four stages:
+One window, in four stages (3b only where the prefix-affinity weight is
+above 0):
 
 1. KNN: squared L2 distance of each prompt embedding to every index
    row, the k nearest (ties by row), inverse-distance weights, and the
@@ -16,10 +17,15 @@ One window, in four stages:
    (batch, pending, context, batch x context) and sums the leaves.
 3. Eq. 2 admission: estimated cost within budget; a request that fits
    nowhere keeps its cheapest live instance.
+3b. Prefix affinity: each request's prefix signatures (a rolling hash
+   per 16-token block) against each live instance's prefix sketch; the
+   leading run of matched blocks, in tokens and capped at the prompt's
+   length, as a share of that length (`hit_fraction`).
 4. LPT greedy: requests in descending order of their longest
    predicted output; each takes the highest Eq. 1 score (quality, cost
    and latency, normalised per request over its admitted instances,
-   snapped to a 2^-13 grid, ties to the lowest instance), and the
+   snapped to a 2^-13 grid, ties to the lowest instance; the latency
+   first scaled by 1 - w_aff x hit where affinity is on), and the
    picked instance's pending work, batch and free slots are dead-
    reckoned forward.
 
@@ -46,6 +52,9 @@ import numpy as np
 
 F32 = np.float32
 SCORE_GRID = F32(2.0 ** 13)
+BLOCK = 16                       # tokens per prefix signature
+SIG_COLS = 8                     # signatures per prompt: 128 tokens
+HASH_MULT = np.uint32(2654435761)
 
 
 @dataclasses.dataclass
@@ -75,6 +84,7 @@ class Fleet:
     price_out: np.ndarray    # (I,) float32
     trees: List[Trees]
     weights: Sequence[float]  # (w_quality, w_latency, w_cost)
+    w_aff: float = 0.0       # prefix-affinity weight, in [0, 1]
 
 
 @dataclasses.dataclass
@@ -88,6 +98,10 @@ class Window:
     free: np.ndarray
     ctx: np.ndarray
     alive: np.ndarray        # (I,) bool
+    # read only where the fleet's w_aff is above 0
+    tokens: Optional[np.ndarray] = None   # (R, L) prompt tokens, 0-padded
+    tok_len: Optional[np.ndarray] = None  # (R,) tokens per prompt
+    sketch: Optional[np.ndarray] = None   # (I, S) cached prefix signatures
 
 
 @dataclasses.dataclass
@@ -95,10 +109,12 @@ class Decision:
     """The reference's answer for one window, teacher-forced on `picks`."""
     pick: np.ndarray         # (R,) the reference's own choice per step
     length_at: np.ndarray    # (R,) predicted length at the program's pick
-    latency_at: np.ndarray   # (R,) predicted latency at the program's pick
+    latency_at: np.ndarray   # (R,) predicted latency at the program's
+    #                          pick, after the affinity discount
     pending: np.ndarray      # (I,) dead-reckoned state after the window
     batch: np.ndarray
     free: np.ndarray
+    hit_at: np.ndarray       # (R,) prefix hit share at the program's pick
 
 
 def _bf16_split(a: np.ndarray):
@@ -208,6 +224,46 @@ def _score(q, c, t, allowed, weights):
     return np.where(allowed, s, -np.inf)
 
 
+def signatures(tokens: np.ndarray, tok_len: np.ndarray) -> np.ndarray:
+    """(R, SIG_COLS) int32 prefix signatures. Column d is the rolling
+    hash h <- h * 2654435761 + token + 1 (uint32, wrapping, from h = 0)
+    over the first min(len, 16 (d + 1)) tokens, read as int32 with 0
+    taken to 1; or 0 where the prompt does not reach block d (len <=
+    16 d)."""
+    lens = np.minimum(np.asarray(tok_len, np.int64), BLOCK * SIG_COLS)
+    R = len(lens)
+    width = int(lens.max(initial=0))
+    h = np.zeros(R, np.uint32)
+    upto = np.zeros((R, max(width, 1)), np.uint32)   # hash of [0, t]
+    for t in range(width):
+        h = h * HASH_MULT + np.asarray(tokens[:, t]).astype(np.uint32) \
+            + np.uint32(1)
+        upto[:, t] = h
+    starts = BLOCK * np.arange(SIG_COLS)
+    last = np.minimum(lens[:, None], starts + BLOCK) - 1
+    reach = lens[:, None] > starts
+    sig = np.take_along_axis(upto, np.where(reach, last, 0), axis=1)
+    sig = sig.view(np.int32)
+    sig = np.where(sig == 0, 1, sig)
+    return np.where(reach, sig, 0).astype(np.int32)
+
+
+def hit_fraction(req_sig: np.ndarray, len_in: np.ndarray,
+                 sketch: np.ndarray, alive: np.ndarray) -> np.ndarray:
+    """(R, I) float32: for each request and instance, the leading run
+    of the request's signature columns present in the instance's
+    sketch row (a 0 signature never matches), in tokens (x 16), capped
+    at max(len_in, 1) and divided by it; 0 on dead instances."""
+    R, I = len(req_sig), len(sketch)
+    run = np.zeros((R, I), np.int64)
+    for i in range(I):
+        present = np.isin(req_sig, sketch[i]) & (req_sig != 0)
+        run[:, i] = np.cumprod(present, axis=1).sum(axis=1)
+    lenf = np.maximum(np.asarray(len_in, F32), F32(1.0))[:, None]
+    hit = np.minimum((run * BLOCK).astype(F32), lenf) / lenf
+    return np.where(np.asarray(alive, bool)[None, :], hit, F32(0.0))
+
+
 def decide(fleet: Fleet, w: Window, qual: np.ndarray, leng: np.ndarray,
            picks: Optional[np.ndarray] = None,
            lengths: Optional[np.ndarray] = None) -> Decision:
@@ -224,12 +280,19 @@ def decide(fleet: Fleet, w: Window, qual: np.ndarray, leng: np.ndarray,
     b = np.maximum(w.batch.astype(F32), F32(1.0))
     free = w.free.astype(F32).copy()
     b0 = b.copy()
+    hit = np.zeros((R, len(d)), F32)
+    if fleet.w_aff > 0.0:
+        hit = hit_fraction(signatures(w.tokens, w.tok_len), w.len_in,
+                           w.sketch, w.alive)
+        keep = F32(1.0) - F32(fleet.w_aff) * hit          # (R, I)
     mine = np.zeros(R, np.int64)
     length_at = np.zeros(R, F32)
     latency_at = np.zeros(R, F32)
     for r in order:
         wait = np.where(free > 0, F32(0.0), d / np.maximum(b, F32(1.0)))
         lat = per_token * np.maximum(b / b0, F32(1.0)) * (wait + l_inst[r])
+        if fleet.w_aff > 0.0:
+            lat = lat * keep[r]
         s = _score(q_inst[r], cost[r], lat, allowed[r], fleet.weights)
         mine[r] = int(np.argmax(s))
         p = mine[r] if picks is None else int(picks[r])
@@ -240,4 +303,5 @@ def decide(fleet: Fleet, w: Window, qual: np.ndarray, leng: np.ndarray,
         if free[p] > 0:
             free[p] = free[p] - F32(1.0)
             b[p] = min(b[p] + F32(1.0), fleet.max_batch[p])
-    return Decision(mine, length_at, latency_at, d, b, free)
+    return Decision(mine, length_at, latency_at, d, b, free,
+                    hit[np.arange(R), picks if picks is not None else mine])

@@ -6,7 +6,9 @@ where its outputs are produced (`bench/faults.py`), and sees `correct`
 come out false, on the number that is there to catch the fault. The
 exchange between chips cannot be left out: every cell runs on one chip.
 The control, the reference with its KNN distance at three-pass bfloat16
-put in the program's place, must fail the check too.
+put in the program's place, must fail the check too; and, on a session
+mix with the prefix-affinity term on, so must the reference with that
+term dropped.
 """
 from bench import check, faults
 
@@ -45,6 +47,17 @@ def test_an_altered_answer_fails(drive):
 
 def test_the_control_fails(drive, small):
     cell = small[0]
-    res, _ = drive(control=True, seconds=3.0)
+    res, _ = drive(control="knn_high", seconds=3.0)
     verdict = check.judge(res["control"], check.load_limits(cell.name))
     assert not all(v["ok"] for v in verdict.values()), verdict
+
+
+def test_the_affinity_control_fails(drive, sessions):
+    """A program that dropped the affinity term could not pass: the
+    reference without it fails `decide_p99` where the program passes
+    and its picks found cached prefixes."""
+    res, _ = drive(control="affinity_off", seconds=3.0, on=sessions)
+    assert res["correct"], res["checks"]
+    assert res["program"]["hit_share"] > 0.05, res["program"]
+    verdict = check.judge(res["control"], check.load_limits(sessions[0].name))
+    assert not verdict["decide_p99"]["ok"], verdict

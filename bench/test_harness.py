@@ -102,9 +102,110 @@ def test_decision_block_sets_the_backend():
     assert cfg.decision_backend == "megakernel"
     assert cfg.weights == tuple(block["weights"])
     assert cellmod.decision_config(c.config).decision_backend == "fused"
-    with pytest.raises(SystemExit, match="affinity_weight"):
+    with pytest.raises(SystemExit, match="lpt"):
         cellmod.decision_config(dict(
-            c.config, decision=dict(block, affinity_weight=0.5)))
+            c.config, decision=dict(block, lpt=False)))
+
+
+def test_decision_block_takes_an_affinity_weight_in_range():
+    c = cellmod.load_cell(CELLS[0])
+    block = dict(c.config["decision"], affinity_weight=0.35)
+    cfg = cellmod.decision_config(dict(c.config, decision=block))
+    assert cfg.affinity_weight == 0.35
+    with pytest.raises(SystemExit, match=r"affinity_weight = 1\.5"):
+        cellmod.decision_config(dict(
+            c.config, decision=dict(block, affinity_weight=1.5)))
+
+
+def _session_mix(tmp_path, **over):
+    from bench.conftest import SESSION_MIX
+    raw = json.loads(json.dumps(SESSION_MIX))
+    raw["sessions"].update(over)
+    (tmp_path / "chat.json").write_text(json.dumps(raw))
+    return arrivals.Mix.load(tmp_path / "chat.json")
+
+
+def test_session_turns_grow_from_their_predecessor(tmp_path):
+    """A follow-up's tokens begin with the turn before's, it arrives
+    later, its tokens stop at 128, and the seed fixes the traffic."""
+    import numpy as np
+    from types import SimpleNamespace
+    from bench import sessions
+    mix = _session_mix(tmp_path, base_len=100, extend=[20, 28])
+    rng0 = np.random.default_rng(5)
+    prompts = [SimpleNamespace(tokens=rng0.integers(1, 4096, n))
+               for n in (8, 60, 128, 128)]
+    at, conv, base, toks = sessions.chat_turns(
+        mix, prompts, np.random.default_rng(11))
+    assert len(at) > 20 and len(set(conv)) > 5
+    assert all(len(t) <= sessions.MAX_TOKENS for t in toks)
+    assert any(len(t) == sessions.MAX_TOKENS for t in toks)
+    follow = 0
+    for k in range(len(at)):
+        if k and conv[k] == conv[k - 1]:
+            follow += 1
+            prev = toks[k - 1]
+            assert at[k] > at[k - 1]
+            assert np.array_equal(toks[k][:len(prev)], prev)
+            grew = len(toks[k]) - len(prev)
+            assert 20 <= grew <= 28 or len(toks[k]) == 128
+            assert base[k] == base[k - 1]
+        else:
+            first = prompts[base[k]].tokens[:100]
+            assert np.array_equal(toks[k], first)
+    assert follow > 10
+    again = sessions.chat_turns(mix, prompts, np.random.default_rng(11))
+    assert np.array_equal(again[0], at) and np.array_equal(again[2], base)
+    assert all(np.array_equal(a, b) for a, b in zip(again[3], toks))
+
+
+@pytest.mark.parametrize("mix_name", ["poisson_53rps", "gamma_cv3_40rps"])
+def test_a_mix_without_sessions_gives_the_same_requests(mix_name, small):
+    """The requests of a mix without sessions are those the harness
+    built before sessions existed: arrivals, dealt prompts, budgets."""
+    import dataclasses
+    import numpy as np
+    from repro.core import make_requests
+    ds = small[1].dataset
+    mix = arrivals.Mix.load(ROOT / "bench" / "traffic" / f"{mix_name}.json")
+    seed = cellmod.traffic_seed(2 ** 33 + 1)
+    drawn = np.random.default_rng(seed)
+    t, reqs = cellmod.requests_for(mix, ds, None, drawn)
+    rng = np.random.default_rng(seed)
+    t0 = arrivals.arrivals(mix, rng)
+    order = rng.permutation(len(ds.test_idx))
+    dealt = dataclasses.replace(ds, test_idx=ds.test_idx[order])
+    want = make_requests(dealt, "test", t0,
+                         budgets=arrivals.budgets(mix, len(t0), rng))
+    assert np.array_equal(t, t0) and len(reqs) == len(want)
+    assert all(a.prompt is b.prompt and a.arrival == b.arrival
+               and a.rid == b.rid and a.budget == b.budget
+               for a, b in zip(reqs, want))
+    assert np.array_equal(reqs[0].cols.prompt_row, want[0].cols.prompt_row)
+    assert np.array_equal(reqs[0].cols.budget, want[0].cols.budget,
+                          equal_nan=True)
+    # the same draws, no more
+    assert drawn.bit_generator.state == rng.bit_generator.state
+
+
+def test_a_session_mix_merges_both_streams(tmp_path, small):
+    """Both streams, in arrival order; each turn a prompt of its own
+    whose length is its token count; turns share their base row's
+    labels."""
+    import numpy as np
+    ds = small[1].dataset
+    mix = _session_mix(tmp_path)
+    t, reqs = cellmod.requests_for(mix, ds, None, np.random.default_rng(4))
+    assert np.all(np.diff(t) >= 0)
+    assert [r.arrival for r in reqs] == list(t)
+    assert [r.rid for r in reqs] == list(range(len(reqs)))
+    test_prompts = {id(ds.prompts[i]) for i in ds.test_idx}
+    turns = [r for r in reqs if id(r.prompt) not in test_prompts]
+    assert 0.5 < len(turns) / len(reqs) < 0.9      # 10 of 14 req/s
+    assert all(r.prompt.len_in == len(r.prompt.tokens) <= 128
+               for r in turns)
+    cols = reqs[0].cols
+    assert all(r.cols is cols for r in reqs)
 
 
 def test_unknown_process_is_refused(tmp_path):
