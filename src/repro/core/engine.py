@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -49,6 +49,9 @@ from repro.serving.tiers import Tier
 
 from .budget import max_tokens_clamp
 from .trace import span
+
+if TYPE_CHECKING:
+    from repro.serving.recovery import RecoveryConfig
 
 DEPLOYMENTS = ("windowed", "concurrent", "serial_published", "microbatch")
 # legacy PipelineConfig spelling, accepted as an alias
@@ -74,6 +77,9 @@ class EngineConfig:
     microbatch_size: int = 64
     microbatch_time: float = 1.72  # padded batch service time (§6.3)
     queue_capacity: Optional[int] = None   # bounded => drops (vLLM-SR)
+    # fault-tolerant request lifecycle (repro.serving.recovery): when
+    # set, `attach` arms a RecoveryManager on a sim that carries none
+    recovery: Optional["RecoveryConfig"] = None
 
 
 class BatchView:
@@ -279,6 +285,9 @@ class ServingEngine:
         self.sim = sim
         self.policy.on_attach(sim)            # new sim -> new roster
         mgr = getattr(sim, "recovery", None)
+        if mgr is None and self.ecfg.recovery is not None:
+            from repro.serving.recovery import arm_recovery
+            mgr = arm_recovery(sim, self.ecfg.recovery)
         if mgr is not None:
             mgr.bind(self)       # retries requeue into us; watchdog starts
         if self.ecfg.deployment != "windowed":

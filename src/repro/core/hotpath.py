@@ -83,13 +83,15 @@ def _new_stats() -> Dict:
     it starts (two per dispatch: the padded choice and l_chosen);
     `dirty_checks` and `dirty_rows_seen`, the syncs that read
     `tel.dirty_rows` and the rows they found dirty, before the
-    mostly-dirty rule decides; then how each sync ended."""
+    mostly-dirty rule decides; then how each sync ended; `dead_cols`,
+    the roster columns masked out (dead or quarantined) at each sync,
+    summed over the syncs."""
     return {"calls": 0, "host_s": 0.0, "stage_s": 0.0, "telemetry_s": 0.0,
             "dispatch_s": 0.0, "device_s": 0.0, "sync_s": 0.0,
             "uploads": 0, "d2h": 0, "dirty_checks": 0, "dirty_rows_seen": 0,
             "full_reseed": 0,
             "roster_reseed": 0,        # full reseeds caused by roster churn
-            "delta_sync": 0, "delta_rows": 0, "carry": 0}
+            "delta_sync": 0, "delta_rows": 0, "carry": 0, "dead_cols": 0}
 
 
 def _host_arrays(args) -> int:
@@ -510,6 +512,7 @@ class FusedHotPath:
         self._state: Optional[Tuple] = None   # (d, b, free, ctx) mirror
         self._post_state: Optional[Tuple] = None   # post-scan (d, b, free)
         self._alive_dev = None
+        self._dead = 0                        # masked columns in the mirror
         self._seen_tel = None                 # identity of the synced view
         self._seen_version = -1
         self._seen_roster = -1
@@ -607,9 +610,13 @@ class FusedHotPath:
                 for x in (tel.pending, tel.batch, tel.free, tel.ctx))
             self._alive_dev = jnp.asarray(
                 self._pad_i(np.asarray(tel.alive), fill=False))
+            # the mask changes only with roster_version, which reseeds
+            self._dead = self._n_real - int(np.count_nonzero(tel.alive))
             st["uploads"] += len(self._state) + 1
             st["full_reseed"] += 1
+            st["dead_cols"] += self._dead
             return self._state + (self._alive_dev,) + self._empty_delta
+        st["dead_cols"] += self._dead
         K = len(rows)
         if K == 0:
             st["carry"] += 1

@@ -16,7 +16,7 @@ amortized batch scoring).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -33,6 +33,9 @@ from .decision_jax import LATENCY_MODES
 from .engine import (AssignmentResult, BatchView, EngineConfig, Ready,
                      SchedulingPolicy, ServingEngine)
 from .weights import PRESETS, Weights, validate
+
+if TYPE_CHECKING:
+    from repro.serving.recovery import RecoveryConfig
 
 
 @dataclasses.dataclass
@@ -98,6 +101,19 @@ class RBConfig:
     #                                    cache so signature-identical
     #                                    cell rosters still get their
     #                                    own carried telemetry mirrors.
+    recovery: Optional["RecoveryConfig"] = None
+    #                                    fault-tolerant request lifecycle
+    #                                    (serving.recovery): when set, the
+    #                                    engine arms a RecoveryManager on
+    #                                    every sim it attaches to. None =
+    #                                    off. A dict (a configuration
+    #                                    file's JSON block) is read as
+    #                                    RecoveryConfig's fields.
+
+    def __post_init__(self):
+        if isinstance(self.recovery, dict):
+            from repro.serving.recovery import RecoveryConfig
+            self.recovery = RecoveryConfig(**self.recovery)
 
 
 class EstimatorBundle:
@@ -209,9 +225,12 @@ class RouteBalancePolicy(SchedulingPolicy):
         # this policy is mounted on (registry path included), not just
         # the RouteBalance convenience class
         cfg = self.cfg
-        return dict(base_window=cfg.base_window, adaptive=cfg.adaptive,
-                    fixed_batch=cfg.fixed_batch,
-                    charge_compute=cfg.charge_compute)
+        out = dict(base_window=cfg.base_window, adaptive=cfg.adaptive,
+                   fixed_batch=cfg.fixed_batch,
+                   charge_compute=cfg.charge_compute)
+        if cfg.recovery is not None:
+            out["recovery"] = cfg.recovery
+        return out
 
     def prepare(self, bundle, tiers: Sequence[Tier]):
         cfg = self.cfg

@@ -20,6 +20,10 @@ Span names describe the system, under ``rb.``:
 - serving engine (``core/engine.py``): ``rb.window`` around one fired
   window, with ``rb.assign`` (the policy call) and ``rb.submit`` (the
   loop that submits the decided requests) inside.
+- recovery (``serving/recovery.py``): ``rb.recovery`` around the
+  manager's work: requeueing a failure's victims, arming the hedge
+  timer of each dispatch (inside ``rb.submit``), the hedge check, the
+  telemetry watchdog and its releases.
 """
 from __future__ import annotations
 

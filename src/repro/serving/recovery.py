@@ -48,12 +48,26 @@ hedge / quarantine churn, and a crash/restore replays bitwise.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core.trace import span
+
 from .cluster import ClusterSim, Instance
+
+
+def _traced(method):
+    """Run `method` inside the `rb.recovery` host span
+    (`repro.core.trace`). The manager's entry points carry it: what they
+    call (`_maybe_hedge`, `_release`) runs inside."""
+    @functools.wraps(method)
+    def inner(*args, **kw):
+        with span("rb.recovery"):
+            return method(*args, **kw)
+    return inner
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,6 +175,7 @@ class RecoveryManager:
         return self
 
     # -- retry/requeue ----------------------------------------------------
+    @_traced
     def on_failure(self, req, inst: Instance, lost_tokens: int,
                    now: float) -> bool:
         """Instance death handed us a victim. True = requeued for retry
@@ -194,6 +209,7 @@ class RecoveryManager:
         return deliver                 # checkpoint re-arms it on resume
 
     # -- timeouts + hedged re-dispatch ------------------------------------
+    @_traced
     def watch_dispatch(self, req, inst: Instance, t: float):
         """Arm the per-request deadline for a dispatch that just
         happened. Deadline = hedge_factor x the tier's roofline service
@@ -219,6 +235,7 @@ class RecoveryManager:
         return self.cfg.hedge_factor * est + self.cfg.hedge_slack_s
 
     def _make_hedge_check(self, req, iid: str, attempt: int, hedges: int):
+        @_traced
         def check(t):
             self._watches.pop((req.rid, attempt, hedges), None)
             self._maybe_hedge(req, iid, attempt, hedges, t)
@@ -257,6 +274,7 @@ class RecoveryManager:
         self.watch_dispatch(req, target, t)
 
     # -- telemetry watchdog -----------------------------------------------
+    @_traced
     def _watch(self, t: float):
         cfg = self.cfg
         tel = self.sim.tel
@@ -266,20 +284,27 @@ class RecoveryManager:
             if not inst.alive:
                 continue
             has_work = bool(inst.running or inst.queue)
-            is_stale = has_work and (t - tel.t[inst.slot]
-                                     ) > cfg.stale_after_s
+            quiet = (t - tel.t[inst.slot]) > cfg.stale_after_s
+            # heard from: published within stale_after_s, or idle with
+            # its telemetry channel up. Rows publish only at iteration
+            # boundaries, so an idle worker writes nothing; a real one
+            # keeps heartbeating, and a blackout (`tel_mute`) silences
+            # that heartbeat too
+            heard = not quiet or not (has_work or inst.tel_mute)
+            is_stale = has_work and quiet
             if inst.quarantined:
                 if not is_stale:
                     self._release(inst, t)
                 continue
             if is_stale:
                 stale.append(inst)
-            else:
+            elif heard:
                 fresh += 1
         if stale and fresh == 0:
-            # whole mirror dark: masking everything would leave the
-            # policy nothing to schedule onto — flip to the degraded
-            # least-loaded fallback instead and leave the masks alone
+            # whole mirror dark: no live row was heard from. Masking
+            # everything would leave the policy nothing to schedule
+            # onto — flip to the degraded least-loaded fallback instead
+            # and leave the masks alone
             if not self.degraded:
                 self.degraded_entries += 1
             self.degraded = True
@@ -372,7 +397,10 @@ class RecoveryManager:
 
 def arm_recovery(sim: ClusterSim,
                  cfg: Optional[RecoveryConfig] = None) -> RecoveryManager:
-    """Attach a `RecoveryManager` to a sim as ``sim.recovery``.
+    """Attach a `RecoveryManager` to a sim as ``sim.recovery``: the one
+    place a manager is armed, by `ScenarioRun.arm` and by
+    `ServingEngine.attach` (from `EngineConfig.recovery`, which
+    `RBConfig.recovery` sets, on a sim that carries none).
     `Instance.fail()` finds it there; `ServingEngine.attach` binds
     itself and starts the watchdog."""
     mgr = RecoveryManager(sim, cfg if cfg is not None

@@ -15,8 +15,8 @@ from repro.serving.faults import (CHAOS_SUITES, chaos_world, compose,
                                   correlated_failure, crash_storm,
                                   straggler_storm, telemetry_blackout)
 from repro.serving.metrics import check_terminal_states
-from repro.serving.recovery import (RecoveryConfig, arm_recovery,
-                                    least_loaded_instance,
+from repro.serving.recovery import (RecoveryConfig, RecoveryManager,
+                                    arm_recovery, least_loaded_instance,
                                     simulate_controller_crash)
 from repro.serving.request import Request
 from repro.serving.scenarios import apply_schedule, synthetic_pool
@@ -224,6 +224,7 @@ def test_watchdog_quarantine_release_zero_recompiles(chaos_run):
                         weights=PRESETS["quality"])
     assert m["failed"] == 0 and m["n"] == len(reqs)
     assert m["quarantines"] > 0
+    assert m["degraded_decisions"] == 0          # half the fleet heard
     from repro.core.decision_jax import bucket_pow2
     buckets = {bucket_pow2(s) for s, _ in rb.compute_log}
     assert rb._fused.compile_count() == len(buckets)
@@ -234,6 +235,31 @@ def test_full_blackout_degrades_to_least_loaded(chaos_run):
                        telemetry_blackout(chaos_run.tiers, frac=1.0))
     assert m["failed"] == 0 and m["n"] == len(reqs)
     assert m["degraded_decisions"] > 0           # mirror went dark
+
+
+def test_one_muted_row_among_idle_rows_is_quarantined():
+    """Partial blackout at low load: one busy row stops publishing
+    while every other live row has sat idle for longer than
+    stale_after_s. The idle rows' mirrors still hold their workers'
+    last state, so the watchdog quarantines the muted row alone and
+    the controller does not go degraded."""
+    sim = _mini_sim(n_tiers=1, n_instances=4)
+    mgr = arm_recovery(sim, RecoveryConfig())
+    busy, *idle = sim.instances
+    for k, inst in enumerate(idle):              # short work, then idle
+        inst.submit(_req(10 + k), 0.0, 40.0, None)
+    long = _req(0)
+    long.true_length = np.full(8, 5000.0)
+    busy.tel_mute = True
+    busy.submit(long, 0.0, 5000.0, None)
+    sim.run(until=6.0)
+    assert all(not i.running and not i.queue for i in idle)
+    assert all(6.0 - sim.tel.t[i.slot] > 2.0 for i in idle)
+    assert busy.running                          # still decoding, unseen
+    mgr._watch(6.0)
+    assert busy.quarantined and mgr.quarantines == 1
+    assert not any(i.quarantined for i in idle)
+    assert not mgr.degraded and mgr.degraded_entries == 0
 
 
 # -- prefix-affinity under failure / quarantine (serving.affinity) -----------
@@ -506,3 +532,120 @@ def test_least_loaded_prefers_unquarantined():
     assert pick is b                             # quarantine = last resort
     b.alive = c.alive = False
     assert least_loaded_instance(sim, exclude=(a.iid,)) is None
+
+
+# -- arming from the deployment's configuration -------------------------------
+
+def test_recovery_armed_from_rbconfig_binds_the_engine(chaos_run):
+    cfg = RecoveryConfig(max_attempts=2)
+    rb = RouteBalance(RBConfig(recovery=cfg), chaos_run.bundle(),
+                      chaos_run.tiers)
+    sim = ClusterSim(chaos_run.tiers, chaos_run.names)
+    rb.attach(sim)
+    assert isinstance(sim.recovery, RecoveryManager)
+    assert sim.recovery.cfg == cfg and sim.recovery.engine is rb
+    assert sim.recovery._watch_armed             # the watchdog started
+    off = RouteBalance(RBConfig(), chaos_run.bundle(), chaos_run.tiers)
+    bare = ClusterSim(chaos_run.tiers, chaos_run.names)
+    off.attach(bare)
+    assert off.ecfg.recovery is None
+    assert getattr(bare, "recovery", None) is None   # as before: off
+
+
+def test_a_recovery_block_becomes_a_recovery_config():
+    cfg = RBConfig(recovery={"max_attempts": 2, "hedge": False})
+    assert cfg.recovery == RecoveryConfig(max_attempts=2, hedge=False)
+    hash(cfg.recovery)
+    assert RBConfig().recovery is None
+    with pytest.raises(TypeError):
+        RBConfig(recovery={"no_such_knob": 1})
+
+
+def test_scenario_and_engine_arm_through_one_function(chaos_run,
+                                                      monkeypatch):
+    """`ScenarioRun.arm` and `ServingEngine.attach` both build their
+    manager in `arm_recovery`; an engine attached to a sim the scenario
+    already armed binds that manager and arms no second one."""
+    import repro.serving.recovery as recovery
+    made = []
+
+    class Spy(recovery.RecoveryManager):
+        def __init__(self, sim, cfg):
+            made.append(cfg)
+            super().__init__(sim, cfg)
+
+    monkeypatch.setattr(recovery, "RecoveryManager", Spy)
+    by_scenario = RecoveryConfig(max_attempts=5)
+    by_engine = RecoveryConfig(max_attempts=2)
+    monkeypatch.setattr(chaos_run, "recovery", by_scenario)
+    armed = chaos_run.arm(ClusterSim(chaos_run.tiers, chaos_run.names))
+    rb = RouteBalance(RBConfig(recovery=by_engine), chaos_run.bundle(),
+                      chaos_run.tiers)
+    fresh = ClusterSim(chaos_run.tiers, chaos_run.names)
+    rb.attach(fresh)
+    assert type(armed.recovery) is Spy and type(fresh.recovery) is Spy
+    assert made == [by_scenario, by_engine]
+    rb.attach(armed)
+    assert armed.recovery.cfg == by_scenario
+    assert armed.recovery.engine is rb and len(made) == 2
+
+
+def test_dead_cols_counts_the_masked_columns(chaos_run):
+    """The runner's `dead_cols` adds the columns masked out, dead or
+    quarantined, at each sync; fail, quarantine and recover flip the
+    alive mask without a compile."""
+    from repro.core import make_requests
+    from repro.core.hotpath import FusedHotPath
+    from repro.serving.request import batch_columns
+    sim = ClusterSim(chaos_run.tiers, chaos_run.names)
+    fp = FusedHotPath(chaos_run.bundle(), sim.instances,
+                      RBConfig(decision_backend="fused"))
+    cols, rows = batch_columns(make_requests(chaos_run.ds, "test",
+                                             np.zeros(4)))
+    cols.ensure_embeddings(chaos_run.bundle().encoder)
+
+    def decide():
+        fp.decide_cols(cols, rows, sim.tel).fetch()
+        return fp.stats["dead_cols"]
+
+    assert decide() == 0
+    sim.instances[1].fail()
+    sim.instances[3].fail()
+    assert decide() == 2
+    assert decide() == 4                         # counted at every sync
+    sim.instances[5].quarantined = True
+    sim.tel.quarantine(5)
+    assert decide() == 7
+    sim.instances[1].recover(1.0)
+    sim.instances[3].recover(1.0)
+    assert decide() == 8
+    assert fp.stats["calls"] == 5 and fp.compile_count() == 1
+
+
+def test_recovery_spans_in_the_trace(chaos_run, tmp_path):
+    """`rb.recovery` spans open around the manager's work: inside the
+    engine's `rb.submit` (the hedge timer armed per dispatch) and on the
+    simulator's own events (a failure's victims, the watchdog)."""
+    import jax
+    from jax.profiler import ProfileData
+    _cell(chaos_run, crash_storm(chaos_run.tiers), n=40)    # compile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, _, m = _cell(chaos_run, crash_storm(chaos_run.tiers), n=40)
+    finally:
+        jax.profiler.stop_trace()
+    assert m["retries"] > 0
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("rb.")]
+    rec = [s for s in spans if s[0] == "rb.recovery"]
+    submits = [s for s in spans if s[0] == "rb.submit"]
+
+    def inside(a, b):
+        return b[1] <= a[1] and a[2] <= b[2]
+
+    assert any(inside(r, s) for r in rec for s in submits)
+    assert any(not any(inside(r, s) for s in submits) for r in rec)
