@@ -27,11 +27,13 @@ encoder forward over the padded token matrix, and a blocking
     entirely: tokens stay at ingest, the program starts from
     embeddings);
   * **incremental device telemetry** — the (d, b, free, ctx) state is a
-    device-resident mirror of ``TelemetryArrays``; each batch scatters
-    only the rows written since the last sync (``tel.dirty_rows``)
-    inside the jitted step, with a full reseed only on roster-shape
-    events (fail/recover, tracked by ``tel.roster_version``) or when
-    most of the roster is dirty. The refreshed mirror is bitwise the
+    device-resident mirror of ``TelemetryArrays``; each batch uploads
+    one (5, I) block — a write mask, then the four planes — and the
+    jitted step takes the lanes the mask marks: the rows written since
+    the last sync (``tel.dirty_rows``), or every lane on a reseed
+    (roster-shape events, tracked by ``tel.roster_version``, or most of
+    the roster dirty); a window with nothing written uploads no
+    telemetry at all. The refreshed mirror is bitwise the
     staged backends' reseed-per-batch host read — untouched rows'
     telemetry has not moved — so carry-forward is now the common case
     AND exact-parity-safe (the PR-2 semantics, which carried post-scan
@@ -78,8 +80,9 @@ def _new_stats() -> Dict:
     `stage_s` (rb.stage), `telemetry_s` (rb.telemetry), `host_s` (the
     two together), `dispatch_s` (rb.dispatch), `device_s` (rb.wait) and
     `sync_s` (rb.copy). Counters: `uploads`, the host arrays the runner
-    hands to the device (each numpy argument of the jitted step, and
-    each array a reseed uploads); `d2h`, the device-to-host transfers
+    hands to the device (each numpy argument of the jitted step, the
+    telemetry block of a reseed or delta sync, and the alive mask when
+    the roster moved); `d2h`, the device-to-host transfers
     it starts (two per dispatch: the padded choice and l_chosen);
     `dirty_checks` and `dirty_rows_seen`, the syncs that read
     `tel.dirty_rows` and the rows they found dirty, before the
@@ -100,14 +103,14 @@ def _host_arrays(args) -> int:
     return sum(type(a) is np.ndarray for a in args)
 
 
-def _scatter_delta(d, b, free, ctx, didx, dd, db, dfree, dctx):
-    """The dirty rows into the telemetry mirror, under the `telemetry`
-    scope (pad lanes carry out-of-range indices and drop)."""
+def _apply_block(d, b, free, ctx, block):
+    """The telemetry block into the mirror, under the `telemetry` scope:
+    each lane that row 0 (the write mask) marks takes rows 1-4 (pending,
+    batch, free, ctx); every other lane keeps the carried value."""
     with jax.named_scope("telemetry"):
-        return (d.at[didx].set(dd, mode="drop"),
-                b.at[didx].set(db, mode="drop"),
-                free.at[didx].set(dfree, mode="drop"),
-                ctx.at[didx].set(dctx, mode="drop"))
+        write = block[0] != 0.0
+        return tuple(jnp.where(write, block[k], x)
+                     for k, x in enumerate((d, b, free, ctx), 1))
 
 
 class LazyDecision:
@@ -303,8 +306,8 @@ class FusedHotPath:
         # refreshed (pre-scan) mirror comes back out, so it chains
         # batch-to-batch on device; alive is read-only (re-uploaded on
         # roster events). args: emb 0, row_valid 1, budgets 2, len_in 3,
-        # d 4, b 5, free 6, ctx 7, alive 8, delta idx/d/b/free/ctx 9-13,
-        # psig 14, sig_plane 15 (appended so donate indices stay fixed)
+        # d 4, b 5, free 6, ctx 7, alive 8, telemetry block 9, psig 10,
+        # sig_plane 11 (appended so donate indices stay fixed)
         self._step = jax.jit(self._step_impl, donate_argnums=(4, 5, 6, 7))
         # multi-window megakernel dispatch: same signature with a
         # leading K axis on the per-window args; compiled per
@@ -314,23 +317,20 @@ class FusedHotPath:
             if self._backend == "megakernel" else None)
         self._mstage: Dict[Tuple[int, int], list] = {}
         self._mflip: Dict[Tuple[int, int], int] = {}
-        # the delta lane count is FIXED at one pow2 capacity (≥ the
-        # mostly-dirty threshold where _sync_state reseeds instead), so
-        # full-reseed, carry and every delta sync share one compiled
-        # shape per R bucket — K never adds a compile dimension, and
-        # warming the R buckets warms everything. Unused lanes carry
-        # out-of-range indices and drop in the scatter.
-        self._Kcap = bucket_pow2(max(8, (self._n_real + 1) // 2))
-        self._empty_delta = (
-            np.full(self._Kcap, self._Itot, np.int32),
-            np.zeros(self._Kcap, np.float32),
-            np.zeros(self._Kcap, np.float32),
-            np.zeros(self._Kcap, np.float32),
-            np.zeros(self._Kcap, np.float32))
+        # the telemetry block is one (5, Itot) shape in every sync arm
+        # (the arms differ only in its write mask), so reseed, delta and
+        # carry share one compiled program per R bucket and warming the
+        # R buckets warms everything. Every arm hands the step a device
+        # array there: jit keys its dispatch cache on host-vs-device
+        # arguments, so a numpy block beside the carry's device block
+        # would double the cache entries. Double buffered like staging;
+        # pad lanes stay 0 (only real rows are ever written).
+        self._tstage = [np.zeros((5, self._Itot), np.float32)
+                        for _ in range(2)]
+        self._tflip = 0
+        self._noop_block = jnp.zeros((5, self._Itot), jnp.float32)
         self._stage: Dict[int, list] = {}    # Rb -> [bufset, bufset]
         self._sflip: Dict[int, int] = {}
-        self._dstage: Optional[list] = None  # [bufset, bufset]
-        self._dflip = 0
         self.reset()                         # also installs fresh stats
 
     def _pad_i(self, x: np.ndarray, fill=0) -> np.ndarray:
@@ -373,16 +373,13 @@ class FusedHotPath:
                 interpret=self._interpret)
 
     def _step_impl(self, emb, row_valid, budgets, len_in,
-                   d, b, free, ctx, alive,
-                   didx, dd, db, dfree, dctx, psig, sig_plane):
-        # 0. incremental telemetry: scatter the dirty rows into the
-        # donated device mirror (pad lanes carry out-of-range indices
-        # and drop). The refreshed mirror is bitwise a full host
-        # re-read — untouched rows' telemetry has not moved since they
-        # were last synced — so this arm preserves the staged backends'
-        # reseed-per-batch semantics exactly.
-        d, b, free, ctx = _scatter_delta(d, b, free, ctx,
-                                         didx, dd, db, dfree, dctx)
+                   d, b, free, ctx, alive, block, psig, sig_plane):
+        # 0. incremental telemetry: the lanes the block's mask marks
+        # into the donated device mirror. The refreshed mirror is
+        # bitwise a full host re-read — untouched rows' telemetry has
+        # not moved since they were last synced — so every arm
+        # preserves the staged backends' reseed-per-batch semantics.
+        d, b, free, ctx = _apply_block(d, b, free, ctx, block)
 
         if self._backend == "megakernel":
             # stages 1–4 fused into one Pallas dispatch (K=1 window);
@@ -487,16 +484,14 @@ class FusedHotPath:
             self._mode, row_valid=row_valid, affinity=aff)
 
     def _step_multi_impl(self, emb, row_valid, budgets, len_in,
-                         d, b, free, ctx, alive,
-                         didx, dd, db, dfree, dctx, psig, sig_plane):
+                         d, b, free, ctx, alive, block, psig, sig_plane):
         """K coalesced scheduler windows, one megakernel dispatch. The
-        delta scatter runs once; every window scans from the refreshed
+        telemetry block applies once; every window scans from the refreshed
         mirror — bitwise what K back-to-back `_step` calls see when
         telemetry has not moved between them (the mirror reseeds from
         telemetry per dispatch, never across-batch dead-reckoning), so
         coalescing only amortizes launch/sync overhead."""
-        d, b, free, ctx = _scatter_delta(d, b, free, ctx,
-                                         didx, dd, db, dfree, dctx)
+        d, b, free, ctx = _apply_block(d, b, free, ctx, block)
         choice, est_T, l_chosen, d1, b1, f1 = self._mega_stages(
             emb, row_valid, budgets, len_in, d, b, free, ctx, alive,
             psig, sig_plane)
@@ -509,7 +504,10 @@ class FusedHotPath:
         counters rather than accumulating across cache-hit reuses. A
         `LazyDecision` still in flight keeps a reference to the old
         window and is unaffected."""
-        self._state: Optional[Tuple] = None   # (d, b, free, ctx) mirror
+        # the (d, b, free, ctx) mirror starts device-resident, so the
+        # first sync is an ordinary all-lanes block
+        self._state: Tuple = tuple(jnp.zeros(self._Itot, jnp.float32)
+                                   for _ in range(4))
         self._post_state: Optional[Tuple] = None   # post-scan (d, b, free)
         self._alive_dev = None
         self._dead = 0                        # masked columns in the mirror
@@ -552,31 +550,41 @@ class FusedHotPath:
         self._sflip[Rb] ^= 1
         return pair[self._sflip[Rb]]
 
-    def _delta_buffers(self) -> Dict[str, np.ndarray]:
-        if self._dstage is None:
-            def mk():
-                return {"idx": np.full(self._Kcap, self._Itot, np.int32),
-                        "d": np.zeros(self._Kcap, np.float32),
-                        "b": np.zeros(self._Kcap, np.float32),
-                        "free": np.zeros(self._Kcap, np.float32),
-                        "ctx": np.zeros(self._Kcap, np.float32)}
-            self._dstage = [mk(), mk()]
-        self._dflip ^= 1
-        return self._dstage[self._dflip]
+    def _telemetry_block(self, tel, rows) -> jax.Array:
+        """Fill the next host block from `tel` and upload it: every lane
+        marked when `rows` is None (a reseed: pad lanes take 0), else
+        only `rows`. The four planes are copied whole either way, so
+        the arms differ only in the mask."""
+        self._tflip ^= 1
+        blk = self._tstage[self._tflip]
+        n = self._n_real
+        blk[1, :n] = tel.pending
+        blk[2, :n] = tel.batch
+        blk[3, :n] = tel.free
+        blk[4, :n] = tel.ctx
+        if rows is None:
+            blk[0] = 1.0
+        else:
+            blk[0] = 0.0
+            blk[0, rows] = 1.0
+        self.stats["uploads"] += 1
+        return jax.device_put(blk)
 
     def _sync_state(self, tel) -> Tuple:
         """Assemble the telemetry-state args for `_step`: the carried
-        device mirror plus a dirty-row delta, or a full reseed.
+        device mirror, the alive mask and the telemetry block.
 
-        Full reseed happens only on the first batch, after `reset()`,
-        on roster-shape events (`tel.roster_version` moved: a fail or
-        recover flipped the alive mask), or when most of the roster is
-        dirty (the scatter would cost more than the re-upload). The
-        common steady-state case is the delta arm: only rows with
-        ``tel.last_write > seen_version`` are shipped. Either way the
-        state handed to the scan equals the staged backends' fresh host
-        read of `tel` bit for bit — which is what keeps the fused
-        backend in exact assignment parity (regression-tested in
+        Full reseed (every lane marked) happens only on the first batch,
+        after `reset()`, on roster-shape events (`tel.roster_version`
+        moved: a fail or recover flipped the alive mask), or when most
+        of the roster is dirty. The common steady-state case is the
+        delta arm: only rows with ``tel.last_write > seen_version`` are
+        marked; with none, the carry arm uploads nothing and hands over
+        a device-resident block that writes nothing. Alive is uploaded
+        only when the roster or the telemetry object changed. Either
+        way the state handed to the scan equals the staged backends'
+        fresh host read of `tel` bit for bit — which is what keeps the
+        fused backend in exact assignment parity (regression-tested in
         ``tests/test_ingest.py``; the PR-2 semantics of carrying
         post-scan dead-reckoned state across batches did NOT have this
         property and is gone)."""
@@ -587,7 +595,7 @@ class FusedHotPath:
         # (rb.sim = ClusterSim(...) without attach()) must reseed — the
         # new view's counters can look "older" than the mirror's and
         # would otherwise silently carry the previous cluster's state
-        if self._state is not None and tel is self._seen_tel:
+        if tel is self._seen_tel:
             if tel.roster_version == self._seen_roster:
                 rows = tel.dirty_rows(self._seen_version)
                 st["dirty_checks"] += 1
@@ -602,36 +610,27 @@ class FusedHotPath:
                 # scale events resync WITHOUT recompiling
                 st["roster_reseed"] += 1
         self._seen_version = tel.version
-        if rows is None:
+        if (tel is not self._seen_tel
+                or tel.roster_version != self._seen_roster):
+            # the mask changes only with roster_version
             self._seen_tel = tel
             self._seen_roster = tel.roster_version
-            self._state = tuple(
-                jnp.asarray(self._pad_i(np.asarray(x, np.float32)))
-                for x in (tel.pending, tel.batch, tel.free, tel.ctx))
             self._alive_dev = jnp.asarray(
                 self._pad_i(np.asarray(tel.alive), fill=False))
-            # the mask changes only with roster_version, which reseeds
             self._dead = self._n_real - int(np.count_nonzero(tel.alive))
-            st["uploads"] += len(self._state) + 1
-            st["full_reseed"] += 1
-            st["dead_cols"] += self._dead
-            return self._state + (self._alive_dev,) + self._empty_delta
+            st["uploads"] += 1
         st["dead_cols"] += self._dead
-        K = len(rows)
-        if K == 0:
+        if rows is None:
+            st["full_reseed"] += 1
+            block = self._telemetry_block(tel, None)
+        elif len(rows) == 0:
             st["carry"] += 1
-            return self._state + (self._alive_dev,) + self._empty_delta
-        st["delta_sync"] += 1
-        st["delta_rows"] += K
-        buf = self._delta_buffers()
-        buf["idx"][:K] = rows
-        buf["idx"][K:] = self._Itot          # out-of-range -> dropped
-        buf["d"][:K] = tel.pending[rows]
-        buf["b"][:K] = tel.batch[rows]
-        buf["free"][:K] = tel.free[rows]
-        buf["ctx"][:K] = tel.ctx[rows]
-        return self._state + (self._alive_dev, buf["idx"], buf["d"],
-                              buf["b"], buf["free"], buf["ctx"])
+            block = self._noop_block
+        else:
+            st["delta_sync"] += 1
+            st["delta_rows"] += len(rows)
+            block = self._telemetry_block(tel, rows)
+        return self._state + (self._alive_dev, block)
 
     def decide_cols(self, cols, rows: np.ndarray, tel) -> LazyDecision:
         """One scheduler batch as a row slice into the SoA ingest

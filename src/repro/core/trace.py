@@ -13,7 +13,8 @@ Span names describe the system, under ``rb.``:
 
 - decision runner (``core/hotpath.py``): ``rb.stage`` (gathers into the
   staging buffers), ``rb.telemetry`` (the dirty-row read, then the
-  reseed uploads or the delta fill), ``rb.dispatch`` (the jitted step:
+  one telemetry block's fill and upload, and the alive mask's when the
+  roster moved), ``rb.dispatch`` (the jitted step:
   argument transfers, the launch and the start of the result's host
   transfers), ``rb.fetch`` with ``rb.wait`` (until the transfers are
   complete) and ``rb.copy`` (the numpy slice and cast) inside;

@@ -348,11 +348,14 @@ def test_dirty_row_tracking(small_ctx):
 # -- spans and counters of the runner -----------------------------------------
 
 # host arrays a window hands the device: the four staging buffers (emb,
-# row_valid, budgets, len_in) and the five delta lanes (idx, d, b, free,
-# ctx) are numpy arguments of the jitted step; the affinity dummies are
-# device-resident; a reseed uploads the four mirror planes and alive
-DELTA_UPLOADS = 4 + 5
-RESEED_UPLOADS = DELTA_UPLOADS + 4 + 1
+# row_valid, budgets, len_in) are numpy arguments of the jitted step;
+# the affinity dummies are device-resident; a reseed or delta sync
+# uploads one telemetry block, a carry none; the alive mask goes up
+# again only on the first sync and when the roster moved
+CARRY_UPLOADS = 4
+DELTA_UPLOADS = CARRY_UPLOADS + 1
+RESEED_UPLOADS = DELTA_UPLOADS
+ALIVE_UPLOADS = 1
 
 
 def test_host_seconds_are_stage_plus_telemetry(small_ctx):
@@ -373,8 +376,9 @@ def test_host_seconds_are_stage_plus_telemetry(small_ctx):
 
 
 def test_uploads_count_the_host_arrays_of_each_window(small_ctx):
-    """A reseed window hands over 14 host arrays, a delta or carry
-    window 9; the counter agrees with the arguments actually given."""
+    """A reseed or delta window uploads 5 host arrays, a carry window 4,
+    and the first sync the alive mask besides; the step itself is
+    handed the four staging buffers in every arm."""
     sim = _loaded_sim(small_ctx)
     tel = sim.tel
     fp = _runner(small_ctx, sim)
@@ -386,16 +390,17 @@ def test_uploads_count_the_host_arrays_of_each_window(small_ctx):
         return step(*args)
 
     fp._step = counting_step
+    first = RESEED_UPLOADS + ALIVE_UPLOADS
     fp.decide(_batch(small_ctx, R=8, seed=1), tel)          # reseed
-    assert fp.stats["uploads"] == RESEED_UPLOADS
+    assert fp.stats["uploads"] == first
     tel.write(4, pending=10.0, batch=2, free=1, ctx=300.0, queue=0, t=1.0)
     fp.decide(_batch(small_ctx, R=8, seed=2), tel)          # delta
-    assert fp.stats["uploads"] == RESEED_UPLOADS + DELTA_UPLOADS
+    assert fp.stats["uploads"] == first + DELTA_UPLOADS
     fp.decide(_batch(small_ctx, R=3, seed=3), tel)          # carry
-    assert fp.stats["uploads"] == RESEED_UPLOADS + 2 * DELTA_UPLOADS
+    assert fp.stats["uploads"] == first + DELTA_UPLOADS + CARRY_UPLOADS
     assert (fp.stats["full_reseed"], fp.stats["delta_sync"],
             fp.stats["carry"]) == (1, 1, 1)
-    assert handed == [DELTA_UPLOADS] * 3
+    assert handed == [CARRY_UPLOADS] * 3
 
 
 def test_dirty_rows_are_counted_before_the_mostly_dirty_rule(small_ctx):
@@ -465,13 +470,140 @@ def test_spans_leave_the_counters_as_they_were(small_ctx, tmp_path,
         if profiled:
             jax.profiler.stop_trace()
     n = len(_loaded_sim(small_ctx).instances)
-    R, D = RESEED_UPLOADS, DELTA_UPLOADS
+    R, D, C, A = RESEED_UPLOADS, DELTA_UPLOADS, CARRY_UPLOADS, ALIVE_UPLOADS
     # (full, delta, rows, carry, roster, uploads, checks, seen)
-    assert seen == [(1, 0, 0, 0, 0, R, 0, 0),
-                    (1, 0, 0, 1, 0, R + D, 1, 0),
-                    (1, 1, 1, 1, 0, R + 2 * D, 2, 1),
-                    (2, 1, 1, 1, 1, 2 * R + 2 * D, 2, 1),
-                    (3, 1, 1, 1, 1, 3 * R + 2 * D, 3, 1 + n)]
+    assert seen == [(1, 0, 0, 0, 0, R + A, 0, 0),
+                    (1, 0, 0, 1, 0, R + A + C, 1, 0),
+                    (1, 1, 1, 1, 0, R + A + C + D, 2, 1),
+                    (2, 1, 1, 1, 1, 2 * (R + A) + C + D, 2, 1),
+                    (3, 1, 1, 1, 1, 3 * R + 2 * A + C + D, 3, 1 + n)]
+
+
+# -- the telemetry block: the mirror after each sync arm ----------------------
+
+def _padded(fp, x, fill=0):
+    n = len(x)
+    out = np.full(fp._Itot, fill, np.asarray(x).dtype)
+    out[:n] = x
+    return out
+
+
+def _arm_untouched(sim):
+    pass
+
+
+def _arm_delta(sim):
+    for slot in (0, 4):
+        sim.tel.write(slot, pending=77.0 + slot, batch=3, free=2, ctx=640.0,
+                      queue=0, t=1.0)
+
+
+def _arm_roster(sim):
+    sim.instances[3].fail()
+
+
+def _arm_all_dirty(sim):
+    sim.tel.pending[:] += 1.5
+    sim.tel.mark_all_dirty()
+
+
+# (arm, touch the sim before the decision, counter it bumps, uploads)
+ARMS = [("first", _arm_untouched, "full_reseed",
+         RESEED_UPLOADS + ALIVE_UPLOADS),
+        ("delta", _arm_delta, "delta_sync", DELTA_UPLOADS),
+        ("carry", _arm_untouched, "carry", CARRY_UPLOADS),
+        ("roster", _arm_roster, "roster_reseed",
+         RESEED_UPLOADS + ALIVE_UPLOADS),
+        ("all_dirty", _arm_all_dirty, "full_reseed", RESEED_UPLOADS)]
+
+
+@pytest.mark.parametrize("arm,touch,counter,uploads", ARMS,
+                         ids=[a[0] for a in ARMS])
+def test_each_sync_arm_leaves_the_mirror_a_padded_host_read(
+        small_ctx, arm, touch, counter, uploads):
+    """After a decision in each sync arm, the donated mirror is the
+    padded host read of `tel` bit for bit, the device alive mask is
+    `tel.alive` padded with False, only the reseed and delta arms
+    upload a telemetry block, and no arm compiles."""
+    sim = _loaded_sim(small_ctx)
+    tel = sim.tel
+    fp = _runner(small_ctx, sim)
+    blocks = []
+    step = fp._step
+
+    def keep(*args):
+        blocks.append(args[9])
+        return step(*args)
+
+    keep._cache_size = step._cache_size      # compile_count reads it
+    fp._step = keep
+    if arm != "first":
+        fp.decide(_batch(small_ctx, R=8, seed=1), tel)
+    compiled = fp.compile_count()
+    before = dict(fp.stats)
+    touch(sim)
+    fp.decide(_batch(small_ctx, R=8, seed=2), tel)
+    assert fp.stats[counter] == before.get(counter, 0) + 1
+    assert fp.stats["uploads"] - before.get("uploads", 0) == uploads
+    for got, x in zip(fp._state, (tel.pending, tel.batch, tel.free,
+                                  tel.ctx)):
+        want = _padded(fp, np.asarray(x, np.float32))
+        np.testing.assert_array_equal(_bits(np.asarray(got)), _bits(want))
+    np.testing.assert_array_equal(np.asarray(fp._alive_dev),
+                                  _padded(fp, tel.alive, fill=False))
+    assert not isinstance(blocks[-1], np.ndarray)
+    assert (blocks[-1] is fp._noop_block) == (arm == "carry")
+    assert fp.compile_count() == max(compiled, 1) == 1
+
+
+def test_step_keeps_the_argument_and_output_positions(small_ctx):
+    """What the benchmark's planted faults read of `_step`: argument 1
+    is the window's row_valid, argument 8 the alive mask, and the ten
+    outputs come as (choice, est_T, l_chosen, d, b, free, ctx, d1, b1,
+    f1)."""
+    sim = _loaded_sim(small_ctx, kill_frac=0.25)
+    tel = sim.tel
+    fp = _runner(small_ctx, sim)
+    R = 11
+    cols, rows = batch_columns(_batch(small_ctx, R=R, seed=3))
+    cols.ensure_embeddings(small_ctx["bundle"].encoder)
+    seen = {}
+    step = fp._step
+
+    def keep(*args):
+        seen["args"] = args
+        seen["out"] = step(*args)
+        return seen["out"]
+
+    fp._step = keep
+    choice, l_chosen = fp.decide_cols(cols, rows, tel).fetch()
+    args, out = seen["args"], seen["out"]
+    Rb = len(args[1])
+    assert len(args) == 12 and len(out) == 10
+    np.testing.assert_array_equal(np.asarray(args[1]),
+                                  np.arange(Rb) < R)          # row_valid
+    np.testing.assert_array_equal(np.asarray(args[8]),
+                                  _padded(fp, tel.alive, fill=False))
+    c, est, lc = (np.asarray(o) for o in out[:3])
+    np.testing.assert_array_equal(c[:R], choice)
+    np.testing.assert_array_equal(lc[:R].astype(np.float64), l_chosen)
+    assert est.shape == (Rb,) and np.all(np.isfinite(est[:R]))
+    assert np.all(est[:R] > 0.0)
+    d, b, free, ctx = (np.asarray(o) for o in out[3:7])
+    for got, x in zip((d, b, free, ctx), (tel.pending, tel.batch, tel.free,
+                                          tel.ctx)):
+        np.testing.assert_array_equal(got,
+                                      _padded(fp, np.asarray(x, np.float32)))
+    d1, b1, f1 = (np.asarray(o) for o in out[7:10])
+    # dead reckoning: each pick adds its l_chosen to the instance's
+    # pending work and takes a free slot where one was left
+    np.testing.assert_allclose(
+        d1 - d, np.bincount(choice, weights=l_chosen,
+                            minlength=fp._Itot), rtol=1e-5, atol=1e-2)
+    assert np.all(f1 <= free) and np.all(b1 >= b)
+    np.testing.assert_array_equal(free - f1 > 0,
+                                  (np.bincount(choice, minlength=fp._Itot)
+                                   > 0) & (free > 0))
 
 
 # -- the fetch: one padded transfer per result, sliced on the host ------------
