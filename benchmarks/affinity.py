@@ -2,10 +2,10 @@
 multi-turn session workload.
 
 One built ``session_chat`` world (multi-turn conversations sharing
-growing prompt prefixes, `serving.scenarios.SessionSpec`) runs a 2x3
+growing prompt prefixes, `serving.scenarios.SessionSpec`) runs a 2x2
 arm grid on one trained bundle and one request stream: affinity-on
-(``RBConfig.affinity_weight > 0``) vs affinity-off, under each of the
-three decision backends. The sim's prefill-cache physics is identical
+(``RBConfig.affinity_weight > 0``) vs affinity-off, under the numpy and
+fused decision backends. The sim's prefill-cache physics is identical
 in every arm — `Instance._admit` discounts prefill by the matched
 prefix whether or not the router scored for it — so the arms isolate
 exactly what affinity-aware ROUTING adds: follow-up turns landing on
@@ -35,7 +35,7 @@ from repro.serving.scenarios import get_scenario
 
 SMOKE = os.environ.get("REPRO_AFFINITY_SMOKE", "") not in ("", "0")
 N_CELL = 140 if SMOKE else 420
-BACKENDS = ("numpy", "jax", "fused")
+BACKENDS = ("numpy", "fused")
 W_AFF = 0.35
 
 

@@ -1,4 +1,4 @@
-"""Full hot-path latency: staged numpy vs staged jax vs the fused
+"""Full hot-path latency: the staged numpy reference vs the fused
 single-dispatch program vs the Pallas decision megakernel
 (`repro.kernels.decision_megakernel` — one kernel for KNN top-k ->
 packed GBM -> admission -> LPT greedy scan; megakernel rows carry
@@ -9,7 +9,7 @@ token padding, the sentence encoder, the batched KNN lookup, the
 per-tier TPOT heads, Eq. 2 admission, LPT ordering and the dead-reckoned
 greedy pass — i.e. exactly what `RouteBalance._decide_core` runs per
 fired batch (the paper's ~32 ms/batch headline, §6.3). The staged
-backends pay one device dispatch + host round trip per stage; the fused
+numpy backend pays a host round trip per stage; the fused
 backend (`repro.core.hotpath`) pays one dispatch total with
 device-resident constants and state.
 
@@ -46,7 +46,7 @@ SMOKE = os.environ.get("REPRO_HOTPATH_SMOKE", "") not in ("", "0")
 GRID = (((8, 13), (16, 13)) if SMOKE else
         ((8, 13), (16, 13), (64, 13), (256, 13), (256, 52), (256, 128),
          (512, 128)))
-BACKENDS = ("numpy", "jax", "fused", "megakernel")
+BACKENDS = ("numpy", "fused", "megakernel")
 
 
 def scaled_pool(tiers, I):
@@ -109,7 +109,7 @@ def _bench_cell(ctx, R, I, reps):
     # per-rep paired differences share ambient (CPU-frequency, co-tenant)
     # conditions, so their median is far more noise-robust than the
     # difference of the mins
-    paired = {be: float(np.median(np.array(ts["jax"]) - np.array(v)))
+    paired = {be: float(np.median(np.array(ts["numpy"]) - np.array(v)))
               for be, v in ts.items()}
     return best, paired, agree, breakdown
 
@@ -128,12 +128,10 @@ def main():
             if be == "megakernel":
                 # the one-kernel decision vs the fused-XLA pipeline:
                 # the perf_guard parity-or-better gate's raw material
-                extra += (f";speedup_vs_jax={best['jax']/best[be]:.2f}x"
-                          f";vs_fused={best['fused']/best[be]:.2f}x"
+                extra += (f";vs_fused={best['fused']/best[be]:.2f}x"
                           f";agree={agree:.3f}")
             if be == "fused":
-                extra += (f";speedup_vs_jax={best['jax']/best[be]:.2f}x"
-                          f";margin_vs_jax_ms={paired['fused']*1e3:.2f}"
+                extra += (f";margin_vs_numpy_ms={paired['fused']*1e3:.2f}"
                           f";agree={agree:.3f}"
                           f";host_us={bd['host_s']:.1f}"
                           f";stage_us={bd['stage_s']:.1f}"
@@ -143,7 +141,7 @@ def main():
             csv_row(f"hotpath/{be}_R{R}_I{I}", best[be] * 1e6,
                     f"per_req_us={best[be]/R*1e6:.1f}{extra}")
     if not SMOKE:
-        print(f"# fused margin over staged jax: "
+        print(f"# fused margin over staged numpy: "
               f"{margins.get((64, 13), 0):.1f} ms/batch at R=64,I=13 -> "
               f"{max(m for (R, _), m in margins.items() if R >= 256):.1f}"
               f" ms/batch at R>=256")

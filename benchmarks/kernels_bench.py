@@ -1,20 +1,17 @@
 """Kernel-level microbenchmarks -> BENCH_kernels.json.
 
-Three families:
+Two families:
 
   * the historical hot-spot rows — batched embed+KNN (the paper's
     ~27 ms term), greedy scoring-loop scaling (|I| = 13/100/500;
     paper: 12.8/14.3/22.5 us), knn_topk-vs-oracle;
   * the **decision megakernel grid**: per-batch decision µs over
-    (R, I) cells with megakernel / fused-XLA / staged-jax columns —
+    (R, I) cells with megakernel / fused-XLA / staged-numpy columns —
     the same `RouteBalance._decide_core` probe `benchmarks.hotpath`
     times, here centered on the kernel comparison (interleaved
     min-of-N so ambient CPU drift doesn't bias one backend). Off a
     TPU the megakernel runs in the Pallas interpreter, which executes
-    as XLA; on a TPU the same kernel body compiles with Mosaic;
-  * **multi-window batching**: K coalesced windows through one
-    megakernel dispatch (`FusedHotPath.decide_cols_multi`) vs K
-    separate dispatches — the launch/sync amortization rows.
+    as XLA; on a TPU the same kernel body compiles with Mosaic.
 
 Smoke mode for CI: REPRO_KERNELS_SMOKE=1 trims the decision grid to the
 small cells (a subset of the full grid, so perf_guard can gate smoke
@@ -36,8 +33,6 @@ FLUSH_AS = "kernels"     # artifact name: BENCH_kernels.json
 SMOKE = os.environ.get("REPRO_KERNELS_SMOKE", "") not in ("", "0")
 DECISION_GRID = (((8, 13), (16, 13)) if SMOKE else
                  ((8, 13), (16, 13), (64, 13), (64, 52), (256, 128)))
-MULTIWIN_GRID = (((4, 16, 13),) if SMOKE else
-                 ((4, 16, 13), (8, 16, 13), (4, 64, 52)))
 
 
 def _time(fn, n=20, warmup=3):
@@ -54,7 +49,7 @@ def _decision_cells(ctx):
     from .hotpath import scaled_pool
     from repro.serving.cluster import ClusterSim
     from repro.serving.scenarios import randomize_telemetry
-    backends = ("jax", "fused", "megakernel")
+    backends = ("numpy", "fused", "megakernel")
     for R, I in DECISION_GRID:
         tiers = (ctx["tiers"]
                  if I == sum(t.n_instances for t in ctx["tiers"])
@@ -86,49 +81,11 @@ def _decision_cells(ctx):
             f"kernels/decision_R{R}_I{I}", best["megakernel"],
             f"megakernel_us={best['megakernel']:.1f}"
             f";fused_us={best['fused']:.1f}"
-            f";staged_us={best['jax']:.1f}"
+            f";staged_us={best['numpy']:.1f}"
             f";per_req_us={best['megakernel']/R:.1f}"
             f";vs_fused={best['fused']/best['megakernel']:.2f}x"
-            f";vs_staged={best['jax']/best['megakernel']:.2f}x"
+            f";vs_staged={best['numpy']/best['megakernel']:.2f}x"
             f";agree={agree:.3f}")
-
-
-def _multiwin_cells(ctx):
-    """K windows, one dispatch vs K dispatches."""
-    from .hotpath import scaled_pool
-    from repro.core.engine import BatchView
-    from repro.core.scheduler import RouteBalancePolicy
-    from repro.serving.cluster import ClusterSim
-    from repro.serving.scenarios import randomize_telemetry
-    for K, R, I in MULTIWIN_GRID:
-        tiers = (ctx["tiers"]
-                 if I == sum(t.n_instances for t in ctx["tiers"])
-                 else scaled_pool(ctx["tiers"], I))
-        sim = randomize_telemetry(
-            ClusterSim(tiers, ctx["names"], seed=0), seed=1)
-        reqs = make_requests(ctx["ds"], "test", np.zeros(K * R))
-        views = [BatchView(reqs[i * R:(i + 1) * R]) for i in range(K)]
-        pol = RouteBalancePolicy(RBConfig(decision_backend="megakernel",
-                                          window_coalesce=K))
-        pol.prepare(ctx["bundle"], tiers)
-        pol.on_attach(sim)
-
-        def coalesced():
-            for res in pol.assign_windows(views, sim):
-                res.fetch()
-
-        def separate():
-            for v in views:
-                pol.assign(v, sim).fetch()
-
-        coalesced(), separate()                 # compile both shapes
-        dt_c = _time(coalesced, n=12) / K
-        dt_s = _time(separate, n=12) / K
-        csv_row(
-            f"kernels/decision_multiwin_K{K}_R{R}_I{I}", dt_c * 1e6,
-            f"per_window_us={dt_c*1e6:.1f}"
-            f";separate_per_window_us={dt_s*1e6:.1f}"
-            f";amortization={dt_s/dt_c:.2f}x")
 
 
 def main():
@@ -179,9 +136,8 @@ def main():
         kref.knn_topk_ref(q, x, k=10)), n=10)
     csv_row("kernels/knn_topk_pallas", dt_ref * 1e6,
             f"allclose_err={err:.1e};jnp_oracle_us={dt_ref*1e6:.0f}")
-    # the decision megakernel grid + multi-window amortization
+    # the decision megakernel grid
     _decision_cells(ctx)
-    _multiwin_cells(ctx)
     return None
 
 

@@ -17,7 +17,7 @@ decision path fails the guard loudly instead of gating a dead code
 path.
 
 Only the **fused** rows gate (the production hot path this guard
-protects); staged numpy/jax rows print informationally — their Python
+protects); staged numpy rows print informationally — their Python
 loops are far noisier under co-tenant load, and a regression there
 doesn't ship. A failing cell is re-timed once (min of the two runs)
 before it counts, since even min-of-N timing jitters tens of percent on

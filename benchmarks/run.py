@@ -13,10 +13,10 @@ import sys
 import time
 import traceback
 
-MODULES = ("predictors", "kernels_bench", "decision_core", "hotpath",
-           "sweep", "replay", "frontier", "residual", "isolation",
-           "batching", "budget", "tier_loss", "ladder", "tails",
-           "roofline", "elastic", "chaos", "affinity", "hierarchy")
+MODULES = ("predictors", "kernels_bench", "hotpath", "sweep", "replay",
+           "frontier", "residual", "isolation", "batching", "budget",
+           "tier_loss", "ladder", "tails", "roofline", "elastic", "chaos",
+           "affinity", "hierarchy")
 
 
 def main() -> None:

@@ -11,16 +11,16 @@ per-request cost, measured decision time with a host/stage/device/sync
 breakdown (mean us per fired batch, from ``FusedHotPath.stats`` — see
 ``benchmarks.hotpath`` for the column semantics) plus delta-telemetry
 counters, goodput (SLO-bounded throughput) and a per-weight-config
-parity probe — ``parity`` is fused-vs-staged-jax agreement and
-``parity_np`` fused-vs-numpy; both are exact-1.0 guarantees since the
-epsilon-quantized tie-break (`repro.core.scoring`) and gated at 1.0 in
-CI — landing in ``BENCH_sweep.json`` via benchmarks.run.
+parity probe — ``parity_np``, fused-vs-numpy agreement, an exact-1.0
+guarantee since the epsilon-quantized tie-break (`repro.core.scoring`)
+and gated at 1.0 in CI — landing in ``BENCH_sweep.json`` via
+benchmarks.run.
 
 The ``sweep/hyperscale_*`` family promotes the 16-tier x 128-instance
 scenario into the committed artifact on the decision-megakernel backend
 (`RBConfig(decision_backend="megakernel")`): a smaller weights x loads
 grid at CI-nightly sizing, carrying the same decide_ms_per_req +
-per-stage breakdown columns, with the parity probes anchored on the
+per-stage breakdown columns, with the parity probe anchored on the
 megakernel.
 
 Smoke mode for CI: REPRO_SWEEP_SMOKE=1 trims the grid (small rosters,
@@ -60,15 +60,15 @@ HYPER_DATASET_N = 300 if SMOKE else 800
 def _parity_probe(run, bundle, weights, R=16, seed=7,
                   cell_backend="fused"):
     """Probe batch under THIS cell's weight vector on a randomly-loaded
-    roster. Returns (cell-backend-vs-staged-jax agreement,
-    cell-backend-vs-numpy agreement); both are exact-parity guarantees
-    under the epsilon-quantized tie-break and gate the artifact at
-    1.0 (the hyperscale family anchors on the megakernel backend)."""
+    roster. Returns the cell backend's agreement with numpy, an
+    exact-parity guarantee under the epsilon-quantized tie-break that
+    gates the artifact at 1.0 (the hyperscale family anchors on the
+    megakernel backend)."""
     reqs = run.requests(R, seed=seed)[:R]
     for r in reqs:
         r.arrival = 0.0
     picks = {}
-    for be in ("numpy", "jax", cell_backend):
+    for be in ("numpy", cell_backend):
         rb = RouteBalance(
             RBConfig(weights=weights, decision_backend=be), bundle,
             run.tiers)
@@ -76,13 +76,11 @@ def _parity_probe(run, bundle, weights, R=16, seed=7,
             ClusterSim(run.tiers, run.names, seed=0), seed)
         instances, choice, _ = rb._decide_core(reqs)
         picks[be] = [instances[int(i)].iid for i in choice]
-    agree = {be: float(np.mean([a == b for a, b in
-                                zip(picks[be], picks[cell_backend])]))
-             for be in ("jax", "numpy")}
-    return agree["jax"], agree["numpy"]
+    return float(np.mean([a == b for a, b in
+                          zip(picks["numpy"], picks[cell_backend])]))
 
 
-def _cell_row(scene, run, sc, rb, m, wname, scale, parity, parity_np):
+def _cell_row(scene, run, sc, rb, m, wname, scale, parity_np):
     lam = sc.lam * scale
     # per-fired-batch decision breakdown over the whole cell
     # (FusedHotPath.stats is a per-cell window: for_bundle resets it
@@ -114,7 +112,6 @@ def _cell_row(scene, run, sc, rb, m, wname, scale, parity, parity_np):
         f";full_reseeds={st.get('full_reseed', 0)}"
         f";delta_syncs={st.get('delta_sync', 0)}"
         f";carries={st.get('carry', 0)}"
-        f";parity={parity:.3f}"
         f";parity_np={parity_np:.3f}"
         + tenant_cols(m))
 
@@ -128,8 +125,8 @@ def _hyperscale_cells():
     bundle = run.bundle()
     warm_reqs = run.requests(128, seed=99)
     for wname, w in HYPER_WEIGHTS:
-        parity, parity_np = _parity_probe(
-            run, bundle, w, cell_backend="megakernel")
+        parity_np = _parity_probe(run, bundle, w,
+                                  cell_backend="megakernel")
         warm = RouteBalance(
             RBConfig(weights=w, decision_backend="megakernel"),
             bundle, run.tiers)
@@ -143,7 +140,7 @@ def _hyperscale_cells():
                 bundle, run.tiers)
             m = run.run_cell(rb, reqs, seed=0)
             _cell_row("hyperscale", run, sc, rb, m, wname, scale,
-                      parity, parity_np)
+                      parity_np)
 
 
 def main():
@@ -153,7 +150,7 @@ def main():
         bundle = run.bundle()
         warm_reqs = run.requests(128, seed=99)
         for wname, w in WEIGHTS:
-            parity, parity_np = _parity_probe(run, bundle, w)
+            parity_np = _parity_probe(run, bundle, w)
             # deterministic warm-up: compile every pow2 R bucket the
             # overloaded cells can reach (backlog pushes batch sizes up
             # through 128) so XLA compiles land outside the measured
@@ -171,8 +168,7 @@ def main():
                     RBConfig(weights=w, decision_backend="fused"),
                     bundle, run.tiers)
                 m = run.run_cell(rb, reqs, seed=0)
-                _cell_row(scene, run, sc, rb, m, wname, scale,
-                          parity, parity_np)
+                _cell_row(scene, run, sc, rb, m, wname, scale, parity_np)
     _hyperscale_cells()
 
 

@@ -1,8 +1,5 @@
-from .assignment import greedy_assign, greedy_assign_jax, hungarian, \
-    lpt_order
+from .assignment import greedy_assign, hungarian, lpt_order
 from .budget import admission_mask, max_tokens_clamp
-from .decision_jax import decide_batch as decide_batch_jax, \
-    greedy_core as greedy_core_jax
 from .dispatchers import DISPATCHERS, RandomDispatch, RoundRobin, \
     ShortestQueue
 from .driver import make_requests, run_cell

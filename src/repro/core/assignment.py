@@ -107,30 +107,6 @@ def greedy_assign(order: np.ndarray, q_hat_inst: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# JAX variant: delegates to the jitted full-parity decision core
-# (repro.core.decision_jax) — the whole greedy pass as one lax.scan,
-# sharing the Eq. 1 / dead-reckoning math with the numpy loop above.
-
-def greedy_assign_jax(order, q_hat_inst, c_hat, len_inst, tpot, d, b, free,
-                      max_batch, weights,
-                      allowed: Optional[np.ndarray] = None,
-                      latency_mode: str = "full",
-                      nominal_tpot: Optional[np.ndarray] = None):
-    from .decision_jax import greedy_core
-
-    if allowed is None:
-        allowed = np.ones(np.shape(q_hat_inst), bool)
-    if nominal_tpot is None:
-        nominal_tpot = tpot
-    weights = tuple(float(w) for w in weights)
-    choice, _ = greedy_core(np.asarray(order), q_hat_inst, c_hat,
-                            len_inst, tpot, nominal_tpot, d, b, free,
-                            max_batch, weights, allowed,
-                            latency_mode=latency_mode)
-    return choice
-
-
-# ---------------------------------------------------------------------------
 # Hungarian (Jonker-free O(n^3) reference) for the offline replay: a
 # batch-level matching differs from greedy only through within-batch state
 # updates; the paper measures 15.6% assignment divergence with -0.002

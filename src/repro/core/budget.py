@@ -3,8 +3,8 @@ time, worst-case enforcement at dispatch (max_tokens clamp) plus the
 engine's streaming early-stop (§4.1, §6.4).
 
 `admission_math` is backend-agnostic (numpy or jax.numpy) so the numpy
-production path and the jitted decision core (`repro.core.decision_jax`)
-evaluate one shared definition of Eq. 2 — no fancy indexing, only
+reference loop, the fused program (`repro.core.hotpath`) and the
+megakernel evaluate one shared definition of Eq. 2 — no fancy indexing, only
 where/min, so it traces under jit and inside the Mosaic kernel unchanged.
 """
 from __future__ import annotations

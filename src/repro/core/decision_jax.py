@@ -1,46 +1,33 @@
-"""Jitted full-parity decision core: the whole per-batch RouteBalance
-decision as one array program (§4).
+"""The greedy scan of the jitted decision: the LPT-ordered,
+dead-reckoned Eq. 1 pass (§4) that the fused program (`repro.core.hotpath`)
+traces, with the pieces the Pallas megakernel and the cell-sharded span
+path share.
 
-The numpy production loop (`assignment.greedy_assign`) walks the batch
-request-by-request in Python; this module runs the identical math —
-Eq. 1 scoring with per-request normalization (`scoring.masked_score`),
-Eq. 2 budget admission (`budget.admission_math`), all four
-``latency_mode`` isolation arms, LPT ordering and the dead-reckoned
-state updates — as a single jitted ``lax.scan``, selectable in
-production via ``RBConfig(decision_backend="jax")``.
+  * ``greedy_step`` — one step of the scan: THE one definition of the
+    per-step arithmetic (Eq. 1 scoring through `scoring.masked_score`,
+    all four ``latency_mode`` isolation arms, the pick and the state
+    update); ``_greedy_scan``'s lax.scan and the megakernel's in-kernel
+    fori_loop both trace it;
+  * ``_greedy_scan`` — the scan over a precomputed order and Eq. 2
+    admission mask, mirroring the numpy reference loop
+    (`assignment.greedy_assign`) operation for operation;
+  * ``sharded_greedy_scan`` / ``cell_greedy_*`` — the bitwise-identical
+    cell-sharded decomposition of that scan (hierarchical scheduling);
+  * ``first_index``, ``bucket_pow2`` and ``LATENCY_MODES``, shared with
+    the megakernel, the runner and the ingest layer.
 
-Two jitted entry points:
-
-  * ``greedy_core``  — the scan alone (order/mask precomputed), the
-    drop-in twin of ``greedy_assign``; ``greedy_assign_jax`` delegates
-    here.
-  * ``decide_batch`` — the full per-batch pipeline (LPT order + Eq. 2
-    admission + scan) traced end-to-end; ``decide`` is the numpy-in /
-    numpy-out wrapper the scheduler calls.
-
-The estimator step that feeds this core (batched KNN over prompt
-embeddings) runs through the Pallas ``knn_topk`` kernel when the bundle
-is built with ``KNNEstimator(backend="pallas")`` or the scheduler is
-configured with ``RBConfig(knn_backend="pallas")``.
-
-Differential parity with the numpy loop is asserted in
-``tests/test_decision_parity.py`` across every mode arm. The math here
-is float32 (the jit default) while numpy runs float64; the shared
-scoring math epsilon-quantizes Eq. 1 scores (`repro.core.scoring`), so
-sub-quantum float noise collapses to exact, identically-broken ties in
-both precisions and the randomized soak asserts three-way assignment
-parity on every seed with no pinned exclusions (``tests/test_soak.py``).
+The math is float32 (the jit default) and the numpy loop runs float32
+too; the shared scoring math epsilon-quantizes Eq. 1 scores
+(`repro.core.scoring`), so sub-quantum float noise collapses to exact,
+identically-broken ties and the randomized soak asserts numpy / fused /
+megakernel assignment parity on every seed with no pinned exclusions
+(``tests/test_soak.py``, ``tests/test_decision_parity.py``).
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from .budget import admission_math, cost_matrix
 from .scoring import affinity_discount, masked_score, quantize_scores
 
 LATENCY_MODES = ("full", "off_reactive", "off_predictive", "static_prior")
@@ -68,7 +55,7 @@ def greedy_step(q_r, c_r, l_r, allowed_r, aff_r, valid_r, d, b, free, *,
     """One greedy-scan step: Eq. 1 score for one request over the live
     dead-reckoned state, the pick, and the state update. THE one
     definition of the per-step arithmetic — `_greedy_scan`'s lax.scan
-    (staged-jax and fused-XLA backends) and the Pallas megakernel's
+    (the fused-XLA backend) and the Pallas megakernel's
     in-kernel fori_loop (`repro.kernels.decision_megakernel`) both
     trace this body, which is what makes their dead-reckoned carries
     bitwise identical by construction rather than by luck.
@@ -121,7 +108,7 @@ def greedy_step(q_r, c_r, l_r, allowed_r, aff_r, valid_r, d, b, free, *,
 def _greedy_scan(order, q_inst, c_hat, l_inst, tpot, nominal_tpot,
                  d, b, free, max_batch, weights, allowed,
                  latency_mode: str, row_valid=None, affinity=None):
-    """Traced body shared by both entry points. Mirrors
+    """The greedy scan the fused program traces. Mirrors
     ``assignment.greedy_assign`` operation-for-operation.
 
     ``row_valid`` (R,) optionally marks shape-padding rows: invalid rows
@@ -156,105 +143,6 @@ def _greedy_scan(order, q_inst, c_hat, l_inst, tpot, nominal_tpot,
     return choice, est_T, (d, b, free)
 
 
-def _f(x):
-    return jnp.asarray(x, jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("latency_mode",))
-def greedy_core(order, q_inst, c_hat, l_inst, tpot, nominal_tpot,
-                d, b, free, max_batch, weights, allowed,
-                latency_mode: str = "full", affinity=None):
-    """Jitted greedy pass over a precomputed order + admission mask."""
-    choice, est_T, state = _greedy_scan(
-        jnp.asarray(order), _f(q_inst), _f(c_hat), _f(l_inst), _f(tpot),
-        _f(nominal_tpot), _f(d), _f(b), _f(free), _f(max_batch),
-        weights, jnp.asarray(allowed, bool), latency_mode,
-        affinity=None if affinity is None else _f(affinity))
-    return choice, est_T
-
-
-@functools.partial(jax.jit, static_argnames=("latency_mode", "lpt",
-                                             "budget_filter"))
-def decide_batch(q_inst, l_inst, pred_len_max, tpot, nominal_tpot,
-                 d, b, free, max_batch, budgets, len_in,
-                 price_in, price_out, weights,
-                 latency_mode: str = "full", lpt: bool = True,
-                 budget_filter: bool = True, affinity=None):
-    """The whole per-batch decision, traced end-to-end.
-
-    q_inst/l_inst: (R, I) per-instance quality / predicted length;
-    pred_len_max: (R,) max predicted length over *models* (LPT key);
-    tpot/nominal_tpot/d/b/free/max_batch: (I,) instance state;
-    budgets (R,) with nan = unconstrained; len_in (R,);
-    price_in/price_out (I,); affinity optionally (R, I) prefix-reuse
-    discount. Returns (choice (R,), est_T (R,), c_hat (R, I),
-    allowed (R, I)).
-    """
-    q_inst, l_inst = _f(q_inst), _f(l_inst)
-    budgets, len_in = _f(budgets), _f(len_in)
-    price_in, price_out = _f(price_in), _f(price_out)
-    R = q_inst.shape[0]
-    if lpt:
-        order = jnp.argsort(-_f(pred_len_max), stable=True)
-    else:
-        order = jnp.arange(R)
-    if budget_filter:
-        allowed, c_hat = admission_math(budgets, len_in, l_inst,
-                                        price_in, price_out, jnp)
-    else:
-        c_hat = cost_matrix(len_in, l_inst, price_in, price_out, jnp)
-        allowed = jnp.ones(c_hat.shape, bool)
-    choice, est_T, _ = _greedy_scan(
-        order, q_inst, c_hat, l_inst, _f(tpot), _f(nominal_tpot),
-        _f(d), _f(b), _f(free), _f(max_batch), weights, allowed,
-        latency_mode,
-        affinity=None if affinity is None else _f(affinity))
-    return choice, est_T, c_hat, allowed
-
-
-def decide(q_inst: np.ndarray, l_inst: np.ndarray,
-           pred_len_max: np.ndarray, tpot: np.ndarray,
-           nominal_tpot: np.ndarray, d: np.ndarray, b: np.ndarray,
-           free: np.ndarray, max_batch: np.ndarray,
-           budgets: np.ndarray, len_in: np.ndarray,
-           price_in: np.ndarray, price_out: np.ndarray, weights,
-           latency_mode: str = "full", lpt: bool = True,
-           budget_filter: bool = True,
-           affinity: Optional[np.ndarray] = None
-           ) -> Tuple[np.ndarray, np.ndarray]:
-    """numpy-in / numpy-out wrapper for the scheduler hot path.
-
-    Batches are padded up to the next power of two so the jit cache sees
-    O(log R) distinct shapes instead of one per batch size. Padding is
-    parity-safe: pad rows carry a -inf LPT key so they scan strictly
-    after every real request — their dead-reckoning updates can only
-    affect later (i.e. other pad) steps — and their choices are dropped.
-    """
-    R = q_inst.shape[0]
-    Rp = bucket_pow2(R)
-    if Rp != R:
-        pad = Rp - R
-        q_inst = np.pad(np.asarray(q_inst, float), ((0, pad), (0, 0)))
-        l_inst = np.pad(np.asarray(l_inst, float), ((0, pad), (0, 0)))
-        pred_len_max = np.concatenate(
-            [np.asarray(pred_len_max, float), np.full(pad, -1e30)])
-        budgets = np.concatenate(
-            [np.asarray(budgets, float), np.full(pad, np.nan)])
-        len_in = np.concatenate(
-            [np.asarray(len_in, float), np.zeros(pad)])
-        if affinity is not None:
-            affinity = np.pad(np.asarray(affinity, np.float32),
-                              ((0, pad), (0, 0)))
-    weights = tuple(float(w) for w in weights)
-    choice, est_T, _, _ = decide_batch(
-        q_inst, l_inst, pred_len_max, tpot, nominal_tpot, d, b, free,
-        max_batch, budgets, len_in, price_in, price_out, weights,
-        latency_mode=latency_mode, lpt=lpt, budget_filter=budget_filter,
-        affinity=affinity)
-    return (np.asarray(choice[:R], np.int64),
-            np.asarray(est_T[:R], np.float64))
-
-
 # ---------------------------------------------------------------------------
 # Cell-sharded greedy scan (hierarchical scheduling, ROADMAP item 1)
 # ---------------------------------------------------------------------------
@@ -277,7 +165,7 @@ def decide(q_inst: np.ndarray, l_inst: np.ndarray,
 #     identical expression in the identical operation order as
 #     `greedy_step`, evaluated on each block's slice of the same
 #     float32 inputs; scores pass through the shared epsilon
-#     quantization, which is what already makes numpy == jax == fused
+#     quantization, which is what already makes numpy == fused
 #     exact across program boundaries;
 #   * dead-reckoning updates land via drop-mode scatters so non-winner
 #     cells are untouched bit-for-bit (no +0.0 writes that could flip a

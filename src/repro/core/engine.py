@@ -192,10 +192,7 @@ class SchedulingPolicy:
         return {}
 
     def prepare(self, bundle, tiers: Sequence[Tier]):
-        """Bind the estimator stack once per engine. Policies that
-        keep a reference may rebind a private copy (e.g. a different
-        KNN backend) and expose it as `self.bundle` — the engine picks
-        the rebound copy up."""
+        """Bind the estimator stack once per engine."""
         self.bundle = bundle
 
     def fit(self, emb: np.ndarray, quality: np.ndarray,
@@ -248,9 +245,7 @@ class ServingEngine:
         self.ecfg = cfg
         self.tiers = list(tiers)
         policy.prepare(bundle, self.tiers)
-        # a policy may rebind a private bundle copy (knn_backend): the
-        # engine must stage/ingest through the same stack it decides on
-        self.bundle = getattr(policy, "bundle", None) or bundle
+        self.bundle = bundle
         self.sim: Optional[ClusterSim] = None
         self._measured_compute = 0.004  # warm estimate, updated online
         self.decisions = 0

@@ -34,10 +34,10 @@ encoder forward over the padded token matrix, and a blocking
     (roster-shape events, tracked by ``tel.roster_version``, or most of
     the roster dirty); a window with nothing written uploads no
     telemetry at all. The refreshed mirror is bitwise the
-    staged backends' reseed-per-batch host read — untouched rows'
+    numpy reference's reseed-per-batch host read — untouched rows'
     telemetry has not moved — so carry-forward is now the common case
     AND exact-parity-safe (the PR-2 semantics, which carried post-scan
-    dead-reckoned state, only matched staged when nothing on the
+    dead-reckoned state, only matched numpy when nothing on the
     cluster moved; that branch almost never fired and silently diverged
     when it did not);
   * **async dispatch** — ``decide_cols`` returns a ``LazyDecision``
@@ -54,7 +54,7 @@ Batch size R and roster size I are still bucketed to powers of two
 columns stay permanently dead and instance death is an ``alive`` mask
 (no recompile after a failure). Eq. 1 scores are epsilon-quantized in
 the shared scoring math (`repro.core.scoring`), so the fused program
-makes exactly the staged backends' assignments — numpy included — on
+makes exactly the numpy reference's assignments on
 randomized worlds (``tests/test_soak.py``).
 """
 from __future__ import annotations
@@ -117,21 +117,19 @@ class LazyDecision:
     """An in-flight fused decision: the step's padded device outputs,
     whose host transfers the runner started at the launch, fetched only
     when the caller needs the values (the dispatch point). `fetch()`
-    waits for the transfers, then slices off the shape-padding rows (and
-    picks window `k` of a multi-window dispatch) in numpy — no device
-    program — and returns fresh arrays, idempotently, so diagnostics may
-    re-fetch. The K decisions of one multi-window dispatch share its
-    arrays and so its one transfer. This is the fused policy's
+    waits for the transfers, then slices off the shape-padding rows in
+    numpy — no device program — and returns fresh arrays, idempotently,
+    so diagnostics may re-fetch. This is the fused policy's
     `AssignmentResult` payload (`repro.core.engine`): the engine's
     windowed dispatch overlaps its host bookkeeping with the device
     program and fetches last."""
 
-    __slots__ = ("_choice", "_l", "_rows", "_stats", "_out")
+    __slots__ = ("_choice", "_l", "_R", "_stats", "_out")
 
-    def __init__(self, choice, l_chosen, rows, stats: Dict):
+    def __init__(self, choice, l_chosen, R: int, stats: Dict):
         self._choice = choice
         self._l = l_chosen
-        self._rows = rows        # numpy index: `np.s_[:R]` or `np.s_[k, :R]`
+        self._R = R              # real rows; the rest are shape padding
         self._stats = stats
         self._out: Optional[Tuple[np.ndarray, np.ndarray]] = None
 
@@ -143,8 +141,8 @@ class LazyDecision:
                     choice, l_chosen = jax.device_get((self._choice,
                                                        self._l))
                 with span("rb.copy", st, "sync_s"):
-                    self._out = (np.array(choice[self._rows], np.int64),
-                                 np.array(l_chosen[self._rows], np.float64))
+                    self._out = (np.array(choice[:self._R], np.int64),
+                                 np.array(l_chosen[:self._R], np.float64))
         return self._out
 
 
@@ -290,7 +288,7 @@ class FusedHotPath:
         self._use_gbm = (cfg.latency_mode != "static_prior"
                          and cfg.learned_tpot)
         if self._use_gbm:
-            # partial fits would silently diverge from the staged
+            # partial fits would silently diverge from the numpy
             # per-tier learned/nominal fallback — refuse instead
             assert all(h.model is not None for h in heads), \
                 "fused backend needs every TPOT head fitted (or " \
@@ -309,14 +307,6 @@ class FusedHotPath:
         # d 4, b 5, free 6, ctx 7, alive 8, telemetry block 9, psig 10,
         # sig_plane 11 (appended so donate indices stay fixed)
         self._step = jax.jit(self._step_impl, donate_argnums=(4, 5, 6, 7))
-        # multi-window megakernel dispatch: same signature with a
-        # leading K axis on the per-window args; compiled per
-        # (pow2 K, pow2 R) pair, so variants stay O(log K · log R)
-        self._step_multi = (
-            jax.jit(self._step_multi_impl, donate_argnums=(4, 5, 6, 7))
-            if self._backend == "megakernel" else None)
-        self._mstage: Dict[Tuple[int, int], list] = {}
-        self._mflip: Dict[Tuple[int, int], int] = {}
         # the telemetry block is one (5, Itot) shape in every sync arm
         # (the arms differ only in its write mask), so reseed, delta and
         # carry share one compiled program per R bucket and warming the
@@ -344,11 +334,8 @@ class FusedHotPath:
     # -- traced bodies ------------------------------------------------------
     def _mega_stages(self, emb, row_valid, budgets, len_in,
                      d, b, free, ctx, alive, psig, sig_plane):
-        """Stages 1–4 as the single Pallas megakernel dispatch. The
-        per-window args carry a leading K axis (K=1 for the plain
-        step); telemetry mirror + estimator constants are shared
-        blocks. Returns (choice, est_T, l_chosen, d1, b1, f1) with the
-        K axis intact."""
+        """Stages 1–4 as the single Pallas megakernel dispatch. Returns
+        (choice, est_T, l_chosen, d1, b1, f1)."""
         from repro.kernels.decision_megakernel import (decision_call,
                                                        dummy_gbm)
         if self._use_gbm:
@@ -378,18 +365,15 @@ class FusedHotPath:
         # into the donated device mirror. The refreshed mirror is
         # bitwise a full host re-read — untouched rows' telemetry has
         # not moved since they were last synced — so every arm
-        # preserves the staged backends' reseed-per-batch semantics.
+        # preserves the numpy reference's reseed-per-batch semantics.
         d, b, free, ctx = _apply_block(d, b, free, ctx, block)
 
         if self._backend == "megakernel":
-            # stages 1–4 fused into one Pallas dispatch (K=1 window);
-            # the refreshed pre-scan mirror still carries forward
-            # exactly as below
-            choice, est_T, l_chosen, d1, b1, f1 = (
-                o[0] for o in self._mega_stages(
-                    emb[None], row_valid[None], budgets[None],
-                    len_in[None], d, b, free, ctx, alive,
-                    psig[None], sig_plane))
+            # stages 1–4 fused into one Pallas dispatch; the refreshed
+            # pre-scan mirror still carries forward exactly as below
+            choice, est_T, l_chosen, d1, b1, f1 = self._mega_stages(
+                emb, row_valid, budgets, len_in, d, b, free, ctx, alive,
+                psig, sig_plane)
             return (choice, est_T, l_chosen, d, b, free, ctx,
                     d1, b1, f1)
 
@@ -461,7 +445,7 @@ class FusedHotPath:
         # the refreshed pre-scan mirror (d, b, free, ctx) is the carried
         # state; (d1, b1, f1) is the post-scan dead-reckoned view, kept
         # for diagnostics/invariant checks only — the next batch reseeds
-        # from telemetry just like the staged backends
+        # from telemetry just like the numpy reference
         return (choice, est_T, l_chosen, d, b, free, ctx, d1, b1, f1)
 
     def _scan(self, order, q_inst, c_hat, l_inst, tpot, d, b_eff, free,
@@ -482,20 +466,6 @@ class FusedHotPath:
             order, q_inst, c_hat, l_inst, tpot, self._nominal,
             d, b_eff, free, self._maxb, self._weights, allowed,
             self._mode, row_valid=row_valid, affinity=aff)
-
-    def _step_multi_impl(self, emb, row_valid, budgets, len_in,
-                         d, b, free, ctx, alive, block, psig, sig_plane):
-        """K coalesced scheduler windows, one megakernel dispatch. The
-        telemetry block applies once; every window scans from the refreshed
-        mirror — bitwise what K back-to-back `_step` calls see when
-        telemetry has not moved between them (the mirror reseeds from
-        telemetry per dispatch, never across-batch dead-reckoning), so
-        coalescing only amortizes launch/sync overhead."""
-        d, b, free, ctx = _apply_block(d, b, free, ctx, block)
-        choice, est_T, l_chosen, d1, b1, f1 = self._mega_stages(
-            emb, row_valid, budgets, len_in, d, b, free, ctx, alive,
-            psig, sig_plane)
-        return (choice, est_T, l_chosen, d, b, free, ctx, d1, b1, f1)
 
     # -- host side ----------------------------------------------------------
     def reset(self):
@@ -518,16 +488,12 @@ class FusedHotPath:
 
     def compile_count(self) -> int:
         """Number of XLA programs compiled for the fused step — one per
-        pow2 R bucket seen (plus one per (pow2 K, pow2 R) pair for the
-        multi-window megakernel dispatch, when used). Roster events
+        pow2 R bucket seen. Roster events
         (fail/recover/autoscale) flip the alive mask and reseed the
         mirror but must NOT add entries here: that is the
         no-recompile-on-scale contract the elastic soak asserts
         (`compile_count() == len(distinct R buckets)`)."""
-        n = int(self._step._cache_size())
-        if self._step_multi is not None:
-            n += int(self._step_multi._cache_size())
-        return n
+        return int(self._step._cache_size())
 
     def _stage_buffers(self, Rb: int) -> Dict[str, np.ndarray]:
         """The preallocated host staging set for the pow2 batch bucket.
@@ -582,7 +548,7 @@ class FusedHotPath:
         marked; with none, the carry arm uploads nothing and hands over
         a device-resident block that writes nothing. Alive is uploaded
         only when the roster or the telemetry object changed. Either
-        way the state handed to the scan equals the staged backends'
+        way the state handed to the scan equals the numpy reference's
         fresh host read of `tel` bit for bit — which is what keeps the
         fused backend in exact assignment parity (regression-tested in
         ``tests/test_ingest.py``; the PR-2 semantics of carrying
@@ -636,7 +602,7 @@ class FusedHotPath:
         """One scheduler batch as a row slice into the SoA ingest
         columns: stage via vectorized gathers into the preallocated
         double-buffered host set, sync the device telemetry mirror
-        (delta scatter in the common case), dispatch the single fused
+        (the dirty rows in the common case), dispatch the single fused
         program, and hand back a `LazyDecision` so the caller's host
         work overlaps device execution."""
         assert cols.emb is not None, \
@@ -665,16 +631,6 @@ class FusedHotPath:
                 psig = s["psig"]
             else:
                 psig, plane = self._dummy_psig, self._dummy_plane
-        out = self._sync_and_dispatch(self._step, staged, s, psig, plane,
-                                      tel)
-        self._post_state = out[7:10]         # post-scan (diagnostics)
-        return LazyDecision(out[0], out[2], np.s_[:R], st)
-
-    def _sync_and_dispatch(self, step, staged: span, s, psig, plane, tel):
-        """Sync the telemetry mirror and launch `step` on the staged
-        buffers; returns its outputs. `staged` is the staging span, so
-        `host_s` gets staging plus sync."""
-        st = self.stats
         with span("rb.telemetry", st, "telemetry_s") as synced:
             state_args = self._sync_state(tel)
         st["host_s"] += staged.seconds + synced.seconds
@@ -682,108 +638,15 @@ class FusedHotPath:
                 *state_args, psig, plane)
         st["uploads"] += _host_arrays(args)
         with span("rb.dispatch", st, "dispatch_s"):
-            out = step(*args)
+            out = self._step(*args)
             self._state = out[3:7]           # refreshed pre-scan mirror
             # the fetch's transfers queue behind the program now, so
             # they overlap each other and the host's work until the fetch
             out[0].copy_to_host_async()
             out[2].copy_to_host_async()
             st["d2h"] += 2
-        return out
-
-    def _multi_buffers(self, Kb: int, Rb: int) -> Dict[str, np.ndarray]:
-        """Double-buffered host staging for the (pow2 K, pow2 R)
-        multi-window bucket, mirroring `_stage_buffers`."""
-        key = (Kb, Rb)
-        pair = self._mstage.get(key)
-        if pair is None:
-            def mk():
-                buf = {"emb": np.zeros((Kb, Rb, self._E), np.float32),
-                       "prow": np.zeros((Kb, Rb), np.int32),
-                       "budgets": np.full((Kb, Rb), np.nan, np.float32),
-                       "len_in": np.zeros((Kb, Rb), np.float32),
-                       "rv": np.zeros((Kb, Rb), bool),
-                       "dummy_psig": jnp.zeros((Kb, 1, 1), jnp.int32)}
-                if self._w_aff > 0.0:
-                    buf["psig"] = np.zeros((Kb, Rb, SIG_WIDTH), np.int32)
-                return buf
-            pair = self._mstage[key] = [mk(), mk()]
-            self._mflip[key] = 0
-        self._mflip[key] ^= 1
-        return pair[self._mflip[key]]
-
-    def decide_cols_multi(self, batches, tel) -> List[LazyDecision]:
-        """K scheduler windows sharing ONE megakernel dispatch
-        (grid=(K,)). `batches` is a list of (cols, rows) window slices;
-        returns one `LazyDecision` per window, in order.
-
-        All windows decide from the same telemetry snapshot — exactly
-        what K back-to-back `decide_cols` calls produce when telemetry
-        has not moved between them (each dispatch reseeds the mirror
-        from `tel`; dead-reckoned state never carries across batches) —
-        so coalescing is assignment-exact while paying one kernel
-        launch, one mirror sync and one staging pass for the K windows.
-        Window count and row count both bucket to powers of two (pad
-        windows are all-invalid rows), keeping compile variants at
-        O(log K · log R). Megakernel backend only."""
-        assert self._backend == "megakernel", self._backend
-        if len(batches) == 1:
-            cols, rows = batches[0]
-            return [self.decide_cols(cols, rows, tel)]
-        st = self.stats
-        K = len(batches)
-        st["calls"] += K
-        st["multi_dispatch"] = st.get("multi_dispatch", 0) + 1
-        with span("rb.stage", st, "stage_s") as staged:
-            s, psig, plane = self._stage_multi(batches, tel)
-        out = self._sync_and_dispatch(self._step_multi, staged, s, psig,
-                                      plane, tel)
-        # diagnostics: the LAST real window's post-scan view (windows
-        # are independent; pad windows apply no updates)
-        self._post_state = tuple(o[K - 1] for o in out[7:10])
-        return [LazyDecision(out[0], out[2], np.s_[ki, :len(rows)], st)
-                for ki, (_, rows) in enumerate(batches)]
-
-    def _stage_multi(self, batches, tel):
-        """Gather K windows into the (pow2 K, pow2 R) staging set;
-        returns (buffers, psig, sig plane)."""
-        K = len(batches)
-        Kb = bucket_pow2(K, lo=1)
-        Rb = bucket_pow2(max(len(rows) for _, rows in batches))
-        s = self._multi_buffers(Kb, Rb)
-        for ki, (cols, rows) in enumerate(batches):
-            assert cols.emb is not None, \
-                "RequestColumns.ensure_embeddings must run before decide"
-            R = len(rows)
-            prow = s["prow"][ki, :R]
-            np.take(cols.prompt_row, rows, out=prow)
-            np.take(cols.emb, prow, axis=0, out=s["emb"][ki, :R])
-            s["emb"][ki, R:] = 0.0
-            s["budgets"][ki, :R] = cols.budget[rows]
-            s["budgets"][ki, R:] = np.nan
-            s["len_in"][ki, :R] = cols.len_in[rows]
-            s["len_in"][ki, R:] = 0.0
-            s["rv"][ki, :R] = True
-            s["rv"][ki, R:] = False
-            if self._w_aff > 0.0:
-                np.take(cols.prefix_sig, prow, axis=0,
-                        out=s["psig"][ki, :R])
-                s["psig"][ki, R:] = 0
-        for ki in range(K, Kb):               # pad windows: no-ops
-            s["emb"][ki] = 0.0
-            s["budgets"][ki] = np.nan
-            s["len_in"][ki] = 0.0
-            s["rv"][ki] = False
-            if self._w_aff > 0.0:
-                s["psig"][ki] = 0
-        if self._w_aff > 0.0:
-            self._pflip ^= 1
-            plane = self._pstage[self._pflip]
-            plane[:self._n_real] = tel.prefix_sig
-            psig = s["psig"]
-        else:
-            psig, plane = s["dummy_psig"], self._dummy_plane
-        return s, psig, plane
+        self._post_state = out[7:10]         # post-scan (diagnostics)
+        return LazyDecision(out[0], out[2], R, st)
 
     def decide(self, batch, tel) -> Tuple[np.ndarray, np.ndarray]:
         """Legacy AoS entry (direct callers, tests): derive the column

@@ -8,9 +8,9 @@ Eq. 1 per request, updating the local instance view after each dispatch.
 Batch formation, SoA ingest, async dispatch and residual charging live
 in `repro.core.engine.ServingEngine` (shared with every baseline
 policy); this module holds the decision itself — `RouteBalancePolicy`
-implementing the `SchedulingPolicy` protocol over the fused / staged
-jax / numpy backends — plus the `RouteBalance` convenience class that
-binds policy and engine the way the paper deploys them (windowed
+implementing the `SchedulingPolicy` protocol over the fused /
+megakernel / numpy backends — plus the `RouteBalance` convenience class
+that binds policy and engine the way the paper deploys them (windowed
 amortized batch scoring).
 """
 from __future__ import annotations
@@ -33,6 +33,8 @@ from .decision_jax import LATENCY_MODES
 from .engine import (AssignmentResult, BatchView, EngineConfig, Ready,
                      SchedulingPolicy, ServingEngine)
 from .weights import PRESETS, Weights, validate
+
+DECISION_BACKENDS = ("numpy", "fused", "megakernel")
 
 if TYPE_CHECKING:
     from repro.serving.recovery import RecoveryConfig
@@ -59,18 +61,11 @@ class RBConfig:
     #                                    repro.kernels.decision_megakernel
     #                                    — behind the same host
     #                                    machinery as fused) |
-    #                                    jax (staged jitted core) |
     #                                    numpy (reference loop)
-    window_coalesce: int = 1           # megakernel only: up to K
-    #                                    scheduler windows share one
-    #                                    kernel dispatch (grid=(K,))
-    #                                    via assign_windows. 1 = one
-    #                                    dispatch per window (default;
-    #                                    matches every other backend)
-    knn_backend: Optional[str] = None  # override bundle's KNN backend
-    #                                    (numpy | jax | pallas); staged
-    #                                    backends only — fused has the
-    #                                    estimator feed in-graph
+    window_coalesce: int = 1           # must be 1: one dispatch per
+    #                                    window. The field stays only
+    #                                    because the benchmark harness's
+    #                                    decision check reads it
     shed: bool = True                  # honor overload admission control
     #                                    when the sim carries an
     #                                    ElasticController (sim.overload)
@@ -81,7 +76,7 @@ class RBConfig:
     #                                    every backend. 0 disables —
     #                                    the term is compiled out of the
     #                                    fused program and skipped by
-    #                                    the staged paths. Kept OUTSIDE
+    #                                    the numpy loop. Kept OUTSIDE
     #                                    `weights`: that tuple is the
     #                                    Eq. 1 simplex (sums to 1);
     #                                    affinity is a discount on the
@@ -154,9 +149,9 @@ class EstimatorBundle:
         of a SoA ingest stream (`repro.serving.request.RequestColumns`)
         the encoder is skipped entirely — the memoized per-prompt
         embedding column is gathered instead (bitwise the per-batch
-        encode, which is padding-stable) — so the staged numpy/jax
-        backends share the fused path's ingest win and the differential
-        harness keeps comparing like for like."""
+        encode, which is padding-stable) — so the numpy backend shares
+        the fused path's ingest win and the differential harness keeps
+        comparing like for like."""
         if cols is None:
             from repro.serving.request import batch_columns
             cols, rows = batch_columns(reqs)
@@ -187,8 +182,9 @@ def _tier_sweep(tier: Tier, rng) -> Tuple[np.ndarray, np.ndarray]:
 class RouteBalancePolicy(SchedulingPolicy):
     """The fused Eq.1/Eq.2 objective as a `SchedulingPolicy`: one
     batched decision over the full roster per fired batch, selectable
-    across the fused single-dispatch program, the staged jitted core,
-    and the numpy reference loop (`RBConfig.decision_backend`)."""
+    across the fused single-dispatch program, the Pallas megakernel
+    behind the same runner, and the numpy reference loop
+    (`RBConfig.decision_backend`)."""
 
     name = "routebalance"
     # under the serial_published deployment ladder arm, charge the warm
@@ -201,15 +197,15 @@ class RouteBalancePolicy(SchedulingPolicy):
     def __init__(self, cfg: RBConfig):
         self.cfg = cfg
         validate(cfg.weights)
-        assert cfg.decision_backend in ("numpy", "jax", "fused",
-                                        "megakernel"), \
-            cfg.decision_backend
-        assert cfg.window_coalesce >= 1, cfg.window_coalesce
-        assert (cfg.window_coalesce == 1
-                or cfg.decision_backend == "megakernel"), \
-            "window_coalesce > 1 needs decision_backend='megakernel'"
-        assert cfg.knn_backend in (None, "numpy", "jax", "pallas"), \
-            cfg.knn_backend
+        if cfg.decision_backend not in DECISION_BACKENDS:
+            raise ValueError(
+                f"decision_backend {cfg.decision_backend!r}: expected one "
+                f"of {DECISION_BACKENDS}")
+        if cfg.window_coalesce != 1:
+            raise ValueError(
+                f"window_coalesce {cfg.window_coalesce!r}: the "
+                f"multi-window dispatch was removed; each window is one "
+                f"dispatch (window_coalesce=1)")
         assert cfg.latency_mode in LATENCY_MODES, cfg.latency_mode
         assert 0.0 <= cfg.affinity_weight <= 1.0, cfg.affinity_weight
         sc = int(cfg.shard_cells or 0)
@@ -232,18 +228,6 @@ class RouteBalancePolicy(SchedulingPolicy):
             out["recovery"] = cfg.recovery
         return out
 
-    def prepare(self, bundle, tiers: Sequence[Tier]):
-        cfg = self.cfg
-        if (cfg.knn_backend is not None
-                and cfg.knn_backend != bundle.knn.backend):
-            # rebind the estimator feed (e.g. the Pallas knn_topk kernel)
-            # on a copy so a shared bundle is not mutated across schedulers
-            bundle = EstimatorBundle(bundle.encoder,
-                                     bundle.knn.with_backend(
-                                         cfg.knn_backend),
-                                     bundle.heads, bundle.model_names)
-        self.bundle = bundle
-
     def on_attach(self, sim: ClusterSim):
         self._fused = None                    # new sim -> new roster
 
@@ -257,36 +241,18 @@ class RouteBalancePolicy(SchedulingPolicy):
     def assign(self, batch: BatchView, cluster: ClusterSim
                ) -> AssignmentResult:
         """Dispatch the per-batch decision; the fused backend's payload
-        is a LazyDecision (device arrays, fetched later); the
-        staged backends' is already numpy."""
+        is a LazyDecision (device arrays, fetched later); the numpy
+        backend's is already numpy."""
         if self.cfg.decision_backend in ("fused", "megakernel"):
             instances, res = self._decide_fused(batch, cluster)
             return AssignmentResult(instances, res)
         instances, choice, l_chosen = self._decide_staged(batch, cluster)
         return AssignmentResult(instances, Ready(choice, l_chosen))
 
-    def assign_windows(self, batches: List[BatchView],
-                       cluster: ClusterSim) -> List[AssignmentResult]:
-        """K scheduler windows as ONE device dispatch (megakernel only:
-        `FusedHotPath.decide_cols_multi`, grid=(K,)). All K windows
-        decide against the same telemetry snapshot — exactly what K
-        back-to-back `assign` calls see when telemetry has not moved
-        between them, so coalescing is assignment-exact there while
-        paying one kernel launch for K windows. Falls back to per-window
-        `assign` for every other backend (and for K == 1)."""
-        if (self.cfg.decision_backend != "megakernel"
-                or len(batches) <= 1):
-            return [self.assign(bv, cluster) for bv in batches]
-        runner = self._fused_runner(cluster)
-        slices = [bv.columns(self.bundle.encoder) for bv in batches]
-        lazies = runner.decide_cols_multi(slices, cluster.tel)
-        return [AssignmentResult(cluster.instances, lz)
-                for lz in lazies]
-
     def _fused_runner(self, sim: ClusterSim):
         """The lazily-built FusedHotPath over this sim's roster — THE
         seam hierarchical policies interpose on (a sharded runner, a
-        per-cell runner), shared by `assign` and `assign_windows`."""
+        per-cell runner) behind `assign`."""
         if not sim.tel.alive.any():
             raise RuntimeError("no alive instances to schedule onto")
         if self._fused is None:
@@ -311,7 +277,7 @@ class RouteBalancePolicy(SchedulingPolicy):
         # candidate roster = the SCHEDULER-VISIBLE rows: tel.alive, not
         # inst.alive — the telemetry watchdog quarantines stale rows by
         # masking them in the mirror while the worker stays up, and the
-        # staged backends must see exactly the roster the fused backend
+        # numpy loop must see exactly the roster the fused backend
         # masks (slot k <-> sim.instances[k] by construction)
         tel = sim.tel
         alive_rows = np.flatnonzero(tel.alive)
@@ -354,7 +320,7 @@ class RouteBalancePolicy(SchedulingPolicy):
                     feats, learned=cfg.learned_tpot)
 
         # 4+5. budget admission (Eq. 2) + LPT-ordered greedy with dead
-        # reckoning — either the numpy loop or the jitted decision core
+        # reckoning
         price_in = np.array([ti.price_in for ti in tiers_of_i])
         price_out = np.array([ti.price_out for ti in tiers_of_i])
         if cols is not None:
@@ -366,11 +332,10 @@ class RouteBalancePolicy(SchedulingPolicy):
             len_in = np.array([r.prompt.len_in for r in reqs], float)
         nominal = np.array([self.bundle.heads[ti.name].nominal_tpot
                             for ti in tiers_of_i])
-        # prefix-cache affinity (serving.affinity): both staged arms
-        # compute the SAME host-side float32 discount matrix — the
-        # fused backend evaluates the identical integer-compare +
-        # float32 math in-graph, so all three backends score reuse
-        # bit-identically
+        # prefix-cache affinity (serving.affinity): a host-side float32
+        # discount matrix — the fused and megakernel backends evaluate
+        # the identical integer-compare + float32 math in-graph, so all
+        # three backends score reuse bit-identically
         aff = None
         if cfg.affinity_weight > 0.0:
             from repro.serving.affinity import (hit_fraction,
@@ -383,34 +348,26 @@ class RouteBalancePolicy(SchedulingPolicy):
             hit = hit_fraction(req_sig, len_in.astype(np.float32),
                                tel.prefix_sig[alive_rows].T, np)
             aff = np.float32(cfg.affinity_weight) * hit
-        if cfg.decision_backend == "jax":
-            from . import decision_jax
-            choice, _ = decision_jax.decide(
-                q_inst, l_inst, L.max(axis=1), tpot, nominal, d, b, free,
-                maxb, budgets, len_in, price_in, price_out, cfg.weights,
-                latency_mode=cfg.latency_mode, lpt=cfg.lpt,
-                budget_filter=cfg.budget_filter, affinity=aff)
+        # the reference loop evaluates the decision arithmetic in float32
+        # — the device backends' precision — so the quantized Eq. 1 tie
+        # groups are identical across all three backends (greedy_assign
+        # follows the dtype of its inputs)
+        f32 = np.float32
+        budgets32, len_in32 = budgets.astype(f32), len_in.astype(f32)
+        pi32, po32 = price_in.astype(f32), price_out.astype(f32)
+        if cfg.budget_filter:
+            allowed, c_hat = admission_mask(budgets32, len_in32,
+                                            l_inst, pi32, po32)
         else:
-            # the reference loop evaluates the decision arithmetic in
-            # float32 — the jitted cores' precision — so the quantized
-            # Eq. 1 tie groups are identical across all three backends
-            # (greedy_assign follows the dtype of its inputs)
-            f32 = np.float32
-            budgets32, len_in32 = budgets.astype(f32), len_in.astype(f32)
-            pi32, po32 = price_in.astype(f32), price_out.astype(f32)
-            if cfg.budget_filter:
-                allowed, c_hat = admission_mask(budgets32, len_in32,
-                                                l_inst, pi32, po32)
-            else:
-                allowed = np.ones((R, I), bool)
-                c_hat = cost_matrix(len_in32, l_inst, pi32, po32)
-            order = lpt_order(L.max(axis=1), enable=cfg.lpt)
-            choice, _ = greedy_assign(
-                order, q_inst.astype(f32), c_hat, l_inst.astype(f32),
-                tpot.astype(f32), d.astype(f32), b.astype(f32),
-                free.astype(f32), maxb.astype(f32),
-                cfg.weights, allowed, latency_mode=cfg.latency_mode,
-                nominal_tpot=nominal.astype(f32), affinity=aff)
+            allowed = np.ones((R, I), bool)
+            c_hat = cost_matrix(len_in32, l_inst, pi32, po32)
+        order = lpt_order(L.max(axis=1), enable=cfg.lpt)
+        choice, _ = greedy_assign(
+            order, q_inst.astype(f32), c_hat, l_inst.astype(f32),
+            tpot.astype(f32), d.astype(f32), b.astype(f32),
+            free.astype(f32), maxb.astype(f32),
+            cfg.weights, allowed, latency_mode=cfg.latency_mode,
+            nominal_tpot=nominal.astype(f32), affinity=aff)
         l_chosen = l_inst[np.arange(R), choice]
         return instances, choice, l_chosen
 

@@ -4,21 +4,22 @@ maxima (the batch supplies the reference set a point-at-a-time router
 lacks; §4.1).
 
 The math lives in one backend-agnostic function (`masked_score`) shared
-by the numpy production loop and the jitted JAX decision cores
-(`repro.core.decision_jax`, `repro.core.hotpath`) — exact-parity
-differential tests depend on every backend evaluating the identical
-expression in the identical operation order.
+by the numpy reference loop, the fused program's greedy scan
+(`repro.core.decision_jax`, `repro.core.hotpath`) and the megakernel —
+exact-parity differential tests depend on every backend evaluating the
+identical expression in the identical operation order.
 
 Scores are **epsilon-quantized** before they are returned: snapped to a
 2^-13 grid (~1.2e-4 of the O(1) score scale). Two candidates whose
 scores are equal in real arithmetic — same-tier replicas in identical
 dead-reckoned state, the common case on a live cluster — used to come
 back with a sub-1e-7 noise gap that the numpy loop's float64 resolved
-and the jitted cores' float32 collapsed (or vice versa), flipping the
+and the device backends' float32 collapsed (or vice versa), flipping the
 argmax between backends on unlucky worlds. Three coordinated choices
 make the backends agree instead: the scheduler's numpy reference now
 evaluates the decision arithmetic in float32 (`greedy_assign` follows
-its input dtype, so the T/score chains are bitwise the jitted cores'),
+its input dtype, so the T/score chains are bitwise the device
+backends'),
 the cost scale is a reciprocal multiply rather than a division
 (matching XLA's rewrite), and quantization absorbs the one residual
 cross-backend difference — XLA's FMA contraction of the cost mul-add,

@@ -44,9 +44,9 @@ def topk_soft_lookup(q, x, xsq, quality, length, k: int, eps: float):
     """The jnp KNN query body: squared distances via the
     ||q-x||² = ||q||² - 2 q·x + ||x||² expansion, `lax.top_k`, then the
     distance-weighted label mix. One definition traced by both the
-    staged jax backend and the fused hot path (exact-parity tests
-    compare their outputs bitwise). All args are jnp arrays; returns
-    (quality (B, M), length (B, M)).
+    jax query (the numpy decision backend's feed) and the fused hot
+    path (exact-parity tests compare their outputs bitwise). All args
+    are jnp arrays; returns (quality (B, M), length (B, M)).
 
     The cross term runs at HIGHEST precision: the TPU's default f32 dot
     takes bf16 passes, and the neighbour set must agree with the
@@ -86,15 +86,6 @@ class KNNEstimator:
     @property
     def n_models(self) -> int:
         return self._quality.shape[1]
-
-    def with_backend(self, backend: str) -> "KNNEstimator":
-        """Copy sharing the fitted index but querying via `backend`
-        (the compiled-query cache is backend-specific, so it resets)."""
-        import copy
-        knn = copy.copy(self)
-        knn.backend = backend
-        knn._jq = None
-        return knn
 
     # -- query ----------------------------------------------------------------
     def query(self, q: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -142,7 +133,7 @@ class KNNEstimator:
         # compiles at: XLA picks its dot kernel by shape (B=1 lowers to
         # a gemv whose f32 accumulation order differs from the gemm a
         # padded batch gets), so querying at the raw B would leave
-        # staged-vs-fused bitwise parity to rounding luck on exactly
+        # numpy-vs-fused bitwise parity to rounding luck on exactly
         # the batches retries produce. Bucketing makes it structural —
         # and caps the jit cache at O(log B) entries instead of one
         # per distinct batch size.

@@ -10,7 +10,7 @@ fusions. This kernel keeps the pipeline in VMEM instead. Every stage is
 written in the forms Mosaic lowers — 2-D blocks, compare/select,
 min/max reductions, static slices — with no sort and no gather:
 
-  * **stage 1 — KNN top-k**, streamed over the index: grid axis 1 walks
+  * **stage 1 — KNN top-k**, streamed over the index: the grid walks
     the training index in tiles (the `knn_topk` idiom), so the (R, N)
     distance plane never exists whole. Per tile, k extract-min rounds
     merge the tile into a running (R, k) buffer that carries each
@@ -29,14 +29,6 @@ min/max reductions, static slices — with no sort and no gather:
     request row by compare-select and runs the shared
     `repro.core.decision_jax.greedy_step` body, with the dead-reckoned
     (d, b, free) carry in the loop state.
-
-**Multi-window batching**: grid axis 0 runs K scheduler windows.
-Per-window inputs carry a leading K axis and block per window; the
-telemetry mirror and every estimator constant are shared blocks. K
-windows decided from one telemetry snapshot are independent by
-construction — the fused path reseeds the mirror from telemetry every
-batch — which is what lets them share one dispatch bitwise-safely
-(`FusedHotPath.decide_cols_multi`).
 
 Execution mode follows the platform (`repro.kernels.ops.interpret_mode`):
 Mosaic on a TPU, the Pallas interpreter elsewhere. Under the
@@ -166,8 +158,8 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
     from repro.estimators.knn import distance_weights, label_mix
     from repro.serving.affinity import hit_fraction
 
-    t = pl.program_id(1)
-    R = emb_ref.shape[1]
+    t = pl.program_id(0)
+    R = emb_ref.shape[0]
     tile = x_ref.shape[0]
     n_lab = lab_ref.shape[0]
 
@@ -183,7 +175,7 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
     # the distance expansion is spelled as topk_soft_lookup's — same op
     # order, HIGHEST precision — so the survivors' distances are the
     # fused program's
-    emb = emb_ref[0]                                        # (R, E)
+    emb = emb_ref[...]                                      # (R, E)
     d2 = (xsq_ref[...]
           - jnp.matmul(2.0 * emb, x_ref[...].T,
                        precision=jax.lax.Precision.HIGHEST)
@@ -205,7 +197,7 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
         return carry
     _row_blocks(R, merge, 0)
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(t == pl.num_programs(0) - 1)
     def _decide():
         d = d_ref[...]                                      # (1, I)
         b = b_ref[...]
@@ -242,12 +234,12 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
             qual, leng = mix[:M], mix[M:]
             l_inst = _by_model(leng, m_of_i)                # (ROWS, I)
             key_ref[rows, :] = jnp.where(
-                rv_ref[0, rows, :] > 0, functools.reduce(jnp.maximum, leng),
+                rv_ref[rows, :] > 0, functools.reduce(jnp.maximum, leng),
                 -1e30)
-            len_in = lin_ref[0, rows, :]
+            len_in = lin_ref[rows, :]
             if budget_filter:
                 allowed, c_hat = admission_math(
-                    bud_ref[0, rows, :], len_in, l_inst, price_in_ref[...],
+                    bud_ref[rows, :], len_in, l_inst, price_in_ref[...],
                     price_out_ref[...], jnp, valid=alive)
             else:
                 c_hat = cost_matrix(len_in, l_inst, price_in_ref[...],
@@ -258,7 +250,7 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
             plane_ref[2, rows, :] = l_inst
             plane_ref[3, rows, :] = allowed.astype(jnp.float32)
             if w_aff > 0.0:
-                hit = hit_fraction(psig_ref[0, rows, :], len_in,
+                hit = hit_fraction(psig_ref[rows, :], len_in,
                                         sig_ref[...], jnp)
                 hit = jnp.where(alive, hit, jnp.float32(0.0))
                 plane_ref[4, rows, :] = jnp.float32(w_aff) * hit
@@ -291,7 +283,7 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
 
             def row(j):
                 return _pick(sel, plane_ref[j], 0)          # (1, I)
-            valid_r = _pick(sel, rv_ref[0], 0) > 0          # (1, 1)
+            valid_r = _pick(sel, rv_ref[...], 0) > 0        # (1, 1)
             dc, bc, fc, i, e = greedy_step(
                 row(0), row(1), row(2), row(3) > 0.5,
                 row(4) if w_aff > 0.0 else None, valid_r, dc, bc, fc,
@@ -303,19 +295,19 @@ def _kernel(emb_ref, rv_ref, bud_ref, lin_ref, psig_ref,
         d1, b1, f1, choice, est = jax.lax.fori_loop(
             0, R, body, (d, b_eff, free, jnp.zeros((R, 1), jnp.int32),
                          jnp.zeros((R, 1), jnp.float32)))
-        choice_ref[0] = choice
-        est_ref[0] = est
+        choice_ref[...] = choice
+        est_ref[...] = est
 
         def chosen_len(rows, row0, carry):
             l_inst = plane_ref[2, rows, :]
             lane = jax.lax.broadcasted_iota(jnp.int32, l_inst.shape, 1)
-            lchosen_ref[0, rows, :] = _pick(lane == choice_ref[0, rows, :],
-                                            l_inst, 1)
+            lchosen_ref[rows, :] = _pick(lane == choice_ref[rows, :],
+                                         l_inst, 1)
             return carry
         _row_blocks(R, chosen_len, 0)
-        d1_ref[0] = d1
-        b1_ref[0] = b1
-        f1_ref[0] = f1
+        d1_ref[...] = d1
+        b1_ref[...] = b1
+        f1_ref[...] = f1
 
 
 def decision_call(emb, row_valid, budgets, len_in, psig,
@@ -330,18 +322,17 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
                   interpret: Optional[bool] = None):
     """The megakernel dispatch (traceable; jit at the call site).
 
-    Per-window args carry a leading K axis — emb (K, R, E), row_valid
-    (K, R) bool, budgets/len_in (K, R), psig (K, R, SIG_WIDTH) int32
-    (any (K, 1, 1) dummy when ``w_aff == 0``). Telemetry mirror
-    d/b/free/ctx (I,) f32 + alive (I,) bool and every estimator
-    constant are shared across windows; sig_plane is (I, SKETCH_SLOTS)
+    One scheduler window: emb (R, E), row_valid (R,) bool,
+    budgets/len_in (R,), psig (R, SIG_WIDTH) int32 (any (1, 1) dummy
+    when ``w_aff == 0``); telemetry mirror d/b/free/ctx (I,) f32 +
+    alive (I,) bool; sig_plane is (I, SKETCH_SLOTS)
     (any dummy when ``w_aff == 0``); gfeat/gthr/gleaf/gbase are the
     `pack_ensemble` stack (1-element dummies when ``use_gbm`` is
     False). ``knn_tile`` is the index rows per grid step: by default
     the whole index under the interpreter and `KNN_TILE` compiled.
     ``interpret`` defaults to the platform's mode. Returns
-    (choice (K, R) i32, est_T (K, R) f32, l_chosen (K, R) f32,
-    d1/b1/f1 (K, I) f32 post-scan dead-reckoned views).
+    (choice (R,) i32, est_T (R,) f32, l_chosen (R,) f32,
+    d1/b1/f1 (I,) f32 post-scan dead-reckoned views).
 
     Layout work happens here, outside the kernel: per-request vectors
     become (R, 1) columns, per-instance vectors (1, I) rows, bools
@@ -353,7 +344,7 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
     from .ops import interpret_mode
     if interpret is None:
         interpret = interpret_mode()
-    K, R0, E = emb.shape
+    R0, E = emb.shape
     R = -(-R0 // ROWS) * ROWS          # pad rows: invalid, scanned last
     I = d.shape[0]
     N, M = qual.shape
@@ -363,11 +354,11 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
 
     def pad_rows(a, fill=0):
         a = jnp.asarray(a)
-        return jnp.pad(a, ((0, 0), (0, R - R0)) + ((0, 0),) * (a.ndim - 2),
+        return jnp.pad(a, ((0, R - R0),) + ((0, 0),) * (a.ndim - 1),
                        constant_values=fill)
 
     def col(a, dtype=f32, fill=0):
-        return pad_rows(jnp.asarray(a).astype(dtype), fill).reshape(K, R, 1)
+        return pad_rows(jnp.asarray(a).astype(dtype), fill).reshape(R, 1)
 
     def row(a, dtype=f32):
         return jnp.asarray(a).astype(dtype).reshape(1, I)
@@ -397,12 +388,8 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
     if w_aff > 0.0:
         psig = pad_rows(psig)
 
-    def win(*block):
-        return pl.BlockSpec((1,) + block,
-                            lambda wi, t: (wi,) + (0,) * len(block))
-
-    def shared(*block):
-        return pl.BlockSpec(block, lambda wi, t: (0,) * len(block))
+    def whole(*block):
+        return pl.BlockSpec(block, lambda t: (0,) * len(block))
 
     kern = functools.partial(
         _kernel, k=k, eps=eps, weights=tuple(weights),
@@ -410,35 +397,35 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
         w_aff=w_aff, use_gbm=use_gbm, depth=depth, lr=lr, n_index=N)
     outs = pl.pallas_call(
         kern,
-        grid=(K, Np // tile),
+        grid=(Np // tile,),
         in_specs=[
-            win(R, E),                 # emb
-            win(R, 1),                 # row_valid
-            win(R, 1),                 # budgets
-            win(R, 1),                 # len_in
-            win(*psig.shape[1:]),      # psig
-            shared(1, I), shared(1, I), shared(1, I), shared(1, I),
-            shared(1, I),              # alive
-            pl.BlockSpec((tile, E), lambda wi, t: (t, 0)),       # x
-            pl.BlockSpec((1, tile), lambda wi, t: (0, t)),       # xsq
-            pl.BlockSpec((2 * M, tile), lambda wi, t: (0, t)),   # labels
-            shared(1, I),              # m_of_i
-            shared(1, I),              # maxb
-            shared(1, I),              # price_in
-            shared(1, I),              # price_out
-            shared(1, I),              # nominal
-            shared(*sig_t.shape),      # sketch plane (slots, I)
-            *(shared(*g.shape) for g in gbm),
+            whole(R, E),               # emb
+            whole(R, 1),               # row_valid
+            whole(R, 1),               # budgets
+            whole(R, 1),               # len_in
+            whole(*psig.shape),        # psig
+            whole(1, I), whole(1, I), whole(1, I), whole(1, I),
+            whole(1, I),               # alive
+            pl.BlockSpec((tile, E), lambda t: (t, 0)),           # x
+            pl.BlockSpec((1, tile), lambda t: (0, t)),           # xsq
+            pl.BlockSpec((2 * M, tile), lambda t: (0, t)),       # labels
+            whole(1, I),               # m_of_i
+            whole(1, I),               # maxb
+            whole(1, I),               # price_in
+            whole(1, I),               # price_out
+            whole(1, I),               # nominal
+            whole(*sig_t.shape),       # sketch plane (slots, I)
+            *(whole(*g.shape) for g in gbm),
         ],
-        out_specs=[win(R, 1), win(R, 1), win(R, 1),
-                   win(1, I), win(1, I), win(1, I)],
+        out_specs=[whole(R, 1), whole(R, 1), whole(R, 1),
+                   whole(1, I), whole(1, I), whole(1, I)],
         out_shape=[
-            jax.ShapeDtypeStruct((K, R, 1), jnp.int32),
-            jax.ShapeDtypeStruct((K, R, 1), f32),
-            jax.ShapeDtypeStruct((K, R, 1), f32),
-            jax.ShapeDtypeStruct((K, 1, I), f32),
-            jax.ShapeDtypeStruct((K, 1, I), f32),
-            jax.ShapeDtypeStruct((K, 1, I), f32),
+            jax.ShapeDtypeStruct((R, 1), jnp.int32),
+            jax.ShapeDtypeStruct((R, 1), f32),
+            jax.ShapeDtypeStruct((R, 1), f32),
+            jax.ShapeDtypeStruct((1, I), f32),
+            jax.ShapeDtypeStruct((1, I), f32),
+            jax.ShapeDtypeStruct((1, I), f32),
         ],
         scratch_shapes=[
             pltpu.VMEM((R, k), f32),                # survivor distances
@@ -450,7 +437,7 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
             pltpu.VMEM((R, 1), jnp.int32),          # LPT rank
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(pad_rows(emb), col(row_valid, jnp.int32), col(budgets, fill=np.nan),
@@ -459,8 +446,8 @@ def decision_call(emb, row_valid, budgets, len_in, psig,
       x, xsq, labels,
       row(m_of_i, jnp.int32), row(maxb), row(price_in), row(price_out),
       row(nominal), sig_t, *gbm)
-    return (outs[0][:, :R0, 0], outs[1][:, :R0, 0], outs[2][:, :R0, 0],
-            outs[3][:, 0], outs[4][:, 0], outs[5][:, 0])
+    return (outs[0][:R0, 0], outs[1][:R0, 0], outs[2][:R0, 0],
+            outs[3][0], outs[4][0], outs[5][0])
 
 
 @functools.partial(

@@ -28,9 +28,9 @@ def decision_ref(emb, row_valid, budgets, len_in, psig,
     (`repro.kernels.decision_megakernel.decision_call`): the same
     KNN -> GBM -> admission -> affinity -> greedy-scan pipeline, one
     Python loop per request, float32 throughout like the device
-    backends. Args mirror `decision_call` (leading K window axis on
-    the per-window inputs; `gbm` is the `pack_ensemble` dict or None
-    for nominal-TPOT mode). Returns the same six outputs.
+    backends. Args mirror `decision_call` (`gbm` is the
+    `pack_ensemble` dict or None for nominal-TPOT mode). Returns the
+    same six outputs.
 
     This is a *logical* oracle (assignment-exact on the tested worlds,
     latencies to float tolerance), not the bitwise contract — that is
@@ -42,8 +42,8 @@ def decision_ref(emb, row_valid, budgets, len_in, psig,
     from repro.serving.affinity import hit_fraction
 
     f32 = np.float32
-    emb = np.asarray(emb, f32)
-    K, R, E = emb.shape
+    q = np.asarray(emb, f32)
+    R = q.shape[0]
     d0, b_tel, free0, ctx0 = (np.asarray(a, f32)
                               for a in (d, b, free, ctx))
     alive = np.asarray(alive, bool)
@@ -59,8 +59,6 @@ def decision_ref(emb, row_valid, budgets, len_in, psig,
     I = d0.shape[0]
     wq, wl, wc = (f32(w) for w in weights)
 
-    # state-dependent TPOT is window-invariant (every window scans from
-    # the same telemetry snapshot), so evaluate it once
     b_eff = np.maximum(b_tel, f32(1.0))
     ctx_eff = np.maximum(ctx0, f32(64.0))
     if gbm is not None:
@@ -86,73 +84,70 @@ def decision_ref(emb, row_valid, budgets, len_in, psig,
     else:
         tpot = nominal
 
-    outs = [np.zeros((K, R), np.int32), np.zeros((K, R), f32),
-            np.zeros((K, R), f32), np.zeros((K, I), f32),
-            np.zeros((K, I), f32), np.zeros((K, I), f32)]
-    for wi in range(K):
-        q = emb[wi]
-        rv = np.asarray(row_valid[wi], bool)
-        bud = np.asarray(budgets[wi], f32)
-        lin = np.asarray(len_in[wi], f32)
-        # stage 1: exact KNN (sorted ascending by (distance, index))
-        d2 = (xsq[None, :] - 2.0 * q @ x.T
-              + (q * q).sum(-1, keepdims=True)).astype(f32)
-        nidx = np.argsort(d2, axis=1, kind="stable")[:, :k]
-        d2k = np.take_along_axis(d2, nidx, axis=1)
-        w = distance_weights(d2k, eps, np).astype(f32)
-        qmix = (qual_lbl[nidx] * w[..., None]).sum(1)    # (R, M)
-        lmix = (leng_lbl[nidx] * w[..., None]).sum(1)
-        q_inst = qmix[:, m_of_i]
-        l_inst = lmix[:, m_of_i]
-        pred_len_max = np.where(rv, lmix.max(axis=1), -1e30)
-        # stage 3: admission + affinity
-        if budget_filter:
-            allowed, c_hat = admission_math(
-                bud, lin, l_inst, price_in, price_out, np, valid=alive)
+    rv = np.asarray(row_valid, bool)
+    bud = np.asarray(budgets, f32)
+    lin = np.asarray(len_in, f32)
+    # stage 1: exact KNN (sorted ascending by (distance, index))
+    d2 = (xsq[None, :] - 2.0 * q @ x.T
+          + (q * q).sum(-1, keepdims=True)).astype(f32)
+    nidx = np.argsort(d2, axis=1, kind="stable")[:, :k]
+    d2k = np.take_along_axis(d2, nidx, axis=1)
+    w = distance_weights(d2k, eps, np).astype(f32)
+    qmix = (qual_lbl[nidx] * w[..., None]).sum(1)        # (R, M)
+    lmix = (leng_lbl[nidx] * w[..., None]).sum(1)
+    q_inst = qmix[:, m_of_i]
+    l_inst = lmix[:, m_of_i]
+    pred_len_max = np.where(rv, lmix.max(axis=1), -1e30)
+    # stage 3: admission + affinity
+    if budget_filter:
+        allowed, c_hat = admission_math(
+            bud, lin, l_inst, price_in, price_out, np, valid=alive)
+    else:
+        c_hat = cost_matrix(lin, l_inst, price_in, price_out, np)
+        allowed = np.broadcast_to(alive[None, :], c_hat.shape)
+    if w_aff > 0.0:
+        hit = hit_fraction(np.asarray(psig), lin,
+                           np.asarray(sig_plane).T, np)
+        aff = f32(w_aff) * np.where(alive[None, :], hit, f32(0.0))
+    else:
+        aff = None
+    # stage 4: LPT order + greedy scan (mirrors greedy_step)
+    order = (np.argsort(-pred_len_max, kind="stable") if lpt
+             else np.arange(R))
+    choice = np.zeros(R, np.int32)
+    est_T = np.zeros(R, f32)
+    l_chosen = np.zeros(R, f32)
+    dc, bc, fc = d0.copy(), b_eff.copy(), free0.copy()
+    b0 = np.maximum(b_eff, f32(1.0))
+    for r in order:
+        wait = np.where(fc > 0, f32(0.0),
+                        dc / np.maximum(bc, f32(1.0)))
+        tpot_eff = tpot * np.maximum(bc / b0, f32(1.0))
+        if latency_mode == "static_prior":
+            T = nominal * l_inst[r]
         else:
-            c_hat = cost_matrix(lin, l_inst, price_in, price_out, np)
-            allowed = np.broadcast_to(alive[None, :], c_hat.shape)
-        if w_aff > 0.0:
-            hit = hit_fraction(np.asarray(psig[wi]), lin,
-                               np.asarray(sig_plane).T, np)
-            aff = f32(w_aff) * np.where(alive[None, :], hit, f32(0.0))
+            T = tpot_eff * (wait + l_inst[r])
+        if aff is not None:
+            T = affinity_discount(T, aff[r], np)
+        if latency_mode in ("off_reactive", "off_predictive"):
+            s = masked_score(q_inst[r], c_hat[r], T, (wq, 0.0, wc),
+                             allowed[r], np)
+            tie = (dc + bc) if latency_mode == "off_reactive" else T
+            tn = tie / np.maximum(tie.max(), f32(1e-9))
+            i = int(np.argmin(np.where(s >= s.max(), tn, np.inf)))
         else:
-            aff = None
-        # stage 4: LPT order + greedy scan (mirrors greedy_step)
-        order = (np.argsort(-pred_len_max, kind="stable") if lpt
-                 else np.arange(R))
-        dc, bc, fc = d0.copy(), b_eff.copy(), free0.copy()
-        b0 = np.maximum(b_eff, f32(1.0))
-        for r in order:
-            wait = np.where(fc > 0, f32(0.0),
-                            dc / np.maximum(bc, f32(1.0)))
-            tpot_eff = tpot * np.maximum(bc / b0, f32(1.0))
-            if latency_mode == "static_prior":
-                T = nominal * l_inst[r]
-            else:
-                T = tpot_eff * (wait + l_inst[r])
-            if aff is not None:
-                T = affinity_discount(T, aff[r], np)
-            if latency_mode in ("off_reactive", "off_predictive"):
-                s = masked_score(q_inst[r], c_hat[r], T, (wq, 0.0, wc),
-                                 allowed[r], np)
-                tie = (dc + bc) if latency_mode == "off_reactive" else T
-                tn = tie / np.maximum(tie.max(), f32(1e-9))
-                i = int(np.argmin(np.where(s >= s.max(), tn, np.inf)))
-            else:
-                s = masked_score(q_inst[r], c_hat[r], T, (wq, wl, wc),
-                                 allowed[r], np)
-                i = int(np.argmax(s))
-            outs[0][wi, r] = i
-            outs[1][wi, r] = T[i]
-            outs[2][wi, r] = l_inst[r, i]
-            if rv[r]:
-                dc[i] += l_inst[r, i]
-                if fc[i] > 0:
-                    fc[i] -= 1.0
-                    bc[i] = min(bc[i] + 1.0, maxb[i])
-        outs[3][wi], outs[4][wi], outs[5][wi] = dc, bc, fc
-    return tuple(outs)
+            s = masked_score(q_inst[r], c_hat[r], T, (wq, wl, wc),
+                             allowed[r], np)
+            i = int(np.argmax(s))
+        choice[r] = i
+        est_T[r] = T[i]
+        l_chosen[r] = l_inst[r, i]
+        if rv[r]:
+            dc[i] += l_inst[r, i]
+            if fc[i] > 0:
+                fc[i] -= 1.0
+                bc[i] = min(bc[i] + 1.0, maxb[i])
+    return choice, est_T, l_chosen, dc, bc, fc
 
 
 def decode_attention_ref(q, k_cache, v_cache, cache_positions, pos,

@@ -10,7 +10,7 @@ whole stack:
     two prompts sharing a prefix share the leading signature columns.
     Signatures are 32-bit ON PURPOSE: the fused hot path compares them
     in-graph and jax runs with x64 disabled — a 64-bit hash would be
-    silently truncated on device and break numpy==jax==fused parity.
+    silently truncated on device and break numpy==fused parity.
   * `PrefixSketch` — the per-instance host-side cache model: a
     flattened hash-trie (each signature encodes its whole root path,
     so a dict IS the trie) with LRU eviction at `SKETCH_SLOTS`
@@ -19,7 +19,7 @@ whole stack:
     `TelemetryArrays` carries for the scheduler.
   * `hit_fraction` — the scoring-side lookup: matched-prefix fraction
     per (request, instance), written once over a generic `xp`
-    (numpy or jax.numpy) so the staged and fused decision backends
+    (numpy or jax.numpy) so the numpy and device decision backends
     score bit-identically by construction.
 
 The affinity term itself (`RBConfig.affinity_weight`) discounts the

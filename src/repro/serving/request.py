@@ -121,7 +121,7 @@ class RequestColumns:
         encoder jit cache stays warm across streams). Embedding depends
         only on the prompt, and the masked-pooling encoder is bitwise
         stable under batch/length padding, so precomputing here is pure
-        memoization of the per-batch encode the staged path used to run
+        memoization of the per-batch encode the decision used to run
         inside every decision. Lengths are capped at the encoder's own
         `max_len` — the same truncation the per-batch encode applies."""
         if self.emb is not None:

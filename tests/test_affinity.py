@@ -1,7 +1,7 @@
 """Prefix-cache / session-affinity term (`repro.serving.affinity` +
 ``RBConfig.affinity_weight``): signature math, sketch lifecycle,
-backend-exact hit scoring, decision steering across all three
-backends, the zero-recompile pin through session churn, and the
+backend-exact hit scoring, decision steering across the numpy and
+fused backends, the zero-recompile pin through session churn, and the
 SoA-ingest re-entrancy fixes that rode along (stale retry row stamps,
 all-or-nothing embedding resume)."""
 import dataclasses
@@ -20,7 +20,7 @@ from repro.serving.scenarios import (Scenario, TenantSpec, get_scenario,
                                      randomize_telemetry)
 from repro.serving.world import Prompt
 
-BACKENDS = ("numpy", "jax", "fused")
+BACKENDS = ("numpy", "fused")
 
 
 def _prompt(pid, toks):
@@ -231,7 +231,7 @@ def test_fail_clears_sketch_and_mirror_for_retries():
     assert inst.sketch.hit_tokens(prompt_signatures(p), 64) == 0
 
 
-# -- decision steering: all three backends ------------------------------------
+# -- decision steering: numpy and fused backends ------------------------------
 
 @pytest.fixture(scope="module")
 def steer_run():
@@ -444,4 +444,4 @@ def test_retry_across_two_streams_decides_safely(steer_run):
         out[be] = [instances[int(i)].iid for i in choice]
         alive = {i.iid for i in rb.sim.instances if i.alive}
         assert set(out[be]) <= alive
-    assert out["numpy"] == out["jax"] == out["fused"]
+    assert out["numpy"] == out["fused"]

@@ -1,21 +1,31 @@
 """Differential harness for the scheduler hot path: the numpy greedy
-loop vs the jitted JAX decision core must make the *same decisions*.
+loop vs the greedy scan the fused program traces
+(`decision_jax._greedy_scan`) must make the *same decisions*.
 
 Decision-level: exact assignment parity at fixed seeds across all four
 ``latency_mode`` isolation arms x budget filter on/off x LPT on/off
 (the scan carries float32 state while numpy runs float64, so parity is
 exact away from argmax ties; the pinned seeds keep it deterministic).
-System-level: a full ``ClusterSim`` run under each backend lands on
-matching metrics, including with the Pallas ``knn_topk`` estimator feed.
+The scan is jitted here with Eq. 2 admission in-graph
+(`budget.admission_math` over jnp), the LPT order from `lpt_order`, and
+the batch padded to the fused program's pow2 bucket with a `row_valid`
+mask. System-level: a full ``ClusterSim`` run under the numpy and fused
+backends lands on the same trajectory and metrics, and the numpy
+backend fed by the Pallas ``knn_topk`` estimator lands on the jnp
+feed's metrics.
 """
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import PRESETS, RBConfig, RouteBalance, make_requests, \
-    run_cell
-from repro.core import decision_jax
+from repro.core import PRESETS, EstimatorBundle, RBConfig, RouteBalance, \
+    make_requests, run_cell
 from repro.core.assignment import greedy_assign, lpt_order
-from repro.core.budget import admission_mask
+from repro.core.budget import admission_mask, admission_math, cost_matrix
+from repro.core.decision_jax import _greedy_scan, bucket_pow2
 from repro.serving.workload import poisson_arrivals
 
 MODES = ("full", "off_reactive", "off_predictive", "static_prior")
@@ -59,6 +69,50 @@ def _numpy_decision(p, w, mode, budget_filter, lpt):
                          nominal_tpot=p["nominal"])
 
 
+_scan = jax.jit(_greedy_scan, static_argnames=("weights", "latency_mode"))
+
+
+@functools.partial(jax.jit, static_argnames=("weights", "latency_mode",
+                                             "budget_filter"))
+def _admit_and_scan(order, q, ln, tpot, nominal, d, b, free, maxb,
+                    budgets, len_in, price_in, price_out, row_valid, *,
+                    weights, latency_mode, budget_filter):
+    if budget_filter:
+        allowed, c_hat = admission_math(budgets, len_in, ln, price_in,
+                                        price_out, jnp)
+    else:
+        c_hat = cost_matrix(len_in, ln, price_in, price_out, jnp)
+        allowed = jnp.ones(c_hat.shape, bool)
+    choice, est_T, _ = _greedy_scan(
+        order, q, c_hat, ln, tpot, nominal, d, b, free, maxb, weights,
+        allowed, latency_mode, row_valid=row_valid)
+    return choice, est_T
+
+
+def _scan_decision(p, w, mode="full", budget_filter=True, lpt=True):
+    """The scan's decision for problem `p`, float32 like the fused
+    program, R padded to its pow2 bucket: pad rows take an LPT key below
+    every real one (FIFO puts them last anyway) and are row_valid False,
+    so they scan last and move no state. Returns the real rows'
+    (choice, est_T)."""
+    R = len(p["plm"])
+    pad = bucket_pow2(R) - R
+    f32 = np.float32
+
+    def rows(x, fill=0.0):
+        x = np.asarray(x, f32)
+        return np.concatenate([x, np.full((pad,) + x.shape[1:], fill, f32)])
+    order = lpt_order(rows(p["plm"], -1e30), enable=lpt)
+    choice, est_T = _admit_and_scan(
+        order, rows(p["q"]), rows(p["ln"]), f32(p["tpot"]),
+        f32(p["nominal"]), f32(p["d"]), f32(p["b"]), f32(p["free"]),
+        f32(p["maxb"]), rows(p["budgets"], np.nan), rows(p["len_in"]),
+        f32(p["price_in"]), f32(p["price_out"]),
+        np.arange(R + pad) < R, weights=tuple(float(x) for x in w),
+        latency_mode=mode, budget_filter=budget_filter)
+    return np.asarray(choice)[:R], np.asarray(est_T)[:R]
+
+
 @pytest.mark.parametrize("lpt", [True, False], ids=["lpt", "fifo"])
 @pytest.mark.parametrize("budget_filter", [True, False],
                          ids=["budget", "nobudget"])
@@ -67,27 +121,21 @@ def test_exact_assignment_parity(mode, budget_filter, lpt):
     for seed, w in enumerate(WEIGHTS):
         p = _problem(seed)
         ch_np, info = _numpy_decision(p, w, mode, budget_filter, lpt)
-        ch_jx, est = decision_jax.decide(
-            p["q"], p["ln"], p["plm"], p["tpot"], p["nominal"], p["d"],
-            p["b"], p["free"], p["maxb"], p["budgets"], p["len_in"],
-            p["price_in"], p["price_out"], w, latency_mode=mode,
-            lpt=lpt, budget_filter=budget_filter)
+        ch_jx, est = _scan_decision(p, w, mode, budget_filter, lpt)
         np.testing.assert_array_equal(ch_np, ch_jx)
         np.testing.assert_allclose(est, info["est_latency"],
                                    rtol=2e-4, atol=1e-7)
 
 
 def test_parity_with_batch_padding():
-    """decide() pads R to a power of two; pad rows must not leak into
-    real assignments (they scan strictly after every real request)."""
+    """The fused program pads R to a power of two; pad rows must not
+    leak into real assignments (they scan strictly after every real
+    request and move no state)."""
     for R in (1, 5, 13, 33, 63):
         p = _problem(7 + R, R=R)
         w = PRESETS["uniform"]
         ch_np, _ = _numpy_decision(p, w, "full", True, True)
-        ch_jx, _ = decision_jax.decide(
-            p["q"], p["ln"], p["plm"], p["tpot"], p["nominal"], p["d"],
-            p["b"], p["free"], p["maxb"], p["budgets"], p["len_in"],
-            p["price_in"], p["price_out"], w)
+        ch_jx, _ = _scan_decision(p, w)
         np.testing.assert_array_equal(ch_np, ch_jx)
 
 
@@ -100,9 +148,11 @@ def test_greedy_core_respects_allowed():
     order = lpt_order(p["plm"])
     c_hat = (p["len_in"][:, None] * p["price_in"][None, :]
              + p["ln"] * p["price_out"][None, :]) / 1e6
-    choice, _ = decision_jax.greedy_core(
-        order, p["q"], c_hat, p["ln"], p["tpot"], p["nominal"], p["d"],
-        p["b"], p["free"], p["maxb"], PRESETS["uniform"], allowed)
+    f32 = np.float32
+    choice, _, _ = _scan(
+        order, f32(p["q"]), f32(c_hat), f32(p["ln"]), f32(p["tpot"]),
+        f32(p["nominal"]), f32(p["d"]), f32(p["b"]), f32(p["free"]),
+        f32(p["maxb"]), PRESETS["uniform"], allowed, "full")
     choice = np.asarray(choice)
     assert all(allowed[r, choice[r]] for r in range(R))
 
@@ -138,29 +188,34 @@ def _run(ctx, cfg, n=80, lam=10.0, seed=3):
 
 @pytest.mark.parametrize("mode", ["full", "off_reactive"])
 def test_e2e_cluster_metrics_parity(small_ctx, mode):
+    """The numpy and fused backends land on the same request->instance
+    trajectory, and so the same metrics, in each mode."""
     base = dict(charge_compute=False, latency_mode=mode)
-    m_np = _run(small_ctx, RBConfig(decision_backend="numpy", **base))
-    m_jx = _run(small_ctx, RBConfig(decision_backend="jax", **base))
-    assert abs(m_np["quality"] - m_jx["quality"]) < 0.01
-    assert m_jx["mean_e2e"] == pytest.approx(m_np["mean_e2e"], rel=0.05)
-    assert m_jx["cost_per_req"] == pytest.approx(m_np["cost_per_req"],
-                                                 rel=0.05)
+    out = {}
+    for be in ("numpy", "fused"):
+        arr = poisson_arrivals(10.0, 80, seed=3)
+        reqs = make_requests(small_ctx["ds"], "test", arr)
+        rb = RouteBalance(RBConfig(decision_backend=be, **base),
+                          small_ctx["bundle"], small_ctx["tiers"])
+        m = run_cell(rb, small_ctx["tiers"], small_ctx["names"], reqs)
+        out[be] = ([r.instance for r in reqs], m)
+    assert out["numpy"][0] == out["fused"][0]
+    for k in ("quality", "mean_e2e", "cost_per_req"):
+        assert out["fused"][1][k] == pytest.approx(out["numpy"][1][k],
+                                                   rel=1e-9)
 
 
 def test_e2e_pallas_knn_feed(small_ctx):
-    """The jitted core fed by the Pallas knn_topk estimator lands on the
-    same metrics as the jnp top_k feed."""
-    base = dict(charge_compute=False)
-    m_jnp = _run(small_ctx, RBConfig(decision_backend="jax", **base),
-                 n=40)
-    m_pal = _run(small_ctx, RBConfig(decision_backend="jax",
-                                     knn_backend="pallas", **base), n=40)
+    """The numpy backend fed by the Pallas knn_topk estimator (a bundle
+    built with ``KNNEstimator(backend="pallas")``) lands on the same
+    metrics as the jnp top_k feed."""
+    from repro.estimators.knn import KNNEstimator
+    b = small_ctx["bundle"]
+    knn = KNNEstimator(k=b.knn.k, backend="pallas", eps=b.knn.eps).fit(
+        b.knn._x, b.knn._quality, b.knn._length)
+    pallas = EstimatorBundle(b.encoder, knn, b.heads, b.model_names)
+    cfg = RBConfig(decision_backend="numpy", charge_compute=False)
+    m_jnp = _run(small_ctx, cfg, n=40)
+    m_pal = _run(dict(small_ctx, bundle=pallas), cfg, n=40)
     assert abs(m_jnp["quality"] - m_pal["quality"]) < 0.01
     assert m_pal["mean_e2e"] == pytest.approx(m_jnp["mean_e2e"], rel=0.05)
-
-
-def test_knn_backend_override_does_not_mutate_bundle(small_ctx):
-    before = small_ctx["bundle"].knn.backend
-    RouteBalance(RBConfig(knn_backend="pallas"), small_ctx["bundle"],
-                 small_ctx["tiers"])
-    assert small_ctx["bundle"].knn.backend == before

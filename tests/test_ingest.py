@@ -12,14 +12,14 @@ Five contracts of the host path:
     still-unfetched `LazyDecision`, across same-bucket and
     cross-bucket sequences;
   * **delta telemetry** — `FusedHotPath._sync_state`'s dirty-row
-    scatter must reproduce a from-scratch full reseed (the staged
-    backends' reseed-per-batch semantics) assignment-for-assignment,
+    scatter must reproduce a from-scratch full reseed (the numpy
+    reference's reseed-per-batch semantics) assignment-for-assignment,
     with the delta/carry arms the steady-state common case and full
     reseed reserved for roster-shape events and mostly-dirty batches;
   * **async dispatch** — deferring the result fetch to the dispatch
     point changes nothing observable: full cluster runs through an
     explicit fail/straggle/recover `FailureEvent` schedule land on the
-    staged backends' exact trajectories;
+    numpy reference's exact trajectories;
   * **fetch** — the runner starts one host transfer of each padded
     result at the launch and the fetch slices it in numpy: the same
     arrays as a device slice, bit for bit, and no program of its own.
@@ -158,7 +158,7 @@ def test_staging_double_buffer_no_alias(small_ctx):
 def test_async_dispatch_parity_through_failure_schedule(small_ctx):
     """Full cluster runs through an explicit fail -> straggle -> recover
     schedule: the async fused path (lazy fetch at the dispatch point)
-    must land on the staged backends' exact trajectories."""
+    must land on the numpy reference's exact trajectories."""
     schedule = (FailureEvent(t=1.0, kind="fail", count=3),
                 FailureEvent(t=2.5, kind="straggle", frac=0.25,
                              factor=3.0),
@@ -174,8 +174,8 @@ def test_async_dispatch_parity_through_failure_schedule(small_ctx):
                      seed=0, schedule=schedule, schedule_seed=7)
         return [r.instance for r in reqs], m
 
-    traj = {be: cell(be) for be in ("numpy", "jax", "fused")}
-    assert traj["fused"][0] == traj["jax"][0] == traj["numpy"][0]
+    traj = {be: cell(be) for be in ("numpy", "fused")}
+    assert traj["fused"][0] == traj["numpy"][0]
     for k in ("quality", "mean_e2e", "cost_per_req", "goodput"):
         assert traj["fused"][1][k] == pytest.approx(
             traj["numpy"][1][k], rel=1e-9), k
@@ -186,7 +186,7 @@ def test_async_dispatch_parity_through_failure_schedule(small_ctx):
 def test_delta_scatter_reproduces_full_reseed(small_ctx):
     """After a handful of telemetry writes, the delta arm must make
     exactly the assignments a from-scratch full reseed makes (the
-    staged backends' reseed-per-batch contract)."""
+    numpy reference's reseed-per-batch contract)."""
     sim = _loaded_sim(small_ctx)
     tel = sim.tel
     fp = _runner(small_ctx, sim)
@@ -206,28 +206,25 @@ def test_delta_scatter_reproduces_full_reseed(small_ctx):
 
 def test_delta_path_matches_staged_backends_per_batch(small_ctx):
     """Chained batches with telemetry churn between them: every fused
-    decision off the delta-synced mirror equals the staged numpy/jax
-    decision off a fresh host read."""
+    decision off the delta-synced mirror equals the numpy decision off
+    a fresh host read."""
     sim_f = _loaded_sim(small_ctx)
     rb_f = RouteBalance(RBConfig(decision_backend="fused"),
                         small_ctx["bundle"], small_ctx["tiers"])
     rb_f.sim = sim_f
-    staged = {}
-    for be in ("numpy", "jax"):
-        staged[be] = RouteBalance(RBConfig(decision_backend=be),
-                                  small_ctx["bundle"],
-                                  small_ctx["tiers"])
-        staged[be].sim = _loaded_sim(small_ctx)
+    rb_n = RouteBalance(RBConfig(decision_backend="numpy"),
+                        small_ctx["bundle"], small_ctx["tiers"])
+    rb_n.sim = _loaded_sim(small_ctx)
     rng = np.random.default_rng(0)
     for step in range(4):
         batch = _batch(small_ctx, R=12, seed=100 + step)
         ids = {}
-        for name, rb in [("fused", rb_f)] + list(staged.items()):
+        for name, rb in (("fused", rb_f), ("numpy", rb_n)):
             instances, choice, _ = rb._decide_core(batch)
             ids[name] = [instances[int(i)].iid for i in choice]
-        assert ids["fused"] == ids["jax"] == ids["numpy"], step
+        assert ids["fused"] == ids["numpy"], step
         slots = rng.choice(len(sim_f.instances), 4, replace=False)
-        for sim in [sim_f] + [s.sim for s in staged.values()]:
+        for sim in (sim_f, rb_n.sim):
             for slot in slots:                # same writes for every sim
                 sim.tel.write(int(slot), pending=float(50 * step + slot),
                               batch=3, free=1, ctx=500.0, queue=0,
@@ -629,14 +626,18 @@ def _bits(x):
     return np.ascontiguousarray(x).view(np.uint8)
 
 
-@pytest.mark.parametrize("R", [1, 5, 8, 13, 16])
-def test_fetch_is_the_device_slice_bit_for_bit(small_ctx, R):
+@pytest.mark.parametrize("backend,R", [
+    *(pytest.param("fused", R, id=str(R)) for R in (1, 5, 8, 13, 16)),
+    *(pytest.param("megakernel", R, id=f"megakernel-{R}") for R in (5, 13))])
+def test_fetch_is_the_device_slice_bit_for_bit(small_ctx, backend, R):
     """`fetch()` returns fresh, writable int64 choice and float64
     l_chosen equal bit for bit to slicing the step's outputs on the
     device; a second fetch returns the same objects; each window starts
-    two device-to-host transfers."""
+    two device-to-host transfers. The megakernel runner fetches the
+    same way."""
     sim = _loaded_sim(small_ctx)
-    fp = _runner(small_ctx, sim)
+    fp = FusedHotPath(small_ctx["bundle"], sim.instances,
+                      RBConfig(decision_backend=backend))
     cols, rows = batch_columns(_batch(small_ctx, R=R, seed=R))
     cols.ensure_embeddings(small_ctx["bundle"].encoder)
     for window in (1, 2):
@@ -654,37 +655,6 @@ def test_fetch_is_the_device_slice_bit_for_bit(small_ctx, R):
         again = lz.fetch()
         assert again[0] is choice and again[1] is l_chosen
         assert fp.stats["d2h"] == 2 * window
-
-
-def test_multi_window_fetches_share_one_transfer(small_ctx):
-    """K windows of one multi-window dispatch fetch what K separate
-    `decide_cols` fetch, from the one pair of padded arrays: two
-    transfers for the dispatch, not two per window."""
-    sim = _loaded_sim(small_ctx)
-    cfg = RBConfig(decision_backend="megakernel")
-    multi = FusedHotPath(small_ctx["bundle"], sim.instances, cfg)
-    single = FusedHotPath(small_ctx["bundle"], sim.instances, cfg)
-    enc = small_ctx["bundle"].encoder
-    reqs = _batch(small_ctx, R=15, seed=4)
-    batches = []
-    for cut in (reqs[:5], reqs[5:8], reqs[8:15]):
-        cols, rows = batch_columns(cut)
-        cols.ensure_embeddings(enc)
-        batches.append((cols, rows))
-    lazies = multi.decide_cols_multi(batches, sim.tel)
-    assert multi.stats["multi_dispatch"] == 1
-    assert multi.stats["calls"] == 3 and multi.stats["d2h"] == 2
-    got = [lz.fetch() for lz in lazies]
-    assert multi.stats["d2h"] == 2
-    for (cols, rows), (cm, lm) in zip(batches, got):
-        cs, ls = single.decide_cols(cols, rows, sim.tel).fetch()
-        assert cm.shape == (len(rows),) and cm.dtype == np.int64
-        assert lm.dtype == np.float64 and lm.flags.owndata
-        np.testing.assert_array_equal(_bits(cm), _bits(cs))
-        np.testing.assert_array_equal(_bits(lm), _bits(ls))
-    assert single.stats["d2h"] == 2 * len(batches)
-    multi.decide_cols_multi(batches, sim.tel)
-    assert multi.stats["d2h"] == 4
 
 
 @pytest.mark.parametrize("warm,new", [(5, 7), (9, 11), (17, 27)])

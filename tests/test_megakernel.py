@@ -5,15 +5,15 @@ Three layers, mirroring how the fused backend itself graduated:
 
   * kernel-level: `decision_call` against the pure-numpy full-pipeline
     oracle (`repro.kernels.ref.decision_ref`) on synthetic worlds —
-    multi-window, pad rows, dead instances, budgets, GBM on/off;
+    pad rows, dead instances, budgets, GBM on/off;
   * backend-level: ``decision_backend="megakernel"`` through
     `RouteBalance` must make bitwise the fused-XLA program's
     assignments (and l_chosen, and the post-scan dead-reckoned state)
     across the full mode grid, awkward batch sizes, dead rosters and
     the prefix-affinity arm;
-  * plumbing-level: multi-window coalescing equals K separate
-    dispatches, compile variants stay pinned at the pow2 buckets
-    through roster churn, and the kernel mode follows the platform.
+  * plumbing-level: compile variants stay pinned at the pow2 buckets
+    through roster churn, removed decision options are refused, and the
+    kernel mode follows the platform.
 """
 import numpy as np
 import pytest
@@ -164,7 +164,7 @@ def test_megakernel_e2e_cluster_trajectory(small_ctx):
             results["fused"][1][k], rel=1e-12)
 
 
-# -- plumbing: multi-window coalescing + compile pinning ----------------------
+# -- plumbing: compile pinning + refused options ------------------------------
 
 def _policy(ctx, sim, **cfg_kw):
     pol = RouteBalancePolicy(RBConfig(decision_backend="megakernel",
@@ -174,56 +174,21 @@ def _policy(ctx, sim, **cfg_kw):
     return pol
 
 
-def test_multi_window_matches_separate_dispatches(small_ctx):
-    """K windows through ONE kernel dispatch (assign_windows ->
-    decide_cols_multi, grid=(K,)) must be bitwise K separate assign
-    calls against the same telemetry snapshot — including ragged window
-    sizes that share a pow2 row bucket."""
-    sim = _loaded_sim(small_ctx)
-    reqs = _batch(small_ctx, R=42, seed=11)
-    cuts = [reqs[0:12], reqs[12:24], reqs[24:35], reqs[35:42]]
-    views = [BatchView(c) for c in cuts]
-    pol = _policy(small_ctx, sim, window_coalesce=4)
-    multi = [r.fetch() for r in pol.assign_windows(views, sim)]
-    st = pol._fused.stats
-    assert st.get("multi_dispatch") == 1 and st["calls"] == 4
-    # one staging pass, one sync, one launch for the four windows: the
-    # four staging sets, the reseed's telemetry block and the alive mask
-    assert st["uploads"] == 4 + 1 + 1 and st["full_reseed"] == 1
-    assert st["host_s"] == pytest.approx(st["stage_s"] + st["telemetry_s"],
-                                         rel=1e-12)
-    single = _policy(small_ctx, sim)
-    sep = [single.assign(v, sim).fetch() for v in views]
-    for (cm, lm), (cs, ls) in zip(multi, sep):
-        np.testing.assert_array_equal(cm, cs)
-        np.testing.assert_array_equal(lm, ls)
-
-
-def test_assign_windows_falls_back_per_window(small_ctx):
-    """Non-megakernel backends (and K == 1) route through plain
-    per-window assign — coalescing is a megakernel capability, not a
-    semantic fork."""
-    sim = _loaded_sim(small_ctx)
-    reqs = _batch(small_ctx, R=16, seed=2)
-    views = [BatchView(reqs[:8]), BatchView(reqs[8:])]
-    pol = RouteBalancePolicy(RBConfig(decision_backend="fused"))
-    pol.prepare(small_ctx["bundle"], small_ctx["tiers"])
-    pol.on_attach(sim)
-    coal = [r.fetch() for r in pol.assign_windows(views, sim)]
-    sep = [pol.assign(v, sim).fetch() for v in views]
-    for (cm, lm), (cs, ls) in zip(coal, sep):
-        np.testing.assert_array_equal(cm, cs)
-        np.testing.assert_array_equal(lm, ls)
-
-
-def test_window_coalesce_needs_megakernel():
-    with pytest.raises(AssertionError):
-        RouteBalancePolicy(RBConfig(decision_backend="fused",
-                                    window_coalesce=4))
+@pytest.mark.parametrize("cfg_kw", [
+    dict(decision_backend="jax"),
+    dict(decision_backend="megakernel", window_coalesce=4),
+    dict(decision_backend="fused", window_coalesce=4)],
+    ids=["jax_backend", "coalesce_megakernel", "coalesce_fused"])
+def test_removed_decision_options_refused(cfg_kw):
+    """The staged jax backend and the multi-window dispatch are gone:
+    a configuration that asks for either is refused, not served by
+    something else."""
+    with pytest.raises(ValueError):
+        RouteBalancePolicy(RBConfig(**cfg_kw))
 
 
 def test_megakernel_compile_variants_pinned(small_ctx):
-    """Compile count stays O(log R) + O(log K x log R) through batch
+    """Compile count stays one per pow2 R bucket through batch
     sizes, roster churn (fail/recover flips the alive mask, no
     recompile) and repeated dispatches. A non-default weights preset
     gives this test its own `for_bundle` cache slot — the session-scoped
@@ -238,32 +203,24 @@ def test_megakernel_compile_variants_pinned(small_ctx):
     pol.assign(BatchView(_batch(small_ctx, R=7)), sim).fetch()
     sim.instances[0].recover(t=1.0)
     pol.assign(BatchView(_batch(small_ctx, R=7)), sim).fetch()
-    assert pol._fused.compile_count() == 3   # {8, 16, 64}, single-window
-    reqs = _batch(small_ctx, R=24, seed=7)
-    for cut in ([reqs[:8], reqs[8:16]],                    # K=2 -> Kb 2
-                [reqs[:8], reqs[8:16], reqs[16:24]],       # K=3 -> Kb 4
-                [reqs[:6], reqs[6:12], reqs[12:18], reqs[18:24]]):
-        pol.assign_windows([BatchView(c) for c in cut], sim)
-    # + two (Kb, Rb) multi variants: (2, 8) and (4, 8)
-    assert pol._fused.compile_count() == 5
+    assert pol._fused.compile_count() == 3   # {8, 16, 64}
 
 
 # -- kernel-level: decision_call vs the numpy oracle --------------------------
 
-def _toy_world(seed=0, K=2, R=6, E=8, N=40, M=3, I=5, T=2, k=4):
+def _toy_world(seed=0, R=6, E=8, N=40, M=3, I=5, T=2, k=4):
     rng = np.random.default_rng(seed)
     f32 = np.float32
-    emb = rng.normal(size=(K, R, E)).astype(f32)
-    rv = np.ones((K, R), bool)
-    rv[:, R - 1] = False                      # one pad row per window
-    budgets = np.where(rng.uniform(size=(K, R)) < 0.5,
-                       rng.uniform(1e-5, 3e-4, (K, R)), np.nan
-                       ).astype(f32)
-    len_in = rng.integers(8, 200, (K, R)).astype(f32)
+    emb = rng.normal(size=(R, E)).astype(f32)
+    rv = np.ones(R, bool)
+    rv[R - 1] = False                         # one pad row
+    budgets = np.where(rng.uniform(size=R) < 0.5,
+                       rng.uniform(1e-5, 3e-4, R), np.nan).astype(f32)
+    len_in = rng.integers(8, 200, R).astype(f32)
     x = rng.normal(size=(N, E)).astype(f32)
     args = dict(
         emb=emb, row_valid=rv, budgets=budgets, len_in=len_in,
-        psig=np.zeros((K, 1, 1), np.int32),
+        psig=np.zeros((1, 1), np.int32),
         d=rng.uniform(0, 300, I).astype(f32),
         b=rng.integers(1, 6, I).astype(f32),
         free=rng.integers(0, 4, I).astype(f32),
@@ -289,7 +246,7 @@ def _toy_world(seed=0, K=2, R=6, E=8, N=40, M=3, I=5, T=2, k=4):
 def test_decision_call_matches_numpy_oracle(use_gbm):
     """The kernel pipeline (interpret mode) against the pure-numpy
     full-pipeline oracle: exact assignments, latencies and dead-reckoned
-    state to float tolerance — multi-window, pad rows, one dead
+    state to float tolerance — pad rows, one dead
     instance, nan/finite budgets, GBM on and off."""
     from repro.kernels.ops import decision_megakernel as mk_op
     from repro.kernels.ref import decision_ref

@@ -265,11 +265,11 @@ def test_one_muted_row_among_idle_rows_is_quarantined():
 # -- prefix-affinity under failure / quarantine (serving.affinity) -----------
 
 def _pick_one(run, req, w, mutate=None):
-    """The unanimous (numpy == jax == fused) instance pick for a
+    """The unanimous (numpy == fused) instance pick for a
     single-request decision at affinity weight `w`, on a fresh sim
     optionally perturbed by `mutate`."""
     picks = {}
-    for be in ("numpy", "jax", "fused"):
+    for be in ("numpy", "fused"):
         rb = RouteBalance(RBConfig(decision_backend=be,
                                    affinity_weight=w),
                           run.bundle(), run.tiers)
@@ -345,7 +345,7 @@ def test_quarantined_row_never_scores_affinity(chaos_run):
 
 
 def test_parity_through_recovery_churn(chaos_run):
-    """numpy == jax == fused full-trajectory parity THROUGH retry,
+    """numpy == fused full-trajectory parity THROUGH retry,
     hedge and quarantine churn: every recovery decision is a
     deterministic function of the simulation trajectory, so the
     differential-soak contract extends to the fault-tolerant
@@ -355,14 +355,14 @@ def test_parity_through_recovery_churn(chaos_run):
                        telemetry_blackout(chaos_run.tiers, t0=9.0,
                                           frac=0.5))
     out = {}
-    for be in ("numpy", "jax", "fused"):
+    for be in ("numpy", "fused"):
         reqs, _, m = _cell(chaos_run, campaign, backend=be)
         assert m["failed"] == 0
         out[be] = ([(r.rid, r.instance, r.model_idx, r.dispatch_time,
                      r.finish_time, r.tokens_out, r.attempt, r.hedges)
                     for r in reqs],
                    (m["retries"], m["hedges"], m["quarantines"]))
-    assert out["numpy"] == out["jax"] == out["fused"]
+    assert out["numpy"] == out["fused"]
 
 
 def test_retries_are_never_shed():

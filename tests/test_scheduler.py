@@ -1,11 +1,11 @@
 """Scheduler-core invariants: Eq.1 scoring, LPT, greedy dead reckoning,
-budget safety, numpy-vs-jax greedy parity, Hungarian validity."""
+budget safety, numpy-vs-jax greedy parity (the fused program's scan),
+Hungarian validity."""
 import numpy as np
 import pytest
 
 from repro.core import PRESETS, hungarian, score_matrix, validate
-from repro.core.assignment import greedy_assign, greedy_assign_jax, \
-    lpt_order
+from repro.core.assignment import greedy_assign, lpt_order
 from repro.core.budget import admission_mask, max_tokens_clamp
 from repro.core.weights import sweep
 
@@ -78,11 +78,16 @@ def test_greedy_numpy_vs_jax(rng):
     order = lpt_order(ln.max(1))
     ch_np, _ = greedy_assign(order, q, c, ln, tpot, d, b, free, maxb,
                              PRESETS["uniform"])
-    ch_jx = np.asarray(greedy_assign_jax(
-        order, q.astype(np.float32), c.astype(np.float32),
-        ln.astype(np.float32), tpot.astype(np.float32), d, b, free, maxb,
-        PRESETS["uniform"]))
-    np.testing.assert_array_equal(ch_np, ch_jx)
+    import jax
+
+    from repro.core.decision_jax import _greedy_scan
+    scan = jax.jit(_greedy_scan, static_argnames=("weights",
+                                                  "latency_mode"))
+    f32 = np.float32
+    ch_jx, _, _ = scan(order, f32(q), f32(c), f32(ln), f32(tpot), f32(tpot),
+                       f32(d), f32(b), f32(free), f32(maxb),
+                       PRESETS["uniform"], np.ones(q.shape, bool), "full")
+    np.testing.assert_array_equal(ch_np, np.asarray(ch_jx))
 
 
 def test_budget_admission_and_clamp():

@@ -67,8 +67,8 @@ def test_decision_megakernel_compiles(one_chip, R, I):
 
     def s(shape, dtype=np.float32):
         return _shape(one_chip, shape, dtype)
-    args = (s((1, R, EMB)), s((1, R), bool), s((1, R)), s((1, R)),
-            s((1, R, SIG_WIDTH), np.int32),
+    args = (s((R, EMB)), s((R,), bool), s((R,)), s((R,)),
+            s((R, SIG_WIDTH), np.int32),
             s((I,)), s((I,)), s((I,)), s((I,)), s((I,), bool),
             s((N_INDEX, EMB)), s((N_INDEX,)), s((N_INDEX, M)),
             s((N_INDEX, M)),
